@@ -763,4 +763,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

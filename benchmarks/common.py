@@ -1,8 +1,8 @@
 """Benchmark utilities. Every benchmark returns rows
 (name, us_per_call, derived) and benchmarks/run.py prints them as CSV.
 
-CPU wall-times here are *sanity numbers* — the performance claims live in
-EXPERIMENTS.md §Roofline (dry-run derived). Sizes are scaled down from the
+Wall-times taken on the CPU (interpret-mode Pallas) are *sanity
+numbers*, never device speeds. Sizes are scaled down from the
 paper's 2^16..2^21 Kronecker graphs to keep the suite minutes-long on one
 CPU core; the scaling *trends* (the figures' shapes) are what is checked.
 """
@@ -18,21 +18,15 @@ from repro.graph.generators import kronecker_graph, uniform_weights
 
 
 def timed(fn, *args, reps: int = 3, warmup: int = 1, **kw):
+    """Best-of-``reps`` wall time of ``fn``; every call is waited on with
+    ``jax.block_until_ready``, so a device error raises here instead of
+    being timed as a fast call."""
     for _ in range(warmup):
-        out = fn(*args, **kw)
-        jax.block_until_ready(out) if hasattr(out, "block_until_ready") or isinstance(
-            out, jax.Array
-        ) else None
+        jax.block_until_ready(fn(*args, **kw))
     ts = []
     for _ in range(reps):
         t0 = time.perf_counter()
-        out = fn(*args, **kw)
-        try:
-            jax.tree_util.tree_map(
-                lambda x: x.block_until_ready() if isinstance(x, jax.Array) else x, out
-            )
-        except Exception:
-            pass
+        out = jax.block_until_ready(fn(*args, **kw))
         ts.append(time.perf_counter() - t0)
     return min(ts), out
 

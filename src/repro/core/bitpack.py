@@ -1,9 +1,9 @@
 """Bit-packing of the matching-bit block (§4.3's BRAM word, TPU edition).
 
 The FPGA stores each vertex's matching state as ONE L-bit word in BRAM.
-The unpacked TPU layout spends an int8 lane per substream bit — 8× the
-storage the paper's design needs. This module defines the packed
-*bit-plane* layout used everywhere downstream:
+A dense bool layout spends a byte (the unpacked kernel a 32-bit word)
+per substream bit. This module defines the packed *bit-plane* layout
+every caller sees:
 
     mb_packed[v, k] : uint8, bit j of word k  ==  substream 8*k + j of v
 

@@ -155,8 +155,7 @@ def mwm_rounds_sharded(
 
     def local(src, dst, w, valid, thr):
         m_loc = src.shape[0]
-        # jax.lax.axis_size only exists in newer jax; psum(1) is portable
-        n_edge_shards = jax.lax.psum(jnp.int32(1), edge_axis)
+        n_edge_shards = jax.lax.axis_size(edge_axis)
         shard_id = jax.lax.axis_index(edge_axis)
         # global stream position = shard_id * m_loc + local position
         pri = (shard_id * m_loc + jnp.arange(m_loc)).astype(jnp.int32)
@@ -194,9 +193,7 @@ def mwm_rounds_sharded(
         assigned = jax.lax.pmax(assigned, substream_axis)
         return assigned, mb
 
-    from jax.experimental.shard_map import shard_map
-
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(
@@ -207,6 +204,6 @@ def mwm_rounds_sharded(
             P(substream_axis),
         ),
         out_specs=(P(edge_axis), P(None, substream_axis)),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(stream.src, stream.dst, stream.weight, stream.valid, thr_full)

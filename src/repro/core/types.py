@@ -151,9 +151,14 @@ class SubstreamConfig:
     mb_layout: str = dataclasses.field(default="packed", metadata=dict(static=True))
 
     def thresholds(self) -> jax.Array:
-        """[L] array of substream admission thresholds (1+eps)^i."""
-        i = jnp.arange(self.L, dtype=jnp.float32)
-        return (1.0 + self.eps) ** i
+        """[L] float32 substream admission thresholds (1+eps)^i.
+
+        Computed on the host in float64 and rounded once, so a jitted
+        engine, an eager check and every backend compare against the
+        same float32 values (a device ``pow`` differs by an ulp between
+        fused and eager evaluation on a TPU)."""
+        i = np.arange(self.L, dtype=np.float64)
+        return jnp.asarray(((1.0 + self.eps) ** i).astype(np.float32))
 
     @property
     def w_max(self) -> float:

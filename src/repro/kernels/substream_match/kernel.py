@@ -4,46 +4,49 @@ TPU mapping of the FPGA design:
 
   FPGA                                   TPU (this kernel)
   ------------------------------------   --------------------------------
-  BRAM-resident matching bits            VMEM scratch; packed layout
-                                         mb[n_pad, ceil(L/8)] u8 (default)
-                                         or unpacked mb[n_pad, L_pad] i8
-  L-bit bit-parallel matching word       packed: 8 substreams per uint8
-                                         lane word (the §4.3 BRAM word);
-                                         unpacked: L on the lane axis
-  1 edge / cycle pipeline                lax.fori_loop, 1 edge / iteration
-  DRAM edge stream + prefetch            HBM->VMEM BlockSpec pipeline over
-                                         edge blocks (double-buffered by
+  BRAM-resident matching bits            resident VMEM output block,
+                                         int32 [rows, 128], G vertices
+                                         folded into each 128-lane row
+  L-bit bit-parallel matching word       ``lanes`` int32 words per vertex
+                                         (32 substreams per word packed,
+                                         1 per word unpacked)
+  1 edge / cycle pipeline                lax.fori_loop over slot groups
+  DRAM edge stream + prefetch            HBM->SMEM BlockSpec pipeline over
+                                         slot blocks (double-buffered by
                                          the Pallas grid pipeline)
-  epoch double-buffer of u-bits          whole bit-block stays resident;
-                                         the lexicographic pre-sort keeps
-                                         row touches epoch-local anyway
 
-Stage map (Listing 2): Stage 1-3 = row loads (pl.load, dynamic slice),
-Stage 4 = threshold compare (te), Stage 5 = matching update, Stage 6 =
-row stores, Stage 7 = highest-set-bit, Stage 8 = assigned-index store.
+One kernel body serves all three engines. A *slot* is ``(u, v, cnt)``:
+the two endpoints and the number of substream thresholds the weight
+passes. Thresholds ``(1+eps)^i`` are sorted, so the Stage-4 eligibility
+word of an edge is the prefix of its lowest ``cnt`` substreams; the
+caller computes ``cnt`` (0 for self-loops, padding and invalid edges).
+Slots come in *groups* whose members are vertex-disjoint: group size 1
+is the paper's per-edge processor (``edges``), a wave segment of
+``seg`` slots is the wave engine (``waves``), and a block-aligned tile
+of ``seg_block * seg`` slots is the megakernel (``mega``). Disjointness
+lets a group load all its rows before it stores any: no slot of the
+group can see another's update, so the result is the sequential one.
 
-Packed path details: eligibility is evaluated per *bit plane* — the
-thresholds arrive as [8, W_pad] f32 where row j, word k holds substream
-8k+j's threshold (+inf in padding slots), so `w >= thr` directly yields
-the 8 bit planes of the L-bit eligibility word and an 8-way shift-OR
-assembles the uint8 mask. The free test / matching update become single
-bitwise ops on uint8 rows (te & ~mb[u] & ~mb[v]); Stage 7's highest set
-bit is an 8-way shift-mask reduction over lane-index*8 + bit.
+Stage map (Listing 2): Stage 1 = scalar slot reads from SMEM and the
+row address ``u // G``; Stage 2-3 = dynamic single-row loads; Stage 4 =
+the prefix word from ``cnt`` (an iota compare, no table); Stage 5 =
+``te & ~mb[u] & ~mb[v]``; Stage 6 = read-modify-write OR of the new
+bits into both rows; Stage 7 = highest set bit via ``clz``; Stage 8 =
+a scalar store of the substream index into the SMEM ``assigned`` block.
 
-Capacity: the bit block must fit VMEM: n_pad * ceil(L/8) bytes packed
-(8x the unpacked n_pad * L_pad budget of the int8 layout). Physical-TPU
-note: uint8 tiles are (32, 128), so to realize the full win on hardware
-when ceil(L/8) < 128 the row is folded vertex-major — G = 128 // W_pad
-vertices share one 128-lane row (u selects row u // G, byte offset
-(u % G) * W_pad). The interpret-mode kernel keeps the simple
-[n_pad, W_pad] layout; ops.vmem_plan reports the logical packed bytes
-either way. For
-larger graphs the vertex set is partitioned across devices and the
-parallel-rounds path (repro.core.rounds) stitches partitions together;
-within a partition this kernel is the inner engine.
+Layout. Mosaic tiles 32-bit data as (8, 128), so a one-row dynamic
+load is legal for int32 and not for 8-bit types. Each vertex owns
+``lanes`` consecutive int32 lanes of a 128-lane row; ``G = 128 //
+lanes`` vertices share a row, and vertex ``u`` lives in row ``u // G``
+at lane offset ``(u % G) * lanes``. The ``v`` row is rotated onto
+``u``'s lanes for Stage 5 and the new bits are rotated back for Stage 6.
+Vertices wider than 128 lanes (G = 1) use ``lanes`` (a multiple of 128)
+as the row width and need no rotation.
 
-Grid: one program per edge block, sequential ("arbitrary") so the VMEM
-scratch carries state across blocks — the stream order is preserved.
+Grid: one program per slot block, sequential ("arbitrary"), so the
+resident output block carries the bit state across programs and the
+stream order is preserved. ``bound`` (scalar prefetch) is the number of
+real groups; programs stop there, so grid padding costs no trips.
 """
 from __future__ import annotations
 
@@ -51,719 +54,161 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams around 0.5; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+#: Lanes in one vector row of the TPU.
+LANES = 128
+#: Int32 words per slot in the SMEM slot stream: (u, v, cnt).
+SLOT_WORDS = 3
 
 
-def _split_refs(refs):
-    """Unpack the trailing kernel refs: ``(assigned, mb_out, scratch)``
-    plus an optional leading ``mb0`` input (the epoch executor's carried
-    initial bit block — see ops.match_epochs). The wrappers only append
-    the extra operand when an initial state is given, so the zero-state
-    call graph (and its jit cache keys) is byte-for-byte unchanged."""
+def _prefix_words(cnt, k, bits: int):
+    """Eligibility word of lane ``k`` (vertex-relative) for a slot that
+    passes ``cnt`` thresholds: the lowest ``clip(cnt - bits*k, 0, bits)``
+    bits set. ``bits`` is 32 (packed words) or 1 (one substream per
+    word)."""
+    nb = jnp.clip(cnt - bits * k, 0, bits)
+    if bits == 1:
+        return nb
+    return jnp.where(nb >= 32, -1, (1 << jnp.minimum(nb, 31)) - 1)
+
+
+def _kernel(
+    bound_ref, slots_ref, *refs,
+    group: int, groups_per_block: int, lanes: int, bits: int,
+):
     if len(refs) == 4:
-        return refs[0], refs[1], refs[2], refs[3]
-    assigned_ref, mb_out_ref, mb = refs
-    return None, assigned_ref, mb_out_ref, mb
-
-
-def _kernel(edges_ref, w_ref, thr_ref, *refs, block_e: int):
-    mb0_ref, assigned_ref, mb_out_ref, mb = _split_refs(refs)
+        mb0_ref, assigned_ref, mb_ref, sem = refs
+    else:
+        (assigned_ref, mb_ref), mb0_ref = refs, None
     b = pl.program_id(0)
-    nblocks = pl.num_programs(0)
 
     @pl.when(b == 0)
     def _init():
-        mb[...] = jnp.zeros_like(mb) if mb0_ref is None else mb0_ref[...]
+        if mb0_ref is None:
+            mb_ref[...] = jnp.zeros_like(mb_ref)
+        else:
+            copy = pltpu.make_async_copy(mb0_ref, mb_ref, sem)
+            copy.start()
+            copy.wait()
 
-    L_pad = mb.shape[1]
-    thr = thr_ref[0, :]  # [L_pad] f32; padding lanes hold +inf
-    lane = jax.lax.broadcasted_iota(jnp.int32, (L_pad,), 0)
+    width = mb_ref.shape[1]
+    fold = width // lanes
+    shift = fold.bit_length() - 1
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    ngroups = jnp.clip(bound_ref[0] - b * groups_per_block, 0, groups_per_block)
 
-    def body(i, _):
-        # Stage 1: unpack edge, compute row addresses
-        u = edges_ref[i, 0]
-        v = edges_ref[i, 1]
-        w = w_ref[i, 0]
-        # Stage 2-3: row loads (BRAM -> register in the paper)
-        mbu = pl.load(mb, (pl.ds(u, 1), slice(None)))[0]  # [L_pad] i8
-        mbv = pl.load(mb, (pl.ds(v, 1), slice(None)))[0]
-        # Stage 4: eligibility te[i] = w >= (1+eps)^i  (+inf pads -> False)
-        te = (w >= thr) & (u != v)
-        # Stage 5: compute the matchings
-        add = te & (mbu == 0) & (mbv == 0)
-        addi = add.astype(jnp.int8)
-        # Stage 6: write u/v bits back (v second: self-loop-safe, add=0 there)
-        pl.store(mb, (pl.ds(u, 1), slice(None)), (mbu | addi)[None])
-        mbv2 = pl.load(mb, (pl.ds(v, 1), slice(None)))[0]
-        pl.store(mb, (pl.ds(v, 1), slice(None)), (mbv2 | addi)[None])
-        # Stage 7: highest set bit
-        idx = jnp.max(jnp.where(add, lane, -1))
-        # Stage 8: emit assignment
-        assigned_ref[i, 0] = idx
-        return 0
+    def locate(x):
+        # vertex -> (row, lane offset of its first word)
+        if fold == 1:
+            return x, 0
+        return x >> shift, (x & (fold - 1)) * lanes
 
-    jax.lax.fori_loop(0, block_e, body, 0, unroll=False)
+    def rotate(row, amount):
+        if fold == 1:
+            return row
+        return pltpu.roll(row, amount & (width - 1), 1)
 
-    @pl.when(b == nblocks - 1)
-    def _flush():
-        mb_out_ref[...] = mb[...]
+    def body(t, carry):
+        writes = []
+        for j in range(group):
+            s = t * group + j
+            # Stage 1: slot scalars and row addresses
+            u = slots_ref[SLOT_WORDS * s]
+            v = slots_ref[SLOT_WORDS * s + 1]
+            cnt = slots_ref[SLOT_WORDS * s + 2]
+            ru, ou = locate(u)
+            rv, ov = locate(v)
+            # Stage 2-3: single-row loads; v's words rotated onto u's lanes
+            mbu = mb_ref[pl.ds(ru, 1), :]
+            mbv = rotate(mb_ref[pl.ds(rv, 1), :], ou - ov)
+            # Stage 4: the eligibility prefix on u's lanes only
+            k = lane - ou
+            te = jnp.where(
+                (k >= 0) & (k < lanes), _prefix_words(cnt, k, bits), 0
+            )
+            # Stage 5: the matching update, 32 substreams per word
+            add = te & ~mbu & ~mbv
+            # Stage 7: highest set bit over the vertex's words
+            high = bits * k + (31 - jax.lax.clz(add))
+            # Stage 8: emit the assignment
+            assigned_ref[s] = jnp.max(jnp.where(add != 0, high, -1))
+            writes.append((ru, add, rv, rotate(add, ov - ou)))
+        # Stage 6: OR the new bits into both rows (u first, then v, so a
+        # shared row sees both updates)
+        for ru, add_u, rv, add_v in writes:
+            mb_ref[pl.ds(ru, 1), :] = mb_ref[pl.ds(ru, 1), :] | add_u
+            mb_ref[pl.ds(rv, 1), :] = mb_ref[pl.ds(rv, 1), :] | add_v
+        return carry
 
-
-def _kernel_packed(edges_ref, w_ref, thr_ref, *refs, block_e: int):
-    """Packed bit-plane edge processor: mb rows are uint8 words of 8 bits."""
-    mb0_ref, assigned_ref, mb_out_ref, mb = _split_refs(refs)
-    b = pl.program_id(0)
-    nblocks = pl.num_programs(0)
-
-    @pl.when(b == 0)
-    def _init():
-        mb[...] = jnp.zeros_like(mb) if mb0_ref is None else mb0_ref[...]
-
-    W_pad = mb.shape[1]
-    thr = thr_ref[...]  # [8, W_pad] f32; +inf in padding slots
-    lane = jax.lax.broadcasted_iota(jnp.int32, (W_pad,), 0)
-
-    def body(i, _):
-        # Stage 1: unpack edge, compute row addresses
-        u = edges_ref[i, 0]
-        v = edges_ref[i, 1]
-        w = w_ref[i, 0]
-        # Stage 2-3: row loads (BRAM -> register in the paper)
-        mbu = pl.load(mb, (pl.ds(u, 1), slice(None)))[0]  # [W_pad] u8
-        mbv = pl.load(mb, (pl.ds(v, 1), slice(None)))[0]
-        # Stage 4: assemble the L-bit eligibility word from its 8 bit planes
-        planes = w >= thr  # [8, W_pad] bool; plane j = substreams 8k+j
-        te = jnp.zeros((W_pad,), jnp.uint8)
-        for j in range(8):
-            te |= planes[j].astype(jnp.uint8) << j
-        te = jnp.where(u != v, te, jnp.uint8(0))  # self-loops never match
-        # Stage 5: compute the matchings — one bitwise op per 8 substreams
-        add = te & ~mbu & ~mbv
-        # Stage 6: write u/v bits back (v second: self-loop-safe, add=0 there)
-        pl.store(mb, (pl.ds(u, 1), slice(None)), (mbu | add)[None])
-        mbv2 = pl.load(mb, (pl.ds(v, 1), slice(None)))[0]
-        pl.store(mb, (pl.ds(v, 1), slice(None)), (mbv2 | add)[None])
-        # Stage 7: highest set bit via shift-mask reduction over bit planes
-        addi = add.astype(jnp.int32)
-        idx = jnp.int32(-1)
-        for j in range(8):
-            hit = (addi >> j) & 1
-            idx = jnp.maximum(idx, jnp.max(jnp.where(hit > 0, 8 * lane + j, -1)))
-        # Stage 8: emit assignment
-        assigned_ref[i, 0] = idx
-        return 0
-
-    jax.lax.fori_loop(0, block_e, body, 0, unroll=False)
-
-    @pl.when(b == nblocks - 1)
-    def _flush():
-        mb_out_ref[...] = mb[...]
-
-
-def _kernel_waves(
-    edges_ref, w_ref, thr_ref, *refs,
-    block_s: int, seg: int, n_out: int,
-):
-    """Segment-vectorized edge processor, unpacked int8 layout.
-
-    One ``fori_loop`` iteration consumes one *segment* — ``seg``
-    vertex-disjoint slots of the fill-packed schedule
-    (`repro.graph.waves`): waves are packed back-to-back into fixed
-    [seg]-slot rows, so the kernel never pays for a global max-wave
-    width and its per-trip traffic is O(seg·width), proportional to the
-    slots it actually processes, not to the graph. Row addressing is the
-    gather/scatter form: both endpoint rows are gathered by row index,
-    the eligibility/matching update runs as [seg, L_pad] tile ops, and
-    the new bits are written back row-by-row in place — the former
-    whole-block ``mball.at[u].add`` rematerialized (read + rewrote) the
-    entire [n_rows, width] block once per wave, O(n·width) traffic that
-    dominated near the VMEM capacity ceiling.
-
-    Why in-place row writes are safe: real slots in a segment are
-    vertex-disjoint (u-rows, v-rows all distinct), self-loops contribute
-    ``add == 0`` and write their freshly-gathered row back unchanged,
-    and padding slots are remapped by the caller to a *sacrificial* row
-    at index ``n_out`` (outside the flushed block) so they can never
-    race a real vertex-0 write — every duplicate row index in a scatter
-    carries an identical value.
-
-    Physical-TPU note: the row gather/scatter is expressed as
-    array-indexed ref reads/writes, which interpret mode executes
-    directly; on hardware the same addressing is a seg-step DMA row
-    gather (the per-edge kernel's addressing, seg rows at a time) or a
-    one-hot MXU matmul — the wave semantics are unchanged.
-    """
-    mb0_ref, assigned_ref, mb_out_ref, mb = _split_refs(refs)
-    b = pl.program_id(0)
-    nblocks = pl.num_programs(0)
-
-    @pl.when(b == 0)
-    def _init():
-        mb[...] = jnp.zeros_like(mb) if mb0_ref is None else mb0_ref[...]
-
-    L_pad = mb.shape[1]
-    thr = thr_ref[0, :]  # [L_pad] f32; padding lanes hold +inf
-    lane = jax.lax.broadcasted_iota(jnp.int32, (seg, L_pad), 1)
-
-    def body(i, _):
-        # Stage 1: load one segment of seg slots
-        ed = pl.load(edges_ref, (pl.ds(i * seg, seg), slice(None)))  # [seg, 2]
-        u = ed[:, 0]
-        v = ed[:, 1]
-        w = pl.load(w_ref, (pl.ds(i * seg, seg), slice(None)))[:, 0]  # [seg]
-        # Stage 2-3: row-addressed gather of both endpoint rows
-        mbu = mb[u, :]  # [seg, L_pad] i8
-        mbv = mb[v, :]
-        # Stage 4: eligibility for the whole segment at once
-        te = (w[:, None] >= thr[None, :]) & (u != v)[:, None]
-        # Stage 5: the matching update, one [seg, L_pad] tile op
-        add = te & (mbu == 0) & (mbv == 0)
-        addi = add.astype(jnp.int8)
-        # Stage 6: in-place row scatter of the new bits
-        mb[u, :] = mbu | addi
-        mb[v, :] = mbv | addi
-        # Stage 7: highest set bit, vectorized over the segment
-        idx = jnp.max(jnp.where(add, lane, -1), axis=1)  # [seg]
-        # Stage 8: emit one segment of assignments
-        pl.store(assigned_ref, (pl.ds(i * seg, seg), slice(None)), idx[:, None])
-        return 0
-
-    jax.lax.fori_loop(0, block_s, body, 0, unroll=False)
-
-    @pl.when(b == nblocks - 1)
-    def _flush():
-        mb_out_ref[...] = mb[0:n_out, :]
-
-
-def _kernel_waves_packed(
-    edges_ref, w_ref, thr_ref, *refs,
-    block_s: int, seg: int, n_out: int,
-):
-    """Segment-vectorized edge processor, packed uint8 bit-plane layout.
-
-    Same segment semantics as :func:`_kernel_waves`; the eligibility
-    word is assembled per bit plane ([seg, 8, W_pad] compare, 8-way
-    shift-OR) and the free test / matching update are single bitwise ops
-    on the whole [seg, W_pad] uint8 tile before the in-place row
-    scatter.
-    """
-    mb0_ref, assigned_ref, mb_out_ref, mb = _split_refs(refs)
-    b = pl.program_id(0)
-    nblocks = pl.num_programs(0)
-
-    @pl.when(b == 0)
-    def _init():
-        mb[...] = jnp.zeros_like(mb) if mb0_ref is None else mb0_ref[...]
-
-    W_pad = mb.shape[1]
-    thr = thr_ref[...]  # [8, W_pad] f32; +inf in padding slots
-    shift = jax.lax.broadcasted_iota(jnp.uint8, (1, 8, 1), 1)
-    # substream index of bit j in word k: 8k + j, as one [1, W_pad, 8] map
-    bit_of = (
-        8 * jax.lax.broadcasted_iota(jnp.int32, (1, W_pad, 8), 1)
-        + jax.lax.broadcasted_iota(jnp.int32, (1, W_pad, 8), 2)
-    )
-
-    def body(i, _):
-        # Stage 1: load one segment of seg slots
-        ed = pl.load(edges_ref, (pl.ds(i * seg, seg), slice(None)))  # [seg, 2]
-        u = ed[:, 0]
-        v = ed[:, 1]
-        w = pl.load(w_ref, (pl.ds(i * seg, seg), slice(None)))[:, 0]  # [seg]
-        # Stage 2-3: row-addressed gather of both endpoint rows
-        mbu = mb[u, :]  # [seg, W_pad] u8
-        mbv = mb[v, :]
-        # Stage 4: assemble the L-bit eligibility words from bit planes —
-        # plane bits are disjoint, so the shift-OR is a plain sum
-        planes = w[:, None, None] >= thr[None, :, :]  # [seg, 8, W_pad]
-        te = (planes.astype(jnp.uint8) << shift).sum(axis=1).astype(jnp.uint8)
-        te = jnp.where((u != v)[:, None], te, jnp.uint8(0))
-        # Stage 5: matching update — one bitwise op per 8 substreams
-        add = te & ~mbu & ~mbv
-        # Stage 6: in-place row scatter of the new bits
-        mb[u, :] = mbu | add
-        mb[v, :] = mbv | add
-        # Stage 7: highest set bit over the unpacked [seg, W_pad, 8] view
-        hit = (add[:, :, None] >> shift.reshape(1, 1, 8)) & 1
-        idx = jnp.max(jnp.where(hit > 0, bit_of, -1), axis=(1, 2))  # [seg]
-        # Stage 8: emit one segment of assignments
-        pl.store(assigned_ref, (pl.ds(i * seg, seg), slice(None)), idx[:, None])
-        return 0
-
-    jax.lax.fori_loop(0, block_s, body, 0, unroll=False)
-
-    @pl.when(b == nblocks - 1)
-    def _flush():
-        mb_out_ref[...] = mb[0:n_out, :]
+    jax.lax.fori_loop(0, ngroups, body, 0)
 
 
 def substream_match_pallas(
-    edges: jax.Array,  # int32 [m_pad, 2]
-    weights: jax.Array,  # f32/bf16 [m_pad, 1]; <= 0 marks padding edges
-    thresholds: jax.Array,  # f32 [1, L_pad]; +inf in padding lanes
-    n_pad: int,
-    block_e: int = 1024,
-    interpret: bool = True,
-    mb_init: jax.Array | None = None,  # int8 [n_pad, L_pad] carried-in bits
+    slots: jax.Array,  # int32 [SLOT_WORDS * total]: (u, v, cnt) per slot
+    num_groups: jax.Array,  # int32 [1]: real groups; trips stop there
+    rows: int,
+    width: int,
+    lanes: int,
+    bits: int,
+    group: int,
+    groups_per_block: int,
+    vmem_limit: int,
+    interpret: bool = False,
+    mb_init: jax.Array | None = None,  # int32 [rows, width] carried-in bits
 ):
-    """Raw pallas_call wrapper, unpacked int8 layout (legacy fallback).
+    """Raw pallas_call wrapper: run the slot stream over a folded bit block.
 
-    See ops.substream_match for the typed API and the packed default.
-    ``mb_init`` seeds the resident bit block instead of zeros (the epoch
-    executor's carried state); ``None`` keeps the zero-init fast path.
+    ``total`` slots form ``total / (group * groups_per_block)`` grid
+    programs. Within a group the slots must be vertex-disjoint (any slot
+    with ``cnt = 0`` may alias a vertex: it changes nothing). Returns
+    ``(assigned int32 [total], mb int32 [rows, width])``; assigned is -1
+    where nothing matched and undefined past ``num_groups`` groups.
+    ``mb_init`` seeds the bit block (copied from HBM once); ``None``
+    zero-fills it.
     """
-    m_pad = edges.shape[0]
-    assert m_pad % block_e == 0, (m_pad, block_e)
-    L_pad = thresholds.shape[1]
-    nblocks = m_pad // block_e
-    grid = (nblocks,)
-
+    block = group * groups_per_block
+    total = slots.shape[0] // SLOT_WORDS
+    assert total % block == 0, (total, group, groups_per_block)
+    smem = pltpu.MemorySpace.SMEM
     in_specs = [
-        pl.BlockSpec((block_e, 2), lambda b: (b, 0)),  # edge block (pipelined)
-        pl.BlockSpec((block_e, 1), lambda b: (b, 0)),  # weight block
-        pl.BlockSpec((1, L_pad), lambda b: (0, 0)),  # thresholds (resident)
-    ]
-    operands = [edges, weights.astype(jnp.float32), thresholds]
-    if mb_init is not None:
-        assert mb_init.shape == (n_pad, L_pad), (mb_init.shape, n_pad, L_pad)
-        in_specs.append(pl.BlockSpec((n_pad, L_pad), lambda b: (0, 0)))
-        operands.append(mb_init.astype(jnp.int8))
-
-    kernel = functools.partial(_kernel, block_e=block_e)
-    assigned, mb = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((block_e, 1), lambda b: (b, 0)),
-            pl.BlockSpec((n_pad, L_pad), lambda b: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((m_pad, 1), jnp.int32),
-            jax.ShapeDtypeStruct((n_pad, L_pad), jnp.int8),
-        ],
-        scratch_shapes=[pltpu.VMEM((n_pad, L_pad), jnp.int8)],
-        interpret=interpret,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary",),
-        ),
-    )(*operands)
-    return assigned[:, 0], mb
-
-
-def substream_match_pallas_packed(
-    edges: jax.Array,  # int32 [m_pad, 2]
-    weights: jax.Array,  # f32/bf16 [m_pad, 1]; <= 0 marks padding edges
-    thresholds: jax.Array,  # f32 [8, W_pad]; thr[j, k] = substream 8k+j, +inf pads
-    n_pad: int,
-    block_e: int = 1024,
-    interpret: bool = True,
-    mb_init: jax.Array | None = None,  # uint8 [n_pad, W_pad] carried-in bits
-):
-    """Raw pallas_call wrapper, packed uint8 bit-plane layout (default path).
-
-    Returns (assigned int32 [m_pad], mb_packed uint8 [n_pad, W_pad]).
-    ``mb_init`` seeds the resident bit block instead of zeros (the epoch
-    executor's carried state); ``None`` keeps the zero-init fast path.
-    """
-    m_pad = edges.shape[0]
-    assert m_pad % block_e == 0, (m_pad, block_e)
-    assert thresholds.shape[0] == 8, thresholds.shape
-    W_pad = thresholds.shape[1]
-    nblocks = m_pad // block_e
-    grid = (nblocks,)
-
-    in_specs = [
-        pl.BlockSpec((block_e, 2), lambda b: (b, 0)),  # edge block (pipelined)
-        pl.BlockSpec((block_e, 1), lambda b: (b, 0)),  # weight block
-        pl.BlockSpec((8, W_pad), lambda b: (0, 0)),  # bit-plane thresholds
-    ]
-    operands = [edges, weights.astype(jnp.float32), thresholds]
-    if mb_init is not None:
-        assert mb_init.shape == (n_pad, W_pad), (mb_init.shape, n_pad, W_pad)
-        in_specs.append(pl.BlockSpec((n_pad, W_pad), lambda b: (0, 0)))
-        operands.append(mb_init.astype(jnp.uint8))
-
-    kernel = functools.partial(_kernel_packed, block_e=block_e)
-    assigned, mb = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((block_e, 1), lambda b: (b, 0)),
-            pl.BlockSpec((n_pad, W_pad), lambda b: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((m_pad, 1), jnp.int32),
-            jax.ShapeDtypeStruct((n_pad, W_pad), jnp.uint8),
-        ],
-        scratch_shapes=[pltpu.VMEM((n_pad, W_pad), jnp.uint8)],
-        interpret=interpret,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary",),
-        ),
-    )(*operands)
-    return assigned[:, 0], mb
-
-
-#: Extra scratch rows past ``n_pad``: row ``n_pad`` is the sacrificial
-#: row every padding slot is remapped to (so its no-op writes can never
-#: duplicate a real vertex row inside one scatter); the band is 8 rows
-#: to keep the scratch row count a multiple of 8.
-SACRIFICIAL_ROWS = 8
-
-
-def _prefix_te_table(width: int) -> jax.Array:
-    """[8 * width + 1, width] uint8: row c = the packed L-bit prefix mask
-    with the lowest ``c`` bits set (bit j of word k = substream 8k+j).
-
-    Substream thresholds are non-decreasing ((1+eps)^i), so the Stage-4
-    eligibility word of an edge is always a *prefix*: te = all substreams
-    whose threshold <= w. That reduces the per-edge threshold test to a
-    count (how many thresholds pass) plus this table lookup — one fused
-    [block]-wide compare/sum per grid program instead of a bit-plane
-    assembly per tile. Built from iotas so it can live inside a Pallas
-    kernel (captured array constants are rejected); a handful of one-time
-    ops per grid program.
-    """
-    c = jax.lax.broadcasted_iota(jnp.int32, (8 * width + 1, width), 0)
-    k = jax.lax.broadcasted_iota(jnp.int32, (8 * width + 1, width), 1)
-    nbits = jnp.clip(c - 8 * k, 0, 8)
-    return ((1 << nbits) - 1).astype(jnp.uint8)
-
-
-def _high_bit_table() -> jax.Array:
-    """[256] int32: highest set bit of a uint8 (floor log2), with a
-    sentinel low enough that an all-zero eligibility row still reduces
-    to < -1 after the word offsets (8k <= 8*width) are added. Uses the
-    f32-exponent trick (exact for integers < 2^24) so it builds from an
-    iota inside the kernel."""
-    i = jax.lax.broadcasted_iota(jnp.int32, (256,), 0)
-    e = (jax.lax.bitcast_convert_type(i.astype(jnp.float32), jnp.int32) >> 23) - 127
-    return jnp.where(i > 0, e, -1024)
-
-
-def _kernel_waves_mega(
-    seg_offsets_ref, uv_ref, w_ref, thr_ref, *refs,
-    tiles_per_block: int, bslots: int, seg_block: int, n_out: int,
-):
-    """Grid-pipelined segment megakernel, unpacked int8 layout.
-
-    Same tile semantics and carry structure as
-    :func:`_kernel_waves_mega_packed` (see its docstring for the
-    pipeline story); the eligibility mask is the plain lane-prefix
-    compare ``lane < cnt`` and the matching state is one int8 byte per
-    substream bit.
-    """
-    mb0_ref, assigned_ref, mb_out_ref, mb = _split_refs(refs)
-    b = pl.program_id(0)
-    nblocks = pl.num_programs(0)
-
-    @pl.when(b == 0)
-    def _init():
-        mb[...] = jnp.zeros_like(mb) if mb0_ref is None else mb0_ref[...]
-
-    L_pad = mb.shape[1]
-    block = tiles_per_block * bslots
-    lane = jax.lax.broadcasted_iota(jnp.int32, (bslots, L_pad), 1)
-    total_tiles = seg_offsets_ref[seg_offsets_ref.shape[0] - 1] // seg_block
-    tiles_here = jnp.clip(total_tiles - b * tiles_per_block, 0, tiles_per_block)
-
-    # Stage 4 for the whole program at once: thresholds are sorted, so
-    # eligibility is the lane prefix below the per-slot pass count
-    w_all = w_ref[...][:, 0]  # [block]
-    cnt = jnp.sum(
-        (w_all[:, None] >= thr_ref[0, :][None, :]), axis=1, dtype=jnp.int32
-    )
-    te_all = (
-        jax.lax.broadcasted_iota(jnp.int32, (block, L_pad), 1) < cnt[:, None]
-    ).astype(jnp.int8)
-
-    def body(t, carry):
-        mbv, asg = carry
-        # Stage 1: one fused load of the tile's 2*bslots row addresses
-        uv = pl.load(uv_ref, (pl.ds(t * 2 * bslots, 2 * bslots), slice(None)))[:, 0]
-        te = jax.lax.dynamic_slice(te_all, (t * bslots, 0), (bslots, L_pad))
-        # Stage 2-3: one fused gather of all endpoint rows
-        rows = mbv[uv]  # [2 * bslots, L_pad] i8
-        mbu = rows[:bslots]
-        mbw = rows[bslots:]
-        # Stage 5: the matching update, one [bslots, L_pad] tile op
-        add = te & (1 - (mbu | mbw))
-        # Stage 6: functional row scatter into the carried bit block —
-        # duplicate uv rows (sacrificial padding) carry identical values,
-        # so .at[].set is deterministic here
-        mbv = mbv.at[uv].set(rows | jnp.concatenate([add, add]))
-        # Stage 7: highest set bit, vectorized over the tile
-        idx = jnp.max(jnp.where(add > 0, lane, -1), axis=1)  # [bslots]
-        # Stage 8: emit the tile's assignments into the carried block
-        asg = jax.lax.dynamic_update_slice(asg, idx, (t * bslots,))
-        return mbv, asg
-
-    mbf, asgf = jax.lax.fori_loop(
-        0, tiles_here, body, (mb[...], jnp.full((block,), -1, jnp.int32))
-    )
-    mb[...] = mbf
-    assigned_ref[...] = asgf[:, None]
-
-    @pl.when(b == nblocks - 1)
-    def _flush():
-        mb_out_ref[...] = mb[0:n_out, :]
-
-
-def _kernel_waves_mega_packed(
-    seg_offsets_ref, uv_ref, w_ref, thr_ref, *refs,
-    tiles_per_block: int, bslots: int, seg_block: int, n_out: int,
-):
-    """Grid-pipelined segment megakernel, packed uint8 bit-plane layout.
-
-    The §4.4 pipeline, re-drawn at tile granularity. One *tile* is
-    ``seg_block`` consecutive segment rows of the block-aligned layout
-    (`repro.graph.waves.block_aligned_layout`) — ``bslots = seg_block *
-    SEG`` slots that are guaranteed vertex-disjoint because no tile
-    straddles a wave boundary. Three pipeline levels:
-
-    * **grid** — each program consumes ``tiles_per_block`` tiles; the
-      Pallas grid pipeline double-buffers the HBM->VMEM copy of the next
-      program's slot-stream block behind the current program's compute
-      (the paper's DRAM prefetcher);
-    * **program** — Stage 4 runs once per program as a fused
-      [block]-wide threshold count + prefix-table lookup (thresholds are
-      sorted, so eligibility words are prefixes — see
-      :func:`_prefix_te_table`), saturating the VPU at any L;
-    * **tile loop** — the bit block AND the assigned block are carried
-      as *values* through ``fori_loop`` (gather/compute/scatter as pure
-      array ops, ref I/O only at the program boundary), so one trip
-      costs one fused [2*bslots]-row gather, a handful of [bslots,
-      W_pad] tile ops, and one fused scatter — no per-tile ref traffic,
-      which dominates the discharged interpret-mode execution.
-
-    The caller pre-remaps padding *and self-loop* slots to the
-    sacrificial row with w = 0, so the kernel needs no per-tile
-    ``u != v`` masking. The scalar-prefetched ``seg_offsets`` bound the
-    loop: grid padding beyond the layout's real tile count is skipped
-    entirely (its assigned slots stay -1), not processed-and-discarded.
-    """
-    mb0_ref, assigned_ref, mb_out_ref, mb = _split_refs(refs)
-    b = pl.program_id(0)
-    nblocks = pl.num_programs(0)
-
-    @pl.when(b == 0)
-    def _init():
-        mb[...] = jnp.zeros_like(mb) if mb0_ref is None else mb0_ref[...]
-
-    W_pad = mb.shape[1]
-    block = tiles_per_block * bslots
-    te_table = _prefix_te_table(W_pad)
-    high_bit = _high_bit_table()
-    word_off = 8 * jax.lax.broadcasted_iota(jnp.int32, (1, W_pad), 1)
-    total_tiles = seg_offsets_ref[seg_offsets_ref.shape[0] - 1] // seg_block
-    tiles_here = jnp.clip(total_tiles - b * tiles_per_block, 0, tiles_per_block)
-
-    # Stage 4 for the whole program at once: count passing thresholds
-    # per slot, then look the packed prefix word up in the table
-    w_all = w_ref[...][:, 0]  # [block]
-    cnt = jnp.sum(
-        (w_all[:, None] >= thr_ref[0, :][None, :]), axis=1, dtype=jnp.int32
-    )
-    te_all = te_table[cnt]  # [block, W_pad] u8
-
-    def body(t, carry):
-        mbv, asg = carry
-        # Stage 1: one fused load of the tile's 2*bslots row addresses
-        uv = pl.load(uv_ref, (pl.ds(t * 2 * bslots, 2 * bslots), slice(None)))[:, 0]
-        te = jax.lax.dynamic_slice(te_all, (t * bslots, 0), (bslots, W_pad))
-        # Stage 2-3: one fused gather of all endpoint rows
-        rows = mbv[uv]  # [2 * bslots, W_pad] u8
-        mbu = rows[:bslots]
-        mbw = rows[bslots:]
-        # Stage 5: matching update — one bitwise op per 8 substreams
-        add = te & ~(mbu | mbw)
-        # Stage 6: functional row scatter into the carried bit block
-        mbv = mbv.at[uv].set(rows | jnp.concatenate([add, add]))
-        # Stage 7: highest set bit via the log2 table, one word at a time
-        idx = jnp.maximum(
-            jnp.max(high_bit[add.astype(jnp.int32)] + word_off, axis=1), -1
+        pl.BlockSpec(
+            (SLOT_WORDS * block,), lambda b, bound: (b,), memory_space=smem
         )
-        # Stage 8: emit the tile's assignments into the carried block
-        asg = jax.lax.dynamic_update_slice(asg, idx, (t * bslots,))
-        return mbv, asg
-
-    mbf, asgf = jax.lax.fori_loop(
-        0, tiles_here, body, (mb[...], jnp.full((block,), -1, jnp.int32))
-    )
-    mb[...] = mbf
-    assigned_ref[...] = asgf[:, None]
-
-    @pl.when(b == nblocks - 1)
-    def _flush():
-        mb_out_ref[...] = mb[0:n_out, :]
-
-
-def substream_match_pallas_mega(
-    uv: jax.Array,  # int32 [2 * total, 1], per-tile column-major (u's then v's)
-    weights: jax.Array,  # f32 [total, 1]; padding/self-loop slots are 0
-    thresholds: jax.Array,  # f32 [1, nbits] sorted flat, +inf in padding slots
-    seg_offsets: jax.Array,  # int32 [num_waves + 1], block-aligned
-    n_pad: int,
-    seg: int,
-    seg_block: int,
-    tiles_per_block: int,
-    interpret: bool = True,
-    packed: bool = True,
-    mb_init: jax.Array | None = None,  # [n_pad + SACRIFICIAL_ROWS, width]
-):
-    """Raw pallas_call wrapper for the grid-pipelined megakernel.
-
-    The slot stream is the *block-aligned* layout
-    (`repro.graph.waves.block_aligned_layout`), grid-padded to a
-    ``tiles_per_block`` tile multiple (``total`` slots). ``uv`` is laid
-    out per tile as all ``bslots`` u-rows then all ``bslots`` v-rows, so
-    one contiguous load yields the tile's full gather index vector.
-    Padding AND self-loop slots MUST be pre-remapped to ``u = v = n_pad``
-    (the sacrificial row) with ``w = 0`` — the kernel has no in-loop
-    self-loop test. ``thresholds`` is the *flat sorted* [1, nbits]
-    threshold vector (nbits = 8 * W_pad packed, L_pad unpacked; +inf
-    pads): eligibility is prefix-structured, see :func:`_prefix_te_table`.
-    ``seg_offsets`` rides as scalar prefetch; its last entry bounds the
-    tile loop. Returns (assigned int32 [total] — -1 on every padding
-    slot — and mb as for the waves wrapper). ``mb_init`` seeds the
-    resident bit block instead of zeros — shaped like the scratch
-    (``n_pad + SACRIFICIAL_ROWS`` rows; the sacrificial band must be
-    zero, though the kernel never reads it as a real vertex).
-    """
-    total = weights.shape[0]
-    bslots = seg_block * seg
-    block = tiles_per_block * bslots
-    assert total % block == 0, (total, tiles_per_block, seg_block, seg)
-    assert uv.shape[0] == 2 * total, (uv.shape, total)
-    nblocks = total // block
-    nbits = thresholds.shape[1]
-    n_rows = n_pad + SACRIFICIAL_ROWS
-    if packed:
-        width = nbits // 8
-        kernel_fn, dtype = _kernel_waves_mega_packed, jnp.uint8
-    else:
-        width = nbits
-        kernel_fn, dtype = _kernel_waves_mega, jnp.int8
-
-    kernel = functools.partial(
-        kernel_fn,
-        tiles_per_block=tiles_per_block,
-        bslots=bslots,
-        seg_block=seg_block,
-        n_out=n_pad,
-    )
-    in_specs = [
-        pl.BlockSpec((2 * block, 1), lambda b, offs: (b, 0)),  # uv stream
-        pl.BlockSpec((block, 1), lambda b, offs: (b, 0)),  # weights
-        pl.BlockSpec((1, nbits), lambda b, offs: (0, 0)),  # thresholds
     ]
-    operands = [seg_offsets, uv, weights.astype(jnp.float32), thresholds]
+    operands = [num_groups, slots]
+    scratch = []
     if mb_init is not None:
-        assert mb_init.shape == (n_rows, width), (mb_init.shape, n_rows, width)
-        in_specs.append(pl.BlockSpec((n_rows, width), lambda b, offs: (0, 0)))
-        operands.append(mb_init.astype(dtype))
+        assert mb_init.shape == (rows, width), (mb_init.shape, rows, width)
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+        operands.append(mb_init.astype(jnp.int32))
+        scratch.append(pltpu.SemaphoreType.DMA(()))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(nblocks,),
+        grid=(total // block,),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((block, 1), lambda b, offs: (b, 0)),
-            pl.BlockSpec((n_pad, width), lambda b, offs: (0, 0)),
+            pl.BlockSpec((block,), lambda b, bound: (b,), memory_space=smem),
+            pl.BlockSpec((rows, width), lambda b, bound: (0, 0)),
         ],
-        scratch_shapes=[pltpu.VMEM((n_rows, width), dtype)],
+        scratch_shapes=scratch,
     )
-    assigned, mb = pl.pallas_call(
+    kernel = functools.partial(
+        _kernel, group=group, groups_per_block=groups_per_block,
+        lanes=lanes, bits=bits,
+    )
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((total, 1), jnp.int32),
-            jax.ShapeDtypeStruct((n_pad, width), dtype),
+            jax.ShapeDtypeStruct((total,), jnp.int32),
+            jax.ShapeDtypeStruct((rows, width), jnp.int32),
         ],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem_limit,
         ),
+        name="substream_match",
     )(*operands)
-    return assigned[:, 0], mb
-
-
-def substream_match_pallas_waves(
-    edges: jax.Array,  # int32 [num_segments_pad * seg, 2], packed slot layout
-    weights: jax.Array,  # f32 [num_segments_pad * seg, 1]; padding slots are 0
-    thresholds: jax.Array,  # f32 [1, L_pad] unpacked / [8, W_pad] packed
-    n_pad: int,
-    seg: int,
-    block_s: int,
-    interpret: bool = True,
-    packed: bool = True,
-    mb_init: jax.Array | None = None,  # [n_pad + SACRIFICIAL_ROWS, width]
-):
-    """Raw pallas_call wrapper for the segment-vectorized kernels.
-
-    ``edges``/``weights`` are the fill-packed *slot* stream:
-    ``num_segments_pad`` segments of exactly ``seg`` slots each (see
-    ``repro.graph.waves`` — waves packed back-to-back, each padded only
-    to the next ``seg`` multiple), flattened row-major. Padding slots
-    MUST encode ``u = v = n_pad`` (the sacrificial bit-block row) with
-    ``w = 0``: the in-place row scatter requires duplicate row indices
-    to carry identical values, which a padding alias of a real vertex
-    row would break. The grid walks blocks of ``block_s`` segments;
-    ``assigned`` comes back per slot (callers scatter it to stream
-    positions via the schedule's slot map). Returns (assigned int32
-    [num_segments_pad * seg], mb — uint8 [n_pad, W_pad] packed /
-    int8 [n_pad, L_pad] unpacked; the sacrificial band is not flushed).
-    ``mb_init`` seeds the resident bit block instead of zeros — shaped
-    like the scratch (``n_pad + SACRIFICIAL_ROWS`` rows, sacrificial
-    band zero).
-    """
-    total = edges.shape[0]
-    block = block_s * seg
-    assert total % block == 0, (total, block_s, seg)
-    nblocks = total // block
-    width = thresholds.shape[1]
-    n_rows = n_pad + SACRIFICIAL_ROWS
-    if packed:
-        assert thresholds.shape[0] == 8, thresholds.shape
-        kernel_fn, dtype = _kernel_waves_packed, jnp.uint8
-    else:
-        assert thresholds.shape[0] == 1, thresholds.shape
-        kernel_fn, dtype = _kernel_waves, jnp.int8
-
-    in_specs = [
-        pl.BlockSpec((block, 2), lambda b: (b, 0)),  # segment block (pipelined)
-        pl.BlockSpec((block, 1), lambda b: (b, 0)),  # weight block
-        pl.BlockSpec(thresholds.shape, lambda b: (0, 0)),  # thresholds
-    ]
-    operands = [edges, weights.astype(jnp.float32), thresholds]
-    if mb_init is not None:
-        assert mb_init.shape == (n_rows, width), (mb_init.shape, n_rows, width)
-        in_specs.append(pl.BlockSpec((n_rows, width), lambda b: (0, 0)))
-        operands.append(mb_init.astype(dtype))
-
-    kernel = functools.partial(kernel_fn, block_s=block_s, seg=seg, n_out=n_pad)
-    assigned, mb = pl.pallas_call(
-        kernel,
-        grid=(nblocks,),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((block, 1), lambda b: (b, 0)),
-            pl.BlockSpec((n_pad, width), lambda b: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((total, 1), jnp.int32),
-            jax.ShapeDtypeStruct((n_pad, width), dtype),
-        ],
-        scratch_shapes=[pltpu.VMEM((n_rows, width), dtype)],
-        interpret=interpret,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary",),
-        ),
-    )(*operands)
-    return assigned[:, 0], mb

@@ -1,32 +1,40 @@
 """Typed / padded entry point for the substream_match Pallas kernel.
 
-VMEM accounting (the §4.3 "storage" analysis, TPU edition)
-----------------------------------------------------------
-A TPU v5e core has ~16 MiB of VMEM (``VMEM_PER_CORE``). Of that we
-reserve ``VMEM_BIT_BUDGET`` (12 MiB) for the resident matching-bit
-block and leave the remainder for the edge-stream double buffers that
-the Pallas grid pipeline allocates (edges + weights in, assigned out).
+Memory accounting (the §4.3 "storage" analysis, TPU edition)
+------------------------------------------------------------
+The kernel keeps the matching-bit block resident in VMEM and streams the
+slots through SMEM (see :mod:`repro.kernels.substream_match.kernel`).
+Mosaic tiles 32-bit data as (8, 128), so the block is int32
+``[rows, 128]`` with each vertex's words folded into one lane range of
+a row; :func:`vmem_plan` counts it exactly as the compiler allocates it:
+rows padded to 8 sublanes, lanes to 128. The block is the only VMEM
+buffer the kernel asks for (it is the pallas_call's resident output, so
+it is single-buffered), and each kernel passes ``vmem_limit_bytes`` =
+block + ``VMEM_HEADROOM``. ``VMEM_BIT_BUDGET`` (12 MiB) caps the block
+below v5e's default 16 MiB scoped VMEM limit (``VMEM_PER_CORE``).
 
 Two matching-bit layouts are supported (see :mod:`repro.core.bitpack`):
 
-* ``packed`` (default) — ``mb[n_pad, ceil(L/8)]`` uint8, bit ``j`` of
-  word ``k`` = substream ``8k + j``. One byte stores 8 substreams, the
-  direct analogue of the paper's L-bit BRAM word; capacity per core is
-  8x the unpacked layout (≥ 8x more vertices at any L; 16x at L = 64,
-  where the unpacked layout also pays lane padding 64 -> 128).
-* ``unpacked`` — ``mb[n_pad, L_pad]`` int8, one byte per substream bit.
-  Legacy fallback, selected with ``SubstreamConfig(mb_layout="unpacked")``
-  or ``substream_match(..., packed=False)``.
+* ``packed`` (default) — 32 substreams per int32 word, ``ceil(L/32)``
+  words per vertex rounded up to a power of two: 8 bytes per vertex at
+  L = 64 (64 vertices per row). Callers see uint8 ``[n, ceil(L/8)]``,
+  bit ``j`` of word ``k`` = substream ``8k + j``; the conversion is a
+  little-endian split of the 32-bit words at this module's boundary.
+* ``unpacked`` — one int32 word (0/1) per substream, ``L`` words per
+  vertex rounded up to a power of two (256 bytes per vertex at L = 64).
+  Legacy layout, selected with ``SubstreamConfig(mb_layout="unpacked")``
+  or ``substream_match(..., packed=False)``; callers see bool ``[n, L]``.
 
-:func:`vmem_plan` is the single source of truth for the block geometry:
-it reports the padded shape and byte footprint of the bit block for
-either layout and auto-picks ``block_e`` — the edge-block length — from
-the VMEM budget the bit block leaves free. Both the kernel wrapper and
-the capacity benchmarks (`benchmarks/table6_memory.py`) consume it.
+The slot stream (``(u, v, cnt)`` in, ``assigned`` out, int32, double
+buffered) lives in SMEM, 1 MiB on v5e; blocks of more than one program
+must be a multiple of 1024 slots (SMEM tiles 1-D int32 by 1024).
+:func:`vmem_plan` picks ``block_e`` inside both limits.
 """
 from __future__ import annotations
 
 import dataclasses
+import math
+import warnings
 from functools import partial
 
 import jax
@@ -38,24 +46,45 @@ from repro.core import bitpack
 from repro.core.types import EdgeStream, MatchingResult, SubstreamConfig
 from repro.kernels.substream_match import kernel as _kernel
 
-VMEM_PER_CORE = 16 * 2**20  # usable VMEM on a v5e core
+VMEM_PER_CORE = 16 * 2**20  # v5e default scoped VMEM limit per core
 VMEM_BIT_BUDGET = 12 * 2**20  # bytes reserved for the matching-bit block
-_EDGE_BYTES = 2 * (2 * 4 + 4 + 4)  # (src,dst) i32 + w f32 + assigned i32, x2 buffers
+VMEM_HEADROOM = 2**20  # compiler-internal scratch on top of the bit block
+SMEM_PER_CORE = 2**20  # v5e scalar memory per core
+#: SMEM bytes per slot of a grid block: (u, v, cnt) in + assigned out,
+#: int32, double-buffered by the grid pipeline.
+SLOT_SMEM_BYTES = 2 * 4 * (_kernel.SLOT_WORDS + 1)
+#: Slots per grid program at most: bounds per-program latency and keeps
+#: the slot blocks at a quarter of SMEM.
+MAX_BLOCK = 8192
+#: SMEM tiles 1-D int32 arrays by 1024 words: a block that is not the
+#: whole stream must be a multiple of this many slots.
+SMEM_ALIGN = 1024
 
 
 def _round_up(x: int, mult: int) -> int:
     return ((x + mult - 1) // mult) * mult
 
 
+def _vertex_lanes(L: int, packed: bool) -> int:
+    """int32 words per vertex: ``ceil(L/32)`` packed or ``L`` unpacked,
+    rounded up to a power of two (so vertices tile a 128-lane row) or,
+    past one row, to a multiple of 128."""
+    need = -(-max(L, 1) // 32) if packed else max(L, 1)
+    if need <= _kernel.LANES:
+        return 1 << (need - 1).bit_length()
+    return _round_up(need, _kernel.LANES)
+
+
 @dataclasses.dataclass(frozen=True)
 class VmemPlan:
     """Geometry + budget of the VMEM matching-bit block.
 
-    ``width`` is the padded per-vertex row in bytes (``L_pad`` int8 lanes
-    unpacked; ``ceil(L/8)`` rounded up to 8 uint8 words packed), ``words``
-    the logical (unpadded) row length, ``nbytes = n_pad * width`` the block
-    footprint, and ``block_e`` the auto-picked edge-block length (see
-    :func:`vmem_plan` for the selection rule).
+    ``n_pad`` is the vertex capacity of the folded block, ``width`` the
+    bytes one vertex occupies (``lanes * 4``), ``words`` the caller-format
+    row length (``ceil(L/8)`` bytes packed, ``L`` bools unpacked),
+    ``nbytes = n_pad * width`` the VMEM the compiler allocates for the
+    block (8-sublane, 128-lane tiled), and ``block_e`` the slots per
+    grid program (see :func:`vmem_plan` for the selection rule).
     """
 
     n_pad: int
@@ -69,6 +98,47 @@ class VmemPlan:
     def bytes_per_vertex(self) -> int:
         return self.width
 
+    @property
+    def lanes(self) -> int:
+        """int32 words per vertex."""
+        return self.width // 4
+
+    @property
+    def bits(self) -> int:
+        """Substreams per int32 word: 32 packed, 1 unpacked."""
+        return 32 if self.packed else 1
+
+    @property
+    def row_width(self) -> int:
+        """Lanes per row of the folded block."""
+        return max(self.lanes, _kernel.LANES)
+
+    @property
+    def rows(self) -> int:
+        return self.nbytes // (4 * self.row_width)
+
+    @property
+    def vmem_limit(self) -> int:
+        """The ``vmem_limit_bytes`` the kernel compiles with."""
+        return self.nbytes + VMEM_HEADROOM
+
+
+def _slot_block(group: int, groups: int | None, name: str, knob: str) -> int:
+    """Groups per grid program: the most that fit ``MAX_BLOCK`` slots,
+    rounded to whole ``SMEM_ALIGN`` blocks where possible, never more
+    than the ``groups`` the stream has. Errors name the knob to change."""
+    if group * SLOT_SMEM_BYTES > SMEM_PER_CORE:
+        raise ValueError(
+            f"{name} of {group} slots needs {group * SLOT_SMEM_BYTES} B of "
+            f"slot buffers, more than the {SMEM_PER_CORE} B of SMEM; "
+            f"rebuild with a smaller {knob}"
+        )
+    per = max(1, MAX_BLOCK // group)
+    align = SMEM_ALIGN // math.gcd(group, SMEM_ALIGN)
+    if per >= align:
+        per -= per % align
+    return max(1, min(per, max(groups or 1, 1)))
+
 
 def vmem_plan(
     n: int,
@@ -79,65 +149,68 @@ def vmem_plan(
 ) -> VmemPlan:
     """Plan the VMEM bit block for ``n`` vertices and ``L`` substreams.
 
-    The auto ``block_e`` is min over three constraints (power of two,
-    floor 128): the VMEM the bit block leaves free at ``_EDGE_BYTES``
-    per edge, an 8192 cap bounding per-program pipeline latency, and —
-    when the stream length ``m`` is given — the smallest power of two
-    covering ``m``, so short streams are not padded to a huge block.
-    Since the bit block is capped at 12 of 16 MiB, at least 4 MiB stays
-    free and the VMEM constraint only binds below ~256 KiB of headroom;
-    in practice the 8192 cap or ``m`` decides.
+    The auto ``block_e`` is a power of two, at least 128: ``MAX_BLOCK``,
+    or — when the stream length ``m`` is given — the smallest power of
+    two covering ``m`` if that is less, so short streams run as one
+    program without padding to a huge block. Both choices satisfy the
+    SMEM rules (a whole-stream block, or a multiple of ``SMEM_ALIGN``).
+    An explicit ``block_e`` under ``SMEM_ALIGN`` on a stream of several
+    blocks runs in interpret mode only.
     """
-    n_pad = _round_up(max(n, 1), 8)
-    if packed:
-        words = bitpack.packed_width(max(L, 1))
-        width = _round_up(words, 8)
-    else:
-        words = max(L, 1)
-        width = _round_up(words, 128)
-    nbytes = n_pad * width
+    lanes = _vertex_lanes(L, packed)
+    fold = max(_kernel.LANES // lanes, 1)
+    rows = _round_up(-(-max(n, 1) // fold), 8)
+    words = bitpack.packed_width(max(L, 1)) if packed else max(L, 1)
     if block_e is None:
-        free = max(VMEM_PER_CORE - min(nbytes, VMEM_BIT_BUDGET), 2**20)
-        block_e = 1 << ((free // _EDGE_BYTES).bit_length() - 1)
-        block_e = min(block_e, 8192)
+        block_e = MAX_BLOCK
         if m is not None:
             block_e = min(block_e, 1 << max(m - 1, 1).bit_length())
         block_e = max(128, block_e)
     return VmemPlan(
-        n_pad=n_pad, width=width, words=words, nbytes=nbytes,
-        block_e=block_e, packed=packed,
+        n_pad=rows * fold, width=4 * lanes, words=words,
+        nbytes=rows * max(lanes, _kernel.LANES) * 4, block_e=block_e,
+        packed=packed,
     )
 
 
 def max_vertices(L: int, packed: bool = True, budget: int = VMEM_BIT_BUDGET) -> int:
     """Largest vertex count whose bit block fits ``budget`` bytes."""
-    width = vmem_plan(1, L, packed=packed).width
-    return (budget // width) // 8 * 8
+    plan = vmem_plan(1, L, packed=packed)
+    row_bytes = 4 * plan.row_width
+    return (budget // row_bytes) // 8 * 8 * (plan.n_pad // plan.rows)
 
 
 def resolve_interpret(interpret: bool | None) -> bool:
-    """``None`` = auto: interpret everywhere except on a real TPU backend.
+    """``None`` = auto: compiled on a TPU backend, interpret mode on the
+    CPU (the test path). Any other backend cannot run these kernels, so
+    auto raises there rather than sliding into the interpreter.
 
-    Explicit True/False always wins (debugging a kernel in interpret mode
-    on TPU, or forcing compilation in tests, stays possible). The flip
-    is no longer silent: :func:`substream_match` emits one structured
+    Explicit True/False always wins (debugging a kernel in interpret
+    mode on TPU, or forcing compilation, stays possible). The choice is
+    recorded: :func:`substream_match` emits one structured
     ``substream_match.backend`` telemetry event (backend, interpret,
     engine) per call, so bench JSON records which backend actually ran.
     """
     if interpret is None:
-        return jax.default_backend() != "tpu"
+        backend = jax.default_backend()
+        if backend not in ("tpu", "cpu"):
+            raise RuntimeError(
+                f"the Pallas engines run compiled on a TPU or interpreted "
+                f"on the CPU; the default backend is {backend!r}"
+            )
+        return backend != "tpu"
     return bool(interpret)
 
 
-#: Bytes one slot occupies in the kernel's HBM slot stream: (src, dst)
-#: int32 in, weight f32 in, assigned int32 out — a single buffer (the
-#: double-buffering of ``_EDGE_BYTES`` is a VMEM *capacity* cost, not
-#: extra HBM traffic).
+#: Bytes one slot occupies in the kernel's HBM slot stream: (u, v, cnt)
+#: int32 in, assigned int32 out — a single buffer (the double-buffering
+#: of ``SLOT_SMEM_BYTES`` is an SMEM *capacity* cost, not extra HBM
+#: traffic).
 SLOT_STREAM_BYTES = 16
 
 
 def traffic_bytes(total_slots: int, live_slots: int, width: int) -> int:
-    """Modeled per-call HBM traffic of the row-addressed kernels.
+    """Modeled per-call traffic of the row-addressed kernels.
 
     The slot stream in + assigned out (``SLOT_STREAM_BYTES`` per padded
     slot) plus the bit-block row traffic: two row gathers and two row
@@ -186,15 +259,13 @@ def plan_counters(plan: VmemPlan) -> dict:
 class WavePlan(VmemPlan):
     """VmemPlan plus the segment-pipeline geometry.
 
-    ``seg`` is the fixed slot count per segment tile (the schedule's
+    ``seg`` is the fixed slot count per segment (the schedule's
     fill-packed row width), ``num_waves``/``num_segments`` the
     schedule's true wave count and packed row count, ``block_s`` how
     many segments one grid program consumes (so ``block_e = block_s *
-    seg`` slots), ``gather_bytes`` the VMEM the per-trip [seg, width]
-    gather/compute tiles add on top of the resident bit block —
-    accounted against ``VMEM_PER_CORE`` by :func:`wave_plan` — and
-    ``fill`` the schedule's slot fill (fraction of slots holding a real
-    edge).
+    seg`` slots), ``gather_bytes`` the SMEM the grid pipeline allocates
+    for the double-buffered slot and assigned blocks, and ``fill`` the
+    schedule's slot fill (fraction of slots holding a real edge).
     """
 
     seg: int
@@ -206,10 +277,9 @@ class WavePlan(VmemPlan):
     #: Mega-path geometry (zero on plain wave plans): ``seg_block``
     #: segments per tile, ``num_tiles`` real tiles in the block-aligned
     #: layout, ``tiles_per_block`` tiles per grid program, and
-    #: ``tile_bytes`` the single-buffer working set of one in-flight
-    #: tile — ``gather_bytes`` on a mega plan is ``2 * tile_bytes``
-    #: (double-buffered: the gather of tile k+1 overlaps the
-    #: compute/scatter of tile k).
+    #: ``tile_bytes`` one buffer of the slot pipeline — ``gather_bytes``
+    #: is ``2 * tile_bytes`` (double-buffered: the copy of block b+1
+    #: overlaps the trips of block b).
     seg_block: int = 0
     num_tiles: int = 0
     tiles_per_block: int = 0
@@ -223,52 +293,28 @@ def wave_plan(
     packed: bool = True,
     block_s: int | None = None,
 ) -> WavePlan:
-    """Plan VMEM for the segment-vectorized kernel over ``schedule``.
+    """Plan the segment engine over ``schedule``.
 
-    On top of the bit block (see :func:`vmem_plan`; plus one 8-row
-    sacrificial band for padding slots) the kernel keeps per-segment
-    tiles resident while a trip is in flight: the two gathered
-    endpoint-row tiles, the eligibility/add tiles, the [seg, 8, width]
-    bool bit-plane compare, and the [seg]-sized edge/weight/assigned
-    vectors — ~12 tiles of ``seg * width`` bytes between them. The tile
-    size is the *segment*, so the footprint no longer scales with the
-    largest wave: gather bytes are per trip, proportional to ``seg``.
-    The auto ``block_s`` targets ~512 slots per grid program (the
-    measured interpret-mode sweet spot; a short latency envelope on
-    hardware), never exceeds the schedule's segment count, and shrinks
-    until the double-buffered slot-stream blocks fit the VMEM the bit
-    block and gather tiles leave free. Errors name the knob that must
-    change.
+    The bit block is :func:`vmem_plan`'s. One trip handles one segment
+    of ``seg`` vertex-disjoint slots, so nothing per trip is allocated;
+    the grid pipeline double-buffers ``block_s`` segments of slots in
+    SMEM (``gather_bytes``). The auto ``block_s`` fills ``MAX_BLOCK``
+    slots, never exceeds the schedule's segment count, and keeps a
+    multi-program block a multiple of ``SMEM_ALIGN`` slots. Errors name
+    the knob that must change.
     """
     seg = int(schedule.width)
-    num_waves = int(schedule.num_waves)
     num_segments = int(schedule.num_segments)
     base = vmem_plan(n, L, packed=packed, block_e=1)
-    gather_bytes = 12 * seg * base.width + 24 * seg
-    free = VMEM_PER_CORE - min(base.nbytes, VMEM_BIT_BUDGET)
-    # blame the segment tiles only when they are the culprit: a bit block
-    # over VMEM_BIT_BUDGET is the caller's (vertex-partitioning) problem
-    # and is reported by substream_match's budget check instead
-    if gather_bytes > free:
+    auto = _slot_block(
+        seg, num_segments, "a segment", "seg (repro.graph.waves.wave_schedule(seg=...))"
+    )
+    block_s = auto if block_s is None else block_s
+    gather_bytes = block_s * seg * SLOT_SMEM_BYTES
+    if gather_bytes > SMEM_PER_CORE:
         raise ValueError(
-            f"segment tiles ({gather_bytes} B at seg={seg}) + bit block "
-            f"({base.nbytes} B) exceed VMEM; rebuild the schedule with a "
-            f"smaller seg (repro.graph.waves.wave_schedule(seg=...))"
-        )
-    stream_free = free - gather_bytes
-    if block_s is None:
-        # ~512 slots per grid program: measured sweet spot of the
-        # interpret-mode pipeline (smaller per-program input copies) and
-        # a short enough latency envelope on hardware
-        block_s = max(1, min(512 // seg, 256))
-        block_s = min(block_s, max(num_segments, 1))
-        while block_s > 1 and block_s * seg * _EDGE_BYTES > stream_free:
-            block_s //= 2
-    if block_s * seg * _EDGE_BYTES > stream_free:
-        raise ValueError(
-            f"slot-stream blocks ({block_s * seg * _EDGE_BYTES} B at "
-            f"block_s={block_s}, seg={seg}) exceed the VMEM left by the "
-            f"bit block and segment tiles ({stream_free} B); lower "
+            f"slot-stream blocks ({gather_bytes} B at block_s={block_s}, "
+            f"seg={seg}) exceed the {SMEM_PER_CORE} B of SMEM; lower "
             f"block_s (ops.wave_plan) or seg "
             f"(repro.graph.waves.wave_schedule(seg=...))"
         )
@@ -280,7 +326,7 @@ def wave_plan(
         block_e=block_s * seg,
         packed=packed,
         seg=seg,
-        num_waves=num_waves,
+        num_waves=int(schedule.num_waves),
         num_segments=num_segments,
         block_s=block_s,
         gather_bytes=gather_bytes,
@@ -288,10 +334,9 @@ def wave_plan(
     )
 
 
-#: Default segments per megakernel tile. Measured sweet spot of the
-#: tile-count / slot-inflation trade (block-aligned padding grows with
-#: ``seg_block`` while sequential tile trips shrink as ``1/seg_block``);
-#: 2 wins at every benchmarked scale.
+#: Default segments per megakernel tile: two 8-slot segments make one
+#: 16-slot vertex-disjoint tile. Chosen against the interpreter; not yet
+#: re-derived on the chip.
 MEGA_SEG_BLOCK = 2
 
 
@@ -302,61 +347,33 @@ def mega_plan(
     packed: bool = True,
     tiles_per_block: int | None = None,
 ) -> WavePlan:
-    """Plan VMEM for the grid-pipelined megakernel over ``layout``
-    (a :class:`repro.graph.waves.BlockAlignedLayout`).
+    """Plan the megakernel over ``layout`` (a
+    :class:`repro.graph.waves.BlockAlignedLayout`).
 
-    On top of the resident bit block (plus the sacrificial band) the
-    megakernel keeps one tile's working set in flight — the
-    [2*bslots, width] gathered rows, eligibility/add tiles, the
-    [bslots, 8, width] bit-plane compare, and the index/weight/assigned
-    vectors, ~14 ``width``-wide arrays of ``bslots = seg_block * seg``
-    rows plus 24 B/slot of vectors = ``tile_bytes``. The plan charges
-    **2x** that (``gather_bytes``): the grid pipeline prefetches the
-    next block's stream while the current tile computes, so two tile
-    buffers coexist. The auto ``tiles_per_block`` is the measured
-    interpret-mode sweet spot (64 tiles per program for short layouts,
-    stepping to 128/256 as the tile count grows), clamped to the layout
-    and halved until the double-buffered slot-stream blocks fit the
-    VMEM left over.
+    Same accounting as :func:`wave_plan` with the tile (``seg_block *
+    seg`` slots) as the trip: ``tile_bytes`` is one buffer of the slot
+    pipeline's SMEM blocks and ``gather_bytes`` the double-buffered
+    pair. The auto ``tiles_per_block`` fills ``MAX_BLOCK`` slots,
+    clamped to the layout's tile count and ``SMEM_ALIGN``-aligned where
+    the stream spans several programs.
     """
     seg = int(layout.width)
     seg_block = int(layout.seg_block)
     bslots = seg_block * seg
     num_tiles = int(layout.num_tiles)
     base = vmem_plan(n, L, packed=packed, block_e=1)
-    tile_bytes = 14 * bslots * base.width + 24 * bslots
-    gather_bytes = 2 * tile_bytes  # double-buffered tile working sets
-    free = VMEM_PER_CORE - min(base.nbytes, VMEM_BIT_BUDGET)
-    if gather_bytes > free:
-        raise ValueError(
-            f"double-buffered mega tiles ({gather_bytes} B at "
-            f"seg_block={seg_block}, seg={seg}) + bit block ({base.nbytes} B) "
-            f"exceed VMEM; rebuild the layout with a smaller seg_block "
-            f"(repro.graph.waves.block_aligned_layout)"
-        )
-    stream_free = free - gather_bytes
     if tiles_per_block is None:
-        # measured interpret-mode sweet spots: short layouts want small
-        # per-program input copies, long ones amortize program overhead
-        if num_tiles <= 1024:
-            tiles_per_block = 64
-        elif num_tiles <= 4096:
-            tiles_per_block = 128
-        else:
-            tiles_per_block = 256
-        tiles_per_block = max(1, min(tiles_per_block, num_tiles))
-        while (
-            tiles_per_block > 1
-            and tiles_per_block * bslots * _EDGE_BYTES > stream_free
-        ):
-            tiles_per_block //= 2
-    if tiles_per_block * bslots * _EDGE_BYTES > stream_free:
+        tiles_per_block = _slot_block(
+            bslots, num_tiles, "a tile",
+            "seg_block (repro.graph.waves.block_aligned_layout)",
+        )
+    tile_bytes = tiles_per_block * bslots * SLOT_SMEM_BYTES // 2
+    if 2 * tile_bytes > SMEM_PER_CORE:
         raise ValueError(
-            f"slot-stream blocks ({tiles_per_block * bslots * _EDGE_BYTES} B "
-            f"at tiles_per_block={tiles_per_block}, seg_block={seg_block}, "
-            f"seg={seg}) exceed the VMEM left by the bit block and tile "
-            f"buffers ({stream_free} B); lower tiles_per_block "
-            f"(ops.mega_plan) or seg_block"
+            f"slot-stream blocks ({2 * tile_bytes} B at "
+            f"tiles_per_block={tiles_per_block}, seg_block={seg_block}, "
+            f"seg={seg}) exceed the {SMEM_PER_CORE} B of SMEM; lower "
+            f"tiles_per_block (ops.mega_plan) or seg_block"
         )
     return WavePlan(
         n_pad=base.n_pad,
@@ -369,7 +386,7 @@ def mega_plan(
         num_waves=int(layout.seg_offsets.shape[0] - 1),
         num_segments=int(layout.num_segments),
         block_s=tiles_per_block * seg_block,
-        gather_bytes=gather_bytes,
+        gather_bytes=2 * tile_bytes,
         fill=float(layout.fill),
         seg_block=seg_block,
         num_tiles=num_tiles,
@@ -386,15 +403,9 @@ def _resolve_packed(cfg: SubstreamConfig, packed: bool | None) -> bool:
     return packed
 
 
-def _thresholds_padded(cfg: SubstreamConfig, width: int, packed: bool) -> jax.Array:
-    """Kernel-shaped threshold array: [8, width] bit planes (packed,
-    thr[j, k] = substream 8k+j) or [1, width] lanes (unpacked); +inf pads."""
-    thr = cfg.thresholds()
-    if packed:
-        nbits = width * 8
-        thr_flat = jnp.full((nbits,), jnp.inf, jnp.float32).at[: cfg.L].set(thr)
-        return thr_flat.reshape(width, 8).T
-    return jnp.full((1, width), jnp.inf, jnp.float32).at[0, : cfg.L].set(thr)
+class EngineFallbackWarning(RuntimeWarning):
+    """An engine of the ``on_plan_failure="fallback"`` cascade failed and
+    the next one was tried."""
 
 
 class FallbackExhaustedError(RuntimeError):
@@ -423,17 +434,6 @@ def _empty_result(stream: EdgeStream, cfg: SubstreamConfig, packed: bool):
             L=cfg.L,
         )
     return MatchingResult(assigned=assigned, mb=jnp.zeros((0, cfg.L), bool))
-
-
-def _mb0_pad(mb0, n, words, rows, width, packed):
-    """Pad a caller-format initial bit block (uint8 [n, words] packed /
-    bool [n, L] dense) to the kernel scratch shape [rows, width]; the
-    padding band (incl. the sacrificial rows) is zero — padding slots
-    carry w = 0, so those bits are never set nor read."""
-    dtype = jnp.uint8 if packed else jnp.int8
-    return (
-        jnp.zeros((rows, width), dtype).at[:n, :words].set(mb0.astype(dtype))
-    )
 
 
 def _mb0_dense(mb0, cfg: SubstreamConfig, packed: bool):
@@ -566,7 +566,8 @@ def _substream_match_fallback(
     """The fallback cascade resolver (``on_plan_failure="fallback"``).
 
     Runs the :func:`_fallback_attempts` ladder until an engine returns a
-    result. Every failure is observable: a ``fallback`` instant event
+    result. Every failure is observable: an :class:`EngineFallbackWarning`
+    (always, telemetry on or off), a ``fallback`` instant event
     (from_engine, to_engine, reason) plus the ``fallback.count`` session
     counter, and each degraded attempt runs inside a ``fallback`` span.
     The per-call :class:`repro.obs.MatchTelemetry` record of the engine
@@ -600,8 +601,16 @@ def _substream_match_fallback(
             raise
         except Exception as err:  # noqa: BLE001 — availability cascade
             failures.append((label, err))
+            nxt = attempts[idx + 1][2] if idx + 1 < len(attempts) else None
+            # never silent: the warning fires with telemetry off too, so a
+            # refused kernel cannot pass for the engine that replaced it
+            warnings.warn(
+                f"engine {label} failed ({type(err).__name__}: {err}); "
+                f"falling back to {nxt}",
+                EngineFallbackWarning,
+                stacklevel=3,
+            )
             if telemetry.enabled:
-                nxt = attempts[idx + 1][2] if idx + 1 < len(attempts) else None
                 telemetry.event(
                     "fallback",
                     from_engine=label,
@@ -647,26 +656,25 @@ def substream_match(
     ``schedule`` picks the pipeline:
 
     * ``"edges"`` — the paper-faithful 1-edge-per-iteration processor;
-    * ``"waves"`` — the wave-vectorized processor: the stream is first
-      decomposed into vertex-disjoint waves (``repro.graph.waves``) on
-      the host, then each wave updates the bit block as one [W, width]
-      tile op. Bit-identical to ``"edges"`` (greedy matching is
-      confluent over vertex-disjoint edges) with ``#waves`` instead of
-      ``m`` inner-loop trips. Pass a precomputed ``waves`` schedule to
-      amortize the decomposition across runs; ``max_width`` caps the
-      wave width when building one here.
-    * ``"mega"`` — the grid-pipelined megakernel: the wave schedule is
-      re-padded block-aligned (every tile of ``seg_block`` segments is a
-      subset of one wave, hence vertex-disjoint) and each trip processes
-      one whole tile with the bit block carried functionally through the
-      loop. Same bit-identical contract as ``"waves"``, ~``seg_block``x
+    * ``"waves"`` — the wave processor: the stream is first decomposed
+      into vertex-disjoint waves (``repro.graph.waves``) on the host,
+      packed into ``seg``-slot segments; each kernel trip loads the
+      rows of one whole segment before it stores any. Bit-identical to
+      ``"edges"`` (greedy matching is confluent over vertex-disjoint
+      edges). Pass a precomputed ``waves`` schedule to amortize the
+      decomposition across runs; ``max_width`` caps the wave width when
+      building one here.
+    * ``"mega"`` — the megakernel: the wave schedule is re-padded
+      block-aligned (every tile of ``seg_block`` segments is a subset of
+      one wave, hence vertex-disjoint) and each trip processes one whole
+      tile. Same bit-identical contract as ``"waves"``, ~``seg_block``x
       fewer sequential trips; ``seg_block=None`` takes
       :data:`MEGA_SEG_BLOCK`.
 
     ``packed=None`` follows ``cfg.mb_layout``; ``block_e=None`` takes the
     auto-picked value from :func:`vmem_plan` (edges schedule only).
-    ``interpret=None`` = auto: interpret everywhere except on a real TPU
-    backend (:func:`resolve_interpret`). The packed result carries
+    ``interpret=None`` = auto: compiled on a TPU backend, interpreted on
+    the CPU (:func:`resolve_interpret`). The packed result carries
     ``mb_packed`` (uint8 bit planes) and unpacks to the bool ``mb`` view
     lazily; both layouts are bit-identical in ``assigned`` and ``mb``.
 
@@ -686,8 +694,9 @@ def substream_match(
     the Pallas path fails: ``"raise"`` (default, today's behavior)
     propagates; ``"fallback"`` degrades through the cascade — shrunk
     ``seg_block``/``block_s`` first, then mega -> waves -> ``waves_xla``
-    -> the scan oracle — emitting ``fallback`` spans/events/counters so
-    the degradation is observable, never silent. ``block_s`` caps the
+    -> the scan oracle — emitting an :class:`EngineFallbackWarning` and
+    ``fallback`` spans/events/counters so the degradation is observable,
+    never silent. ``block_s`` caps the
     wave path's segments-per-program (``None`` = the plan's auto pick).
 
     With ``on_plan_failure="raise"``, raises if the bit block exceeds
@@ -743,6 +752,76 @@ def substream_match(
     )
 
 
+def _to_block(mb0, plan: VmemPlan, cfg: SubstreamConfig) -> jax.Array:
+    """Caller-format initial bits (uint8 [n, ceil(L/8)] packed / bool
+    [n, L] dense) -> the kernel's folded int32 [rows, row_width] block.
+    Packed bytes combine little-endian into 32-bit words (byte k of a
+    vertex -> bits 8*(k%4).. of word k//4), so substream 8k+j stays bit
+    j of byte k. Padding vertices and lanes are zero."""
+    n = cfg.n
+    if plan.packed:
+        b = jnp.zeros((n, 4 * plan.lanes), jnp.uint32)
+        b = b.at[:, : plan.words].set(jnp.asarray(mb0).astype(jnp.uint32))
+        b = b.reshape(n, plan.lanes, 4) << jnp.arange(0, 32, 8, dtype=jnp.uint32)
+        words = jax.lax.bitcast_convert_type(b.sum(axis=2, dtype=jnp.uint32), jnp.int32)
+    else:
+        words = jnp.zeros((n, plan.lanes), jnp.int32)
+        words = words.at[:, : cfg.L].set(jnp.asarray(mb0).astype(jnp.int32))
+    block = jnp.zeros((plan.n_pad, plan.lanes), jnp.int32).at[:n].set(words)
+    return block.reshape(plan.rows, plan.row_width)
+
+
+def _from_block(mb: jax.Array, plan: VmemPlan, cfg: SubstreamConfig):
+    """The kernel's folded block -> caller format: uint8 [n, ceil(L/8)]
+    (little-endian bytes of the 32-bit words) packed, bool [n, L]
+    unpacked. Inverse of :func:`_to_block` on the first L bits."""
+    words = mb.reshape(plan.n_pad, plan.lanes)[: cfg.n]
+    if not plan.packed:
+        return words[:, : cfg.L] != 0
+    u = jax.lax.bitcast_convert_type(words, jnp.uint32)
+    b = (u[:, :, None] >> jnp.arange(0, 32, 8, dtype=jnp.uint32)) & 0xFF
+    return b.reshape(cfg.n, 4 * plan.lanes)[:, : plan.words].astype(jnp.uint8)
+
+
+def _match_slots(u, v, w, num_groups, cfg, plan, group, groups_per_block,
+                 interpret, mb0):
+    """Traced core shared by the three engines: Stage 4's threshold
+    count per slot (0 for self-loops and w <= 0), the kernel over the
+    interleaved (u, v, cnt) stream, and the caller-format bits."""
+    thr = cfg.thresholds()
+    cnt = jnp.sum(w[:, None] >= thr[None, :], axis=1, dtype=jnp.int32)
+    cnt = jnp.where(u != v, cnt, 0)
+    slots = jnp.stack([u, v, cnt], axis=1).reshape(-1)
+    assigned, mb = _kernel.substream_match_pallas(
+        slots,
+        jnp.full((1,), num_groups, jnp.int32),
+        rows=plan.rows,
+        width=plan.row_width,
+        lanes=plan.lanes,
+        bits=plan.bits,
+        group=group,
+        groups_per_block=groups_per_block,
+        vmem_limit=plan.vmem_limit,
+        interpret=interpret,
+        mb_init=None if mb0 is None else _to_block(mb0, plan, cfg),
+    )
+    return assigned, _from_block(mb, plan, cfg)
+
+
+def _result(assigned, mb, cfg: SubstreamConfig, packed: bool) -> MatchingResult:
+    if packed:
+        return MatchingResult(assigned=assigned, mb_packed=mb, L=cfg.L)
+    return MatchingResult(assigned=assigned, mb=mb)
+
+
+def _check_budget(plan: VmemPlan) -> None:
+    if plan.nbytes > VMEM_BIT_BUDGET:
+        raise ValueError(
+            f"matching-bit block {plan.nbytes/2**20:.1f} MiB > VMEM budget; "
+            f"use repro.core.rounds with vertex partitioning"
+        )
+
+
 def _edges_entry(
     stream: EdgeStream,
     cfg: SubstreamConfig,
@@ -753,8 +832,8 @@ def _edges_entry(
     mb0=None,
 ) -> MatchingResult:
     """Telemetry shell of the per-edge engine (the jitted body is
-    :func:`_substream_match_edges`, unchanged). The edges path has no
-    host scheduling, so schedule/pack/layout stages stay 0."""
+    :func:`_substream_match_edges`). The edges path has no host
+    scheduling, so schedule/pack/layout stages stay 0."""
     m = stream.num_edges
     rec = obs.recorder(
         telemetry, "pallas_edges", m, jax.default_backend(), interpret
@@ -788,87 +867,123 @@ def _substream_match_edges(
     packed: bool,
     mb0: jax.Array | None = None,
 ) -> MatchingResult:
-    plan = vmem_plan(
-        cfg.n, cfg.L, packed=packed, block_e=block_e, m=stream.num_edges
-    )
-    if plan.nbytes > VMEM_BIT_BUDGET:
-        raise ValueError(
-            f"matching-bit block {plan.nbytes/2**20:.1f} MiB > VMEM budget; "
-            f"use repro.core.rounds with vertex partitioning"
-        )
-    block_e = plan.block_e
+    """The per-edge engine: every stream position is a group of one slot,
+    in stream order."""
     m = stream.num_edges
-    # empty streams still run one block of no-op padding edges (u=v=0,
-    # w=0) so the kernel's init/flush executes and mb comes back zeroed
-    m_pad = _round_up(max(m, 1), block_e)
-    pad = m_pad - m
-
-    edges = jnp.stack([stream.src, stream.dst], axis=1).astype(jnp.int32)
+    plan = vmem_plan(cfg.n, cfg.L, packed=packed, block_e=block_e, m=m)
+    _check_budget(plan)
+    # empty streams still run one block, so the kernel's init executes
+    # and mb comes back zeroed; the trip bound (m) skips the padding
+    pad = _round_up(max(m, 1), plan.block_e) - m
+    u = jnp.pad(stream.src.astype(jnp.int32), (0, pad))
+    v = jnp.pad(stream.dst.astype(jnp.int32), (0, pad))
     # invalid edges -> weight 0 (< every threshold, since thresholds >= 1)
     w = jnp.where(stream.valid, stream.weight.astype(jnp.float32), 0.0)
-    if pad:
-        edges = jnp.concatenate([edges, jnp.zeros((pad, 2), jnp.int32)])
-        w = jnp.concatenate([w, jnp.zeros((pad,), jnp.float32)])
-    thr_pad = _thresholds_padded(cfg, plan.width, packed)
-    mb_init = (
-        None
-        if mb0 is None
-        else _mb0_pad(mb0, cfg.n, plan.words, plan.n_pad, plan.width, packed)
+    w = jnp.pad(w, (0, pad))
+    assigned, mb = _match_slots(
+        u, v, w, m, cfg, plan, 1, plan.block_e, interpret, mb0
     )
-
-    if packed:
-        assigned, mb = _kernel.substream_match_pallas_packed(
-            edges, w[:, None], thr_pad, plan.n_pad,
-            block_e=block_e, interpret=interpret, mb_init=mb_init,
-        )
-        return MatchingResult(
-            assigned=assigned[:m],
-            mb_packed=mb[: cfg.n, : plan.words],
-            L=cfg.L,
-        )
-
-    assigned, mb = _kernel.substream_match_pallas(
-        edges, w[:, None], thr_pad, plan.n_pad, block_e=block_e,
-        interpret=interpret, mb_init=mb_init,
-    )
-    return MatchingResult(
-        assigned=assigned[:m], mb=mb[: cfg.n, : cfg.L].astype(bool)
-    )
+    return _result(assigned[:m], mb, cfg, packed)
 
 
 @partial(
     jax.jit,
-    static_argnames=(
-        "cfg", "seg", "block_s", "n_pad", "width", "words", "interpret", "packed"
-    ),
+    static_argnames=("cfg", "plan", "group", "interpret"),
 )
-def _waves_device(
-    edges, w, cfg, seg, block_s, n_pad, width, words, interpret, packed,
-    mb0=None,
+def _slots_device(u, v, w, num_groups, cfg, plan, group, interpret, mb0=None):
+    """Jitted device half of the wave and mega paths: run the kernel over
+    the host-prepped slot stream (grid-padded, groups vertex-disjoint;
+    padding slots are ``u = v = 0, w = 0``). ``mb0`` (caller storage)
+    seeds the bit block."""
+    return _match_slots(
+        u, v, w, num_groups, cfg, plan, group, plan.block_e // group,
+        interpret, mb0,
+    )
+
+
+# Separate module attributes so a fault injector can fail one engine's
+# device half without touching the other's.
+_waves_device = _slots_device
+_mega_device = _slots_device
+
+
+def _kernel_plan(plan: VmemPlan) -> VmemPlan:
+    """The shape-determining part of a plan (a static jit argument that
+    does not change with the schedule's counters)."""
+    return VmemPlan(
+        n_pad=plan.n_pad, width=plan.width, words=plan.words,
+        nbytes=plan.nbytes, block_e=plan.block_e, packed=plan.packed,
+    )
+
+
+def _schedule_for(stream, waves, max_width, telemetry, rec):
+    """Resolve the wave schedule, recording its stage times."""
+    from repro.graph import waves as _waves
+
+    src = np.asarray(stream.src)
+    dst = np.asarray(stream.dst)
+    valid = np.asarray(stream.valid)
+    if waves is None:
+        # built in-call: the schedule's own stopwatch measurements are
+        # the stage split (assign -> "schedule", layout -> "pack")
+        sch = _waves.resolve_schedule(
+            src, dst, valid, schedule=None, max_width=max_width,
+            telemetry=telemetry,
+        )
+        rec.add_stage("schedule", sch.schedule_seconds)
+        rec.add_stage("pack", sch.pack_seconds)
+        return sch
+    with rec.stage("schedule"):  # precomputed: validation cost only
+        return _waves.resolve_schedule(
+            src, dst, valid, schedule=waves, max_width=max_width,
+            telemetry=telemetry,
+        )
+
+
+def _run_slot_layout(
+    stream, cfg, plan, slots, num_groups, group, device, engine, rec,
+    interpret, packed, mb0,
 ):
-    """Jitted device half of the wave path: run the segment kernel over
-    the host-prepped slot stream. ``edges``/``w`` are already
-    grid-padded with padding slots remapped to the sacrificial row (see
-    :func:`_substream_match_waves`, which also scatters the per-slot
-    assignments back to stream positions — a plain numpy indexed store,
-    since every stream position occupies exactly one slot). ``mb0``
-    (caller storage) seeds the resident bit block; the sacrificial band
-    pads with zeros."""
-    thr_pad = _thresholds_padded(cfg, width, packed)
-    rows = n_pad + _kernel.SACRIFICIAL_ROWS
-    mb_init = (
-        None
-        if mb0 is None
-        else _mb0_pad(mb0, cfg.n, words, rows, width, packed)
+    """Shared host half of the wave and mega paths. ``slots`` is the
+    [rows, seg] slot -> stream-position map (-1 = padding), already
+    grouped so each run of ``group`` slots is vertex-disjoint: gather
+    the slot stream (padding -> ``u = v = 0, w = 0``), pad it to whole
+    grid programs, run ``device``, and scatter the per-slot assignments
+    back to stream positions."""
+    with rec.stage("layout"):
+        flat = slots.reshape(-1)
+        live = flat >= 0
+        pos = flat[live]
+        total = _round_up(max(flat.size, 1), plan.block_e)
+        u = np.zeros(total, np.int32)
+        v = np.zeros(total, np.int32)
+        w = np.zeros(total, np.float32)
+        lv = np.zeros(total, bool)
+        lv[: flat.size] = live
+        u[lv] = np.asarray(stream.src)[pos]
+        v[lv] = np.asarray(stream.dst)[pos]
+        w[lv] = np.where(
+            np.asarray(stream.valid)[pos],
+            np.asarray(stream.weight)[pos].astype(np.float32),
+            0.0,
+        )
+    key = (
+        engine, group, _kernel_plan(plan), interpret, total, cfg,
+        mb0 is not None,
     )
-    assigned_slots, mb = _kernel.substream_match_pallas_waves(
-        edges, w, thr_pad, n_pad,
-        seg=seg, block_s=block_s, interpret=interpret, packed=packed,
-        mb_init=mb_init,
-    )
-    if packed:
-        return assigned_slots, mb[: cfg.n, :words]
-    return assigned_slots, mb[: cfg.n, : cfg.L].astype(bool)
+    with rec.device_stage(key):
+        assigned_slots, mb = device(
+            jnp.asarray(u), jnp.asarray(v), jnp.asarray(w), num_groups, cfg,
+            _kernel_plan(plan), group, interpret,
+            mb0=None if mb0 is None else jnp.asarray(mb0),
+        )
+        rec.block((assigned_slots, mb))
+    with rec.stage("layout"):
+        # slot -> stream-position scatter on the host: each stream position
+        # occupies exactly one slot, so this is a plain indexed store
+        assigned = np.full(stream.num_edges, -1, np.int32)
+        assigned[pos] = np.asarray(assigned_slots)[: flat.size][live]
+    return total, int(pos.size), _result(jnp.asarray(assigned), mb, cfg, packed)
 
 
 def _substream_match_waves(
@@ -888,134 +1003,20 @@ def _substream_match_waves(
         telemetry, "pallas_waves", stream.num_edges,
         jax.default_backend(), interpret,
     )
-    src = np.asarray(stream.src)
-    dst = np.asarray(stream.dst)
-    valid = np.asarray(stream.valid)
-    if waves is None:
-        # built in-call: the schedule's own stopwatch measurements are
-        # the stage split (assign -> "schedule", layout -> "pack")
-        waves = _waves.resolve_schedule(
-            src, dst, valid, schedule=None, max_width=max_width,
-            telemetry=telemetry,
-        )
-        rec.add_stage("schedule", waves.schedule_seconds)
-        rec.add_stage("pack", waves.pack_seconds)
-    else:
-        with rec.stage("schedule"):  # precomputed: validation cost only
-            waves = _waves.resolve_schedule(
-                src, dst, valid, schedule=waves, max_width=max_width,
-                telemetry=telemetry,
-            )
-    plan = wave_plan(cfg.n, cfg.L, waves, packed=packed, block_s=block_s)
-    if plan.nbytes > VMEM_BIT_BUDGET:
-        raise ValueError(
-            f"matching-bit block {plan.nbytes/2**20:.1f} MiB > VMEM budget; "
-            f"use repro.core.rounds with vertex partitioning"
-        )
-    with rec.stage("layout"):
-        u, v, w, ok = _waves.slot_arrays(
-            waves, src, dst, np.asarray(stream.weight), valid
-        )
-        # host-side slot prep (all vectorized numpy): remap padding slots to
-        # the sacrificial bit-block row n_pad — the in-place row scatter
-        # needs duplicate row indices to carry identical values, which a
-        # padding alias of real vertex 0 would break — and pad the segment
-        # count up to the grid block
-        ns = u.shape[0]
-        ns_pad = _round_up(max(ns, 1), plan.block_s)
-        total = ns_pad * plan.seg
-        sac = np.int32(plan.n_pad)
-        edges = np.full((total, 2), sac, np.int32)
-        wf = np.zeros((total, 1), np.float32)
-        okf = ok.reshape(-1)
-        edges[: ns * plan.seg, 0] = np.where(okf, u.reshape(-1), sac)
-        edges[: ns * plan.seg, 1] = np.where(okf, v.reshape(-1), sac)
-        wf[: ns * plan.seg, 0] = w.reshape(-1)
+    sch = _schedule_for(stream, waves, max_width, telemetry, rec)
+    plan = wave_plan(cfg.n, cfg.L, sch, packed=packed, block_s=block_s)
+    _check_budget(plan)
+    total, live, out = _run_slot_layout(
+        stream, cfg, plan, sch.slots, sch.num_segments, plan.seg,
+        _waves_device, "waves", rec, interpret, packed, mb0,
+    )
     if telemetry.enabled:
-        rec.put_many(_waves.schedule_counters(waves))
+        rec.put_many(_waves.schedule_counters(sch))
         rec.put_many(plan_counters(plan))
         rec.put("stream.num_edges", stream.num_edges)
-        rec.put(
-            "traffic.hbm_bytes",
-            traffic_bytes(total, waves.num_scheduled, plan.width),
-        )
-    key = (
-        "waves", plan.seg, plan.block_s, plan.n_pad, plan.width, plan.words,
-        interpret, packed, total, cfg.n, cfg.L, cfg.eps, mb0 is not None,
-    )
-    with rec.device_stage(key):
-        assigned_slots, mb = _waves_device(
-            jnp.asarray(edges),
-            jnp.asarray(wf),
-            cfg,
-            plan.seg,
-            plan.block_s,
-            plan.n_pad,
-            plan.width,
-            plan.words,
-            interpret,
-            packed,
-            mb0=None if mb0 is None else jnp.asarray(mb0),
-        )
-        rec.block((assigned_slots, mb))
-    with rec.stage("layout"):
-        # slot -> stream-position scatter on the host: each stream position
-        # occupies exactly one slot, so this is a plain indexed store
-        m = stream.num_edges
-        flat = waves.slots.reshape(-1)
-        live = flat >= 0
-        assigned = np.full(m, -1, np.int32)
-        assigned[flat[live]] = np.asarray(assigned_slots)[: flat.size][live]
-        assigned = jnp.asarray(assigned)
+        rec.put("traffic.hbm_bytes", traffic_bytes(total, live, plan.width))
     rec.finish()
-    if packed:
-        return MatchingResult(assigned=assigned, mb_packed=mb, L=cfg.L)
-    return MatchingResult(assigned=assigned, mb=mb)
-
-
-def _thresholds_flat(cfg: SubstreamConfig, nbits: int) -> jax.Array:
-    """Megakernel-shaped thresholds: [1, nbits] sorted flat, +inf pads.
-
-    The mega kernels exploit the prefix structure of sorted thresholds
-    (see ``kernel._prefix_te_table``), so they take the flat ascending
-    vector instead of the per-bit-plane [8, W_pad] layout.
-    """
-    thr = cfg.thresholds()
-    return jnp.full((1, nbits), jnp.inf, jnp.float32).at[0, : cfg.L].set(thr)
-
-
-@partial(
-    jax.jit,
-    static_argnames=(
-        "cfg", "seg", "seg_block", "tiles_per_block", "n_pad", "width",
-        "words", "interpret", "packed",
-    ),
-)
-def _mega_device(
-    seg_offsets, uv, w, cfg, seg, seg_block, tiles_per_block,
-    n_pad, width, words, interpret, packed, mb0=None,
-):
-    """Jitted device half of the mega path. Thresholds are built inside
-    the jit (a dozen jnp dispatches otherwise dominate small graphs);
-    ``seg_offsets`` rides along as the scalar prefetch so the kernel can
-    bound its tile loop at the layout's real tile count. ``mb0`` (caller
-    storage) seeds the resident bit block; the sacrificial band pads
-    with zeros."""
-    thr_flat = _thresholds_flat(cfg, width * 8 if packed else width)
-    rows = n_pad + _kernel.SACRIFICIAL_ROWS
-    mb_init = (
-        None
-        if mb0 is None
-        else _mb0_pad(mb0, cfg.n, words, rows, width, packed)
-    )
-    assigned_slots, mb = _kernel.substream_match_pallas_mega(
-        uv, w, thr_flat, seg_offsets, n_pad,
-        seg=seg, seg_block=seg_block, tiles_per_block=tiles_per_block,
-        interpret=interpret, packed=packed, mb_init=mb_init,
-    )
-    if packed:
-        return assigned_slots, mb[: cfg.n, :words]
-    return assigned_slots, mb[: cfg.n, : cfg.L].astype(bool)
+    return out
 
 
 def _substream_match_mega(
@@ -1037,103 +1038,24 @@ def _substream_match_mega(
         telemetry, "pallas_mega", stream.num_edges,
         jax.default_backend(), interpret,
     )
-    src = np.asarray(stream.src)
-    dst = np.asarray(stream.dst)
-    valid = np.asarray(stream.valid)
-    weight = np.asarray(stream.weight)
-    if waves is None:
-        sch = _waves.resolve_schedule(
-            src, dst, valid, schedule=None, max_width=max_width,
-            telemetry=telemetry,
-        )
-        rec.add_stage("schedule", sch.schedule_seconds)
-        rec.add_stage("pack", sch.pack_seconds)
-    else:
-        with rec.stage("schedule"):  # precomputed: validation cost only
-            sch = _waves.resolve_schedule(
-                src, dst, valid, schedule=waves, max_width=max_width,
-                telemetry=telemetry,
-            )
+    sch = _schedule_for(stream, waves, max_width, telemetry, rec)
     with rec.stage("layout"):
         layout = _waves.block_aligned_layout(sch, seg_block)
     plan = mega_plan(cfg.n, cfg.L, layout, packed=packed)
-    if plan.nbytes > VMEM_BIT_BUDGET:
-        raise ValueError(
-            f"matching-bit block {plan.nbytes/2**20:.1f} MiB > VMEM budget; "
-            f"use repro.core.rounds with vertex partitioning"
-        )
-    with rec.stage("layout"):
-        # host-side slot prep (all vectorized numpy): flatten the aligned
-        # layout; remap padding AND self-loop slots to the sacrificial
-        # bit-block row n_pad with w = 0 (duplicate scatter rows must carry
-        # identical values, and the kernel has no in-loop self-loop test);
-        # pad the tile count up to the grid block — the kernel skips those
-        # padding tiles via the prefetched seg_offsets bound. The uv stream
-        # is laid out per tile as all bslots u-rows then all bslots v-rows,
-        # so the kernel's gather index vector is one contiguous load.
-        flat = layout.slots.reshape(-1)
-        live = flat >= 0
-        pos = flat[live]
-        bslots = seg_block * plan.seg
-        ntiles_pad = _round_up(max(layout.num_tiles, 1), plan.tiles_per_block)
-        total = ntiles_pad * bslots
-        sac = np.int32(plan.n_pad)
-        uflat = np.full(total, sac, np.int32)
-        vflat = np.full(total, sac, np.int32)
-        wf = np.zeros((total, 1), np.float32)
-        lv = np.zeros(total, bool)
-        lv[: flat.size] = live
-        u, v, w = src[pos], dst[pos], weight[pos]
-        loop = u == v
-        uflat[lv] = np.where(loop, sac, u)
-        vflat[lv] = np.where(loop, sac, v)
-        wf[lv, 0] = np.where(loop, 0.0, w.astype(np.float32))
-        uv = np.concatenate(
-            [uflat.reshape(ntiles_pad, bslots), vflat.reshape(ntiles_pad, bslots)],
-            axis=1,
-        ).reshape(-1, 1)
+    _check_budget(plan)
+    total, live, out = _run_slot_layout(
+        stream, cfg, plan, layout.slots, layout.num_tiles,
+        seg_block * plan.seg, _mega_device, "mega", rec, interpret, packed,
+        mb0,
+    )
     if telemetry.enabled:
         rec.put_many(_waves.schedule_counters(sch))
         rec.put_many(_waves.layout_counters(layout, sch))
         rec.put_many(plan_counters(plan))
         rec.put("stream.num_edges", stream.num_edges)
-        rec.put(
-            "traffic.hbm_bytes",
-            traffic_bytes(total, int(pos.size), plan.width),
-        )
-    key = (
-        "mega", plan.seg, seg_block, plan.tiles_per_block, plan.n_pad,
-        plan.width, plan.words, interpret, packed, total,
-        layout.seg_offsets.shape[0], cfg.n, cfg.L, cfg.eps, mb0 is not None,
-    )
-    with rec.device_stage(key):
-        assigned_slots, mb = _mega_device(
-            jnp.asarray(layout.seg_offsets),
-            jnp.asarray(uv),
-            jnp.asarray(wf),
-            cfg,
-            plan.seg,
-            seg_block,
-            plan.tiles_per_block,
-            plan.n_pad,
-            plan.width,
-            plan.words,
-            interpret,
-            packed,
-            mb0=None if mb0 is None else jnp.asarray(mb0),
-        )
-        rec.block((assigned_slots, mb))
-    with rec.stage("layout"):
-        # slot -> stream-position scatter on the host: each stream position
-        # occupies exactly one slot, so this is a plain indexed store
-        m = stream.num_edges
-        assigned = np.full(m, -1, np.int32)
-        assigned[pos] = np.asarray(assigned_slots)[: flat.size][live]
-        assigned = jnp.asarray(assigned)
+        rec.put("traffic.hbm_bytes", traffic_bytes(total, live, plan.width))
     rec.finish()
-    if packed:
-        return MatchingResult(assigned=assigned, mb_packed=mb, L=cfg.L)
-    return MatchingResult(assigned=assigned, mb=mb)
+    return out
 
 
 # --------------------------------------------------------------------------

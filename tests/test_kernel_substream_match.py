@@ -1,5 +1,5 @@
 """Pallas substream_match kernel: shape/dtype sweeps vs the jnp oracle,
-packed (uint8 bit-plane) vs unpacked (int8 lane) layout parity, and the
+packed (uint8 bit-plane) vs unpacked (bool) layout parity, and the
 VMEM plan contract."""
 import jax.numpy as jnp
 import numpy as np
@@ -123,10 +123,19 @@ def test_vmem_budget_enforced(packed):
 
 
 def test_vmem_plan_alignment():
-    plan_u = vmem_plan(100, 48, packed=False)
+    """The plan counts the bit block as the compiler tiles it: int32 rows
+    of 128 lanes in 8-row tiles, vertices folded into lane ranges. At a
+    few hundred vertices the 8-row tile dominates, so the layout ratio
+    is checked where it does not."""
+    for packed in (True, False):
+        small = vmem_plan(100, 48, packed=packed)
+        assert small.rows % 8 == 0 and small.row_width % 128 == 0
+        assert small.nbytes == small.rows * small.row_width * 4
+        assert small.n_pad >= 100
+    plan_u = vmem_plan(100_000, 48, packed=False)
     assert plan_u.n_pad % 8 == 0 and plan_u.width % 128 == 0
     assert plan_u.nbytes == plan_u.n_pad * plan_u.width
-    plan_p = vmem_plan(100, 48, packed=True)
+    plan_p = vmem_plan(100_000, 48, packed=True)
     assert plan_p.n_pad % 8 == 0 and plan_p.width % 8 == 0
     assert plan_p.words == packed_width(48) == 6
     assert plan_p.nbytes == plan_p.n_pad * plan_p.width

@@ -29,6 +29,8 @@ from repro.graph.waves import (
     wave_schedule,
 )
 from repro.kernels.substream_match.ops import (
+    SLOT_SMEM_BYTES,
+    SMEM_PER_CORE,
     VMEM_PER_CORE,
     mega_plan,
     substream_match,
@@ -192,9 +194,10 @@ def test_block_aligned_offsets_invariants(data):
 @given(st.data())
 @settings(**SETTINGS)
 def test_mega_plan_double_buffer_accounting(data):
-    """WavePlan VMEM totals under double-buffering: the plan charges
-    exactly 2x one tile's working set, and bit block + double-buffered
-    tiles + slot-stream blocks all fit in VMEM_PER_CORE."""
+    """WavePlan totals under double-buffering: the plan charges exactly
+    2x one buffer of the slot pipeline, the double-buffered slot and
+    assigned blocks fit SMEM, and the bit block with its headroom fits
+    VMEM_PER_CORE."""
     stream, cfg = _stream(data.draw, max_n=40, max_m=120)
     src = np.asarray(stream.src)
     dst = np.asarray(stream.dst)
@@ -208,8 +211,9 @@ def test_mega_plan_double_buffer_accounting(data):
     assert plan.num_tiles == layout.num_tiles
     assert plan.gather_bytes == 2 * plan.tile_bytes, "double-buffer = 2x tile"
     assert plan.block_e == plan.tiles_per_block * sb * plan.seg
-    stream_bytes = plan.tiles_per_block * sb * plan.seg * 24 * 2
-    assert plan.nbytes + plan.gather_bytes + stream_bytes <= VMEM_PER_CORE
+    assert plan.gather_bytes == plan.block_e * SLOT_SMEM_BYTES
+    assert plan.gather_bytes <= SMEM_PER_CORE
+    assert plan.vmem_limit <= VMEM_PER_CORE
     # the resident bit block itself is within the reserved budget
     assert plan.nbytes == plan.n_pad * plan.width
 

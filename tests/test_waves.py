@@ -30,6 +30,9 @@ from repro.graph.waves import (
     wave_schedule,
 )
 from repro.kernels.substream_match.ops import (
+    SLOT_SMEM_BYTES,
+    SMEM_PER_CORE,
+    VMEM_BIT_BUDGET,
     VMEM_PER_CORE,
     WavePlan,
     resolve_interpret,
@@ -282,9 +285,12 @@ def test_wave_plan_accounting(rng):
         assert plan.num_waves == sch.num_waves
         assert plan.num_segments == sch.num_segments
         assert plan.block_e == plan.block_s * plan.seg
-        # gather bytes scale with the segment tile, not the largest wave
-        assert 0 < plan.gather_bytes <= 16 * sch.width * plan.width + 32 * sch.width
-        assert plan.nbytes + plan.gather_bytes <= VMEM_PER_CORE
+        # the slot pipeline's SMEM scales with the segment block, not
+        # the largest wave, and the bit block is the only VMEM it asks for
+        assert plan.gather_bytes == plan.block_s * sch.width * SLOT_SMEM_BYTES
+        assert 0 < plan.gather_bytes <= SMEM_PER_CORE
+        assert plan.nbytes <= VMEM_BIT_BUDGET
+        assert plan.vmem_limit <= VMEM_PER_CORE
     # oversized segment tiles must be rejected, pointing at seg
     huge = WaveSchedule(
         wave=np.zeros(1, np.int32),
