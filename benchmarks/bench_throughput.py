@@ -142,17 +142,13 @@ def _expected_counters(schedule, cfg, L: int) -> dict:
         "pallas_waves": {
             "plan.gather_bytes": int(wplan.gather_bytes),
             "plan.bit_block_bytes": int(wplan.nbytes),
-            "traffic.hbm_bytes": traffic_bytes(
-                ns_pad * wplan.seg, schedule.num_scheduled, wplan.width
-            ),
+            "traffic.hbm_bytes": traffic_bytes(ns_pad * wplan.seg, wplan.nbytes),
         },
         "pallas_mega": {
             "plan.gather_bytes": int(mplan.gather_bytes),
             "plan.bit_block_bytes": int(mplan.nbytes),
             "traffic.hbm_bytes": traffic_bytes(
-                mega_tiles_pad * mplan.seg_block * mplan.seg,
-                schedule.num_scheduled,
-                mplan.width,
+                mega_tiles_pad * mplan.seg_block * mplan.seg, mplan.nbytes
             ),
         },
     }

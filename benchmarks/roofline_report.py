@@ -1,110 +1,22 @@
-"""Roofline summary rows from the dry-run JSON (§5.11 optimality analogue
-plus the 40-cell table feed for EXPERIMENTS.md).
+"""Roofline summary rows from the dry-run JSON (the 40-cell table feed
+for EXPERIMENTS.md).
 
-Since the telemetry counters landed in ``BENCH_substream.json`` (every
-engine row carries ``traffic.hbm_bytes``, the modeled stream + bit-row
-traffic of its plan), this report also derives the *achieved* fraction
-of the substream kernel bound per engine and scale — the measured
-edges/sec over :func:`repro.launch.roofline.substream_bound` at that
-row's bytes-per-edge. One model (``launch/roofline``), two consumers
-(per-call ``MatchTelemetry.roofline()`` and this table).
+The substream-matching kernel's roofline is measured on the chip by the
+benchmark (``perfbench/roofline.py``, the ``substream_match_roofline``
+metric), not modelled here.
 """
 import json
 import os
-import pathlib
-
-from repro.launch.roofline import (
-    LINK_BW,
-    PEAK_FLOPS,
-    SUBSTREAM_CLOCK,
-    SUBSTREAM_CYCLES_PER_EDGE,
-    substream_achieved,
-)
-from repro.kernels.substream_match.ops import vmem_plan
-
-BENCH_PATH = pathlib.Path(__file__).resolve().parent.parent / "BENCH_substream.json"
-
-
-def matching_kernel_roofline(L=64, eps=0.1):
-    """§5.11: the FPGA achieves 175M e/s vs a 200M e/s (1 edge/cycle) bound.
-
-    TPU analogue: the kernel retires 1 edge per fori_loop iteration; the
-    per-edge work is 2 row loads + 2 row stores of L_pad lanes (int8) + an
-    L_pad-wide compare/AND — VPU-bound. At ~940 MHz with ~4 vector ops/edge
-    + loop overhead (~8 cycles/edge conservatively), the bound is
-    ~115M edges/s/core; the stream DMA needs 8 B/edge (0.9 GB/s) << HBM bw,
-    matching the paper's conclusion that the pipeline, not DRAM, limits.
-    The clock/cycle constants live in :mod:`repro.launch.roofline`
-    (``SUBSTREAM_CLOCK`` / ``SUBSTREAM_CYCLES_PER_EDGE``) — shared with
-    the per-call telemetry roofline.
-    """
-    plan = vmem_plan(2**15, L, packed=True)
-    edges_per_s = SUBSTREAM_CLOCK / SUBSTREAM_CYCLES_PER_EDGE
-    return {
-        "edges_per_s_bound": edges_per_s,
-        "vmem_bytes": plan.nbytes,
-        # stream + amortized packed bit rows (width bytes per vertex touch)
-        "dma_bytes_per_edge": 8 + plan.width / 8,
-    }
-
-
-def substream_achieved_rows(bench_path=BENCH_PATH):
-    """Achieved-vs-bound fraction per engine/scale from the bench record.
-
-    Reads the telemetry counters of ``BENCH_substream.json``: engines
-    that model their HBM traffic (``traffic.hbm_bytes`` — the Pallas
-    pipelines) get one row each with the achieved fraction of the
-    pipeline/memory bound at their measured bytes-per-edge.
-    """
-    rows = []
-    if not os.path.exists(bench_path):
-        return [("roofline/substream_achieved", 0.0, "BENCH_substream.json missing")]
-    report = json.load(open(bench_path))
-    for g in report.get("graphs", []):
-        m = g.get("m", 0)
-        for name, row in g.get("engines", {}).items():
-            nbytes = row.get("counters", {}).get("traffic.hbm_bytes")
-            if nbytes is None or not m:
-                continue  # engine has no traffic model (scan / XLA paths)
-            terms = substream_achieved(row["edges_per_sec"], nbytes / m)
-            rows.append(
-                (
-                    f"roofline/substream/{name}_s{g.get('scale', '?')}",
-                    row["seconds_per_call"] * 1e6,
-                    f"frac={terms['achieved_fraction']:.2e};"
-                    f"dom={terms['dominant']};"
-                    f"bpe={terms['bytes_per_edge']:.1f}",
-                )
-            )
-    if len(rows) == 0:
-        rows.append(
-            (
-                "roofline/substream_achieved",
-                0.0,
-                "no traffic.hbm_bytes counters in BENCH_substream.json",
-            )
-        )
-    return rows
 
 
 def run(path="dryrun_results.json"):
     rows = []
-    mk = matching_kernel_roofline()
-    rows.append(
-        (
-            "roofline/substream_match_kernel",
-            0.0,
-            f"bound={mk['edges_per_s_bound']/1e6:.0f}Me/s;vmem={mk['vmem_bytes']/2**20:.1f}MiB",
-        )
-    )
-    rows.extend(substream_achieved_rows())
     if not os.path.exists(path):
         rows.append(("roofline/dryrun", 0.0, "dryrun_results.json missing"))
         return rows
     data = json.load(open(path))
     ok = sum(1 for v in data.values() if "error" not in v)
     rows.append(("roofline/cells_ok", 0.0, f"{ok}/{len(data)}"))
-    best = {}
     for v in data.values():
         if "error" in v or v["mesh"] != "16x16":
             continue

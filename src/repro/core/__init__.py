@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro import obs
 from repro.core.bitpack import pack_bits, packed_width, unpack_bits
 from repro.core.types import (
     EdgeStream,
@@ -59,7 +60,9 @@ def mwm_pipeline(
 ):
     """End-to-end (4+eps)-approx MWM. Returns (edge_indices, weight).
 
-    part1 in {'scan', 'waves', 'blocked', 'pallas', 'rounds'}.
+    part1 in {'scan', 'waves', 'blocked', 'pallas', 'rounds'}. A
+    ``telemetry`` session in ``kw`` reaches Part 1 (``waves`` /
+    ``pallas``) and the merge.
     """
     if part1 == "scan":
         res = mwm_scan(stream, cfg)
@@ -73,8 +76,9 @@ def mwm_pipeline(
         res = mwm_rounds(stream, cfg)
     else:
         raise ValueError(part1)
-    idx = merge_host(stream, res, cfg)
-    return idx, matching_weight(stream, idx)
+    telemetry = kw.get("telemetry", obs.DISABLED)
+    idx = merge_host(stream, res, cfg, telemetry=telemetry)
+    return idx, matching_weight(stream, idx, telemetry=telemetry)
 
 
 __all__ = [

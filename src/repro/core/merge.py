@@ -34,13 +34,16 @@ def merge_host(
     per substream. The greedy pass itself is the dependency chain and
     stays a loop, exactly like the paper's sequential post-processor.
 
-    ``telemetry`` records one ``merge.host`` span plus the recorded /
-    matched edge counters.
+    ``telemetry`` records one ``merge.host`` span (the copies to the
+    host inside it as ``copy.d2h`` spans) plus the recorded / matched
+    edge counters.
     """
     with telemetry.span("merge.host"):
-        src = np.asarray(stream.src)
-        dst = np.asarray(stream.dst)
-        assigned = np.asarray(result.assigned)
+        with telemetry.span("copy.d2h", what="stream"):
+            src = np.asarray(stream.src)
+            dst = np.asarray(stream.dst)
+        with telemetry.span("copy.d2h", what="assigned"):
+            assigned = np.asarray(result.assigned)
         recorded = np.nonzero(assigned >= 0)[0]
         if recorded.size == 0:
             # empty / all-dropped streams: a well-formed empty T, skipping
@@ -106,11 +109,16 @@ def merge_device(
     return mask
 
 
-def matching_weight(stream: EdgeStream, edge_idx: np.ndarray) -> float:
+def matching_weight(
+    stream: EdgeStream, edge_idx: np.ndarray, telemetry=obs.DISABLED
+) -> float:
+    """Summed weight of the stream edges ``edge_idx``; ``telemetry``
+    records the weights' copy to the host as a ``copy.d2h`` span."""
     # the int64 cast keeps empty python lists indexable (np.asarray([])
     # is float64, which cannot index)
     idx = np.asarray(edge_idx, dtype=np.int64)
     if idx.size == 0:
         return 0.0
-    w = np.asarray(stream.weight)
+    with telemetry.span("copy.d2h", what="weight"):
+        w = np.asarray(stream.weight)
     return float(w[idx].sum())

@@ -81,7 +81,7 @@ class WaveSchedule:
 
     ``schedule_seconds`` / ``pack_seconds`` record the host cost of the
     assignment and layout phases. **Deprecated**: both are views of the
-    one telemetry timing path (:class:`repro.obs.stopwatch` spans
+    one telemetry timing path (:func:`repro.obs.stopwatch` spans
     ``wave_schedule.assign`` / ``wave_schedule.pack``) kept populated
     for compatibility — new consumers should pass ``telemetry=`` to
     :func:`wave_schedule` and read the spans or

@@ -209,17 +209,18 @@ def resolve_interpret(interpret: bool | None) -> bool:
 SLOT_STREAM_BYTES = 16
 
 
-def traffic_bytes(total_slots: int, live_slots: int, width: int) -> int:
-    """Modeled per-call traffic of the row-addressed kernels.
+def traffic_bytes(total_slots: int, block_bytes: int, carried: bool = False) -> int:
+    """HBM bytes one kernel call moves.
 
     The slot stream in + assigned out (``SLOT_STREAM_BYTES`` per padded
-    slot) plus the bit-block row traffic: two row gathers and two row
-    scatters of ``width`` bytes per live slot. This is the bytes-moved
-    term :func:`repro.launch.roofline.substream_achieved` divides by —
-    exact integers from the plan accounting, so telemetry counters
-    derived from it are reproducible bit-exactly.
+    slot), and the bit block (``block_bytes``, the plan's ``nbytes``)
+    written back once at the end — plus read once at the start when
+    carried-in state seeds it (``carried``). The rows a slot touches
+    live in VMEM, so they move no HBM bytes. Exact integers from the
+    plan accounting, so the ``traffic.hbm_bytes`` counter is
+    reproducible bit-exactly.
     """
-    return total_slots * SLOT_STREAM_BYTES + live_slots * 4 * width
+    return total_slots * SLOT_STREAM_BYTES + block_bytes * (2 if carried else 1)
 
 
 def plan_counters(plan: VmemPlan) -> dict:
@@ -833,7 +834,8 @@ def _edges_entry(
 ) -> MatchingResult:
     """Telemetry shell of the per-edge engine (the jitted body is
     :func:`_substream_match_edges`). The edges path has no host
-    scheduling, so schedule/pack/layout stages stay 0."""
+    scheduling, so schedule/pack/layout stages stay 0; its kernel makes
+    one trip per stream position (``kernel.trips`` = m)."""
     m = stream.num_edges
     rec = obs.recorder(
         telemetry, "pallas_edges", m, jax.default_backend(), interpret
@@ -843,15 +845,21 @@ def _edges_entry(
         m_pad = _round_up(max(m, 1), plan.block_e)
         rec.put_many(plan_counters(plan))
         rec.put("stream.num_edges", m)
-        rec.put("traffic.hbm_bytes", traffic_bytes(m_pad, m, plan.width))
+        rec.put("kernel.trips", m)
+        rec.put(
+            "traffic.hbm_bytes", traffic_bytes(m_pad, plan.nbytes, mb0 is not None)
+        )
     key = (
         "edges", cfg.n, cfg.L, cfg.eps, packed, interpret, block_e, m,
         mb0 is not None,
     )
     with rec.device_stage(key):
+        if mb0 is not None:
+            with rec.span("copy.h2d", what="mb0"):
+                mb0 = rec.block(jnp.asarray(mb0))
         out = _substream_match_edges(
             stream, cfg, block_e=block_e, interpret=interpret, packed=packed,
-            mb0=None if mb0 is None else jnp.asarray(mb0),
+            mb0=mb0,
         )
         rec.block(out)
     rec.finish()
@@ -920,9 +928,10 @@ def _schedule_for(stream, waves, max_width, telemetry, rec):
     """Resolve the wave schedule, recording its stage times."""
     from repro.graph import waves as _waves
 
-    src = np.asarray(stream.src)
-    dst = np.asarray(stream.dst)
-    valid = np.asarray(stream.valid)
+    with rec.span("copy.d2h", what="stream"):
+        src = np.asarray(stream.src)
+        dst = np.asarray(stream.dst)
+        valid = np.asarray(stream.valid)
     if waves is None:
         # built in-call: the schedule's own stopwatch measurements are
         # the stage split (assign -> "schedule", layout -> "pack")
@@ -949,41 +958,52 @@ def _run_slot_layout(
     grouped so each run of ``group`` slots is vertex-disjoint: gather
     the slot stream (padding -> ``u = v = 0, w = 0``), pad it to whole
     grid programs, run ``device``, and scatter the per-slot assignments
-    back to stream positions."""
+    back to stream positions. The host<->device copies are ``copy.*``
+    spans beside the ``layout.gather`` / ``layout.scatter`` work."""
     with rec.stage("layout"):
-        flat = slots.reshape(-1)
-        live = flat >= 0
-        pos = flat[live]
-        total = _round_up(max(flat.size, 1), plan.block_e)
-        u = np.zeros(total, np.int32)
-        v = np.zeros(total, np.int32)
-        w = np.zeros(total, np.float32)
-        lv = np.zeros(total, bool)
-        lv[: flat.size] = live
-        u[lv] = np.asarray(stream.src)[pos]
-        v[lv] = np.asarray(stream.dst)[pos]
-        w[lv] = np.where(
-            np.asarray(stream.valid)[pos],
-            np.asarray(stream.weight)[pos].astype(np.float32),
-            0.0,
-        )
+        with rec.span("copy.d2h", what="stream"):
+            src = np.asarray(stream.src)
+            dst = np.asarray(stream.dst)
+            valid = np.asarray(stream.valid)
+            weight = np.asarray(stream.weight)
+        with rec.span("layout.gather"):
+            flat = slots.reshape(-1)
+            live = flat >= 0
+            pos = flat[live]
+            total = _round_up(max(flat.size, 1), plan.block_e)
+            u = np.zeros(total, np.int32)
+            v = np.zeros(total, np.int32)
+            w = np.zeros(total, np.float32)
+            lv = np.zeros(total, bool)
+            lv[: flat.size] = live
+            u[lv] = src[pos]
+            v[lv] = dst[pos]
+            w[lv] = np.where(valid[pos], weight[pos].astype(np.float32), 0.0)
     key = (
         engine, group, _kernel_plan(plan), interpret, total, cfg,
         mb0 is not None,
     )
     with rec.device_stage(key):
+        with rec.span("copy.h2d", what="slots"):
+            u, v, w = rec.block((jnp.asarray(u), jnp.asarray(v), jnp.asarray(w)))
+            if mb0 is not None:
+                mb0 = rec.block(jnp.asarray(mb0))
         assigned_slots, mb = device(
-            jnp.asarray(u), jnp.asarray(v), jnp.asarray(w), num_groups, cfg,
-            _kernel_plan(plan), group, interpret,
-            mb0=None if mb0 is None else jnp.asarray(mb0),
+            u, v, w, num_groups, cfg, _kernel_plan(plan), group, interpret,
+            mb0=mb0,
         )
         rec.block((assigned_slots, mb))
     with rec.stage("layout"):
-        # slot -> stream-position scatter on the host: each stream position
-        # occupies exactly one slot, so this is a plain indexed store
-        assigned = np.full(stream.num_edges, -1, np.int32)
-        assigned[pos] = np.asarray(assigned_slots)[: flat.size][live]
-    return total, int(pos.size), _result(jnp.asarray(assigned), mb, cfg, packed)
+        with rec.span("copy.d2h", what="assigned_slots"):
+            assigned_slots = np.asarray(assigned_slots)
+        with rec.span("layout.scatter"):
+            # slot -> stream-position scatter on the host: each stream
+            # position occupies exactly one slot, so a plain indexed store
+            assigned = np.full(stream.num_edges, -1, np.int32)
+            assigned[pos] = assigned_slots[: flat.size][live]
+    with rec.span("copy.h2d", what="assigned"):
+        assigned = rec.block(jnp.asarray(assigned))
+    return total, _result(assigned, mb, cfg, packed)
 
 
 def _substream_match_waves(
@@ -1006,7 +1026,7 @@ def _substream_match_waves(
     sch = _schedule_for(stream, waves, max_width, telemetry, rec)
     plan = wave_plan(cfg.n, cfg.L, sch, packed=packed, block_s=block_s)
     _check_budget(plan)
-    total, live, out = _run_slot_layout(
+    total, out = _run_slot_layout(
         stream, cfg, plan, sch.slots, sch.num_segments, plan.seg,
         _waves_device, "waves", rec, interpret, packed, mb0,
     )
@@ -1014,7 +1034,10 @@ def _substream_match_waves(
         rec.put_many(_waves.schedule_counters(sch))
         rec.put_many(plan_counters(plan))
         rec.put("stream.num_edges", stream.num_edges)
-        rec.put("traffic.hbm_bytes", traffic_bytes(total, live, plan.width))
+        rec.put("kernel.trips", plan.num_segments)
+        rec.put(
+            "traffic.hbm_bytes", traffic_bytes(total, plan.nbytes, mb0 is not None)
+        )
     rec.finish()
     return out
 
@@ -1039,11 +1062,11 @@ def _substream_match_mega(
         jax.default_backend(), interpret,
     )
     sch = _schedule_for(stream, waves, max_width, telemetry, rec)
-    with rec.stage("layout"):
+    with rec.stage("layout"), rec.span("layout.block_align"):
         layout = _waves.block_aligned_layout(sch, seg_block)
     plan = mega_plan(cfg.n, cfg.L, layout, packed=packed)
     _check_budget(plan)
-    total, live, out = _run_slot_layout(
+    total, out = _run_slot_layout(
         stream, cfg, plan, layout.slots, layout.num_tiles,
         seg_block * plan.seg, _mega_device, "mega", rec, interpret, packed,
         mb0,
@@ -1053,7 +1076,10 @@ def _substream_match_mega(
         rec.put_many(_waves.layout_counters(layout, sch))
         rec.put_many(plan_counters(plan))
         rec.put("stream.num_edges", stream.num_edges)
-        rec.put("traffic.hbm_bytes", traffic_bytes(total, live, plan.width))
+        rec.put("kernel.trips", plan.num_tiles)
+        rec.put(
+            "traffic.hbm_bytes", traffic_bytes(total, plan.nbytes, mb0 is not None)
+        )
     rec.finish()
     return out
 
@@ -1150,7 +1176,8 @@ def match_epochs(
         return _empty_result(stream, cfg, packed)
     from repro.core.state import MatchState
 
-    template = MatchState.initial(stream, cfg, packed)
+    with telemetry.span("state.initial"):
+        template = MatchState.initial(stream, cfg, packed)
     if state is None and snapshots is not None:
         state = snapshots.latest(template)
     if state is None:
@@ -1200,7 +1227,8 @@ def match_epochs(
             )
 
         out = guard.run(run_one, label=f"epoch[{k}]") if guard else run_one()
-        state = state.advance(out, b)
+        with telemetry.span("epoch.fold"):
+            state = state.advance(out, b)
         if snapshots is not None:
             snapshots.save(state)
         if epoch_hook is not None:

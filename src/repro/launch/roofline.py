@@ -26,48 +26,6 @@ PEAK_FLOPS = 197e12  # bf16 / chip
 HBM_BW = 819e9  # bytes / s / chip
 LINK_BW = 50e9  # bytes / s / link
 
-# Substream-matching kernel model (the §5.11 optimality analogue): the
-# pipeline retires edges at ``clock / cycles_per_edge`` when nothing
-# stalls (~4 vector ops + loop overhead per edge, conservatively 8
-# cycles), and the HBM side at ``HBM_BW / bytes_per_edge``. Consumed by
-# ``repro.obs.report.MatchTelemetry.roofline`` and
-# ``benchmarks/roofline_report.py``.
-SUBSTREAM_CLOCK = 940e6  # TPU core clock used by the pipeline bound
-SUBSTREAM_CYCLES_PER_EDGE = 8
-
-
-def substream_bound(bytes_per_edge: float) -> dict:
-    """Edges/sec roofline of the substream kernel at the given traffic.
-
-    Two terms: the pipeline bound (1 edge per ``SUBSTREAM_CYCLES_PER_
-    EDGE`` cycles at ``SUBSTREAM_CLOCK``) and the HBM bound (stream +
-    amortized bit-row traffic, ``bytes_per_edge`` per edge). The
-    binding term is the min; ``bytes_per_edge <= 0`` disables the
-    memory term (pure pipeline bound).
-    """
-    pipeline = SUBSTREAM_CLOCK / SUBSTREAM_CYCLES_PER_EDGE
-    memory = HBM_BW / bytes_per_edge if bytes_per_edge > 0 else float("inf")
-    bound = min(pipeline, memory)
-    return {
-        "pipeline_edges_per_s": pipeline,
-        "memory_edges_per_s": memory,
-        "bound_edges_per_s": bound,
-        "dominant": "pipeline" if pipeline <= memory else "memory",
-        "bytes_per_edge": bytes_per_edge,
-    }
-
-
-def substream_achieved(edges_per_sec: float, bytes_per_edge: float) -> dict:
-    """:func:`substream_bound` terms plus the achieved fraction."""
-    terms = substream_bound(bytes_per_edge)
-    terms["achieved_edges_per_s"] = edges_per_sec
-    terms["achieved_fraction"] = (
-        edges_per_sec / terms["bound_edges_per_s"]
-        if terms["bound_edges_per_s"] > 0
-        else 0.0
-    )
-    return terms
-
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
     "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8, "f64": 8, "c64": 8,

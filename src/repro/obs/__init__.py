@@ -4,11 +4,13 @@ Three parts (see ``docs/observability.md`` for the span/counter
 catalog):
 
 * :mod:`repro.obs.trace` — nesting span tracer on ``perf_counter``
-  with Chrome trace-event JSON export (open in Perfetto);
+  with Chrome trace-event JSON export (open in Perfetto); every
+  recorded span is also a ``jax.profiler.TraceAnnotation`` named
+  ``repro.<span>``, on the profiler's clock beside the device ops;
 * :mod:`repro.obs.counters` — flat metrics registry for the plan /
   schedule quantities the engines already compute;
 * :mod:`repro.obs.report` — :class:`MatchTelemetry`, the per-call
-  aggregate (stage split, counters, derived rates, roofline fraction).
+  aggregate (stage split, counters, derived rate).
 
 Usage::
 
@@ -35,7 +37,7 @@ from repro.obs.report import (
     consistency_problems,
     recorder,
 )
-from repro.obs.trace import NULL_SPAN, Span, Tracer, stopwatch
+from repro.obs.trace import NULL_SPAN, PROFILER_PREFIX, Span, Tracer, stopwatch
 
 __all__ = [
     "Telemetry",
@@ -50,6 +52,7 @@ __all__ = [
     "consistency_problems",
     "variant_seen",
     "NULL_SPAN",
+    "PROFILER_PREFIX",
     "NULL_COUNTERS",
     "NULL_RECORDER",
 ]

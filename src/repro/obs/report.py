@@ -2,7 +2,7 @@
 
 A :class:`MatchTelemetry` is the aggregate of ONE ``substream_match``
 (or XLA-engine) call: which engine/backend actually ran, the host
-stage split, the counter snapshot, and the derived rates. The stages:
+stage split, the counter snapshot, and the derived rate. The stages:
 
 ``schedule``
     Host wave-schedule assignment (conflict-depth / earliest-fit), or —
@@ -37,7 +37,7 @@ import dataclasses
 import time
 
 from repro.obs.counters import variant_seen
-from repro.obs.trace import NULL_SPAN
+from repro.obs.trace import NULL_SPAN, Span
 
 #: The canonical stage keys, in pipeline order. Every MatchTelemetry
 #: (and every bench ``stage_seconds`` row) carries exactly these.
@@ -70,20 +70,6 @@ class MatchTelemetry:
         return self.stage_seconds.get("compile", 0.0) + self.stage_seconds.get(
             "execute", 0.0
         )
-
-    def roofline(self) -> dict:
-        """Achieved-vs-bound fraction via :mod:`repro.launch.roofline`.
-
-        Uses the per-edge HBM traffic implied by the counters
-        (``traffic.hbm_bytes`` over the stream length) against the
-        pipeline/memory bound of the substream kernel model. Returns
-        the bound terms plus ``achieved_fraction``.
-        """
-        from repro.launch import roofline as _roofline
-
-        nbytes = self.counters.get("traffic.hbm_bytes", 0)
-        bpe = nbytes / self.num_edges if self.num_edges else 0.0
-        return _roofline.substream_achieved(self.edges_per_sec, bpe)
 
     def asdict(self) -> dict:
         """JSON-ready dict (stages in canonical order, sorted counters)."""
@@ -125,29 +111,6 @@ def consistency_problems(
     return problems
 
 
-class _StageSpan:
-    """Context manager crediting its duration to one recorder stage."""
-
-    __slots__ = ("_rec", "_stage", "_t0")
-
-    def __init__(self, rec: "MatchRecorder", stage: str):
-        self._rec = rec
-        self._stage = stage
-
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        t1 = time.perf_counter()
-        rec = self._rec
-        rec.stage_seconds[self._stage] += t1 - self._t0
-        rec._telemetry.tracer.complete(
-            f"{rec.engine}.{self._stage}", self._t0, t1
-        )
-        return False
-
-
 class MatchRecorder:
     """Accumulates one engine call's stages/counters into a record.
 
@@ -170,13 +133,24 @@ class MatchRecorder:
         self.num_edges = num_edges
         self.stage_seconds = dict.fromkeys(STAGES, 0.0)
         self.counters: dict = {}
+        # every span from here to the next engine call carries this index
+        telemetry.tracer.call = len(telemetry.match_calls)
         self._t0 = time.perf_counter()
 
-    def stage(self, name: str) -> _StageSpan:
-        """``with rec.stage("layout"): ...`` — credit the block to a stage."""
-        return _StageSpan(self, name)
+    def stage(self, name: str) -> Span:
+        """``with rec.stage("layout"): ...`` — credit the block to a stage
+        (recorded as the span ``{engine}.{stage}``)."""
+        return Span(
+            self._telemetry.tracer, f"{self.engine}.{name}",
+            credit=(self.stage_seconds, name),
+        )
 
-    def device_stage(self, variant_key) -> _StageSpan:
+    def span(self, name: str, **args) -> Span:
+        """A named span inside the call (e.g. ``copy.d2h``) that credits no
+        stage: it is a child of whichever stage encloses it."""
+        return self._telemetry.tracer.span(name, **args)
+
+    def device_stage(self, variant_key) -> Span:
         """Stage for the jitted device call: ``compile`` on the variant's
         first dispatch in this process, ``execute`` on repeats; also
         bumps the ``jit.variant_hit``/``jit.variant_miss`` counters."""
@@ -236,6 +210,9 @@ class _NullRecorder:
     __slots__ = ()
 
     def stage(self, name):
+        return NULL_SPAN
+
+    def span(self, name, **args):
         return NULL_SPAN
 
     def device_stage(self, variant_key):

@@ -5,29 +5,45 @@ tracer.span("pack"): ...`` records one *complete* event per exit on a
 single ``perf_counter`` timebase, and :meth:`Tracer.chrome_trace`
 serializes the session as Chrome trace-event JSON — the format Perfetto
 (https://ui.perfetto.dev) and ``chrome://tracing`` open directly.
-Nesting is positional, exactly like Chrome's own traces: an event is a
-child of whichever event's ``[ts, ts + dur]`` interval encloses it on
-the same track, so the tracer needs no explicit stack.
+
+One span primitive
+------------------
+:class:`Span` is the only timing code path. ``Tracer.span``, the
+engine recorders' stage spans (which also credit their seconds to a
+stage) and :func:`stopwatch` (which times even when telemetry is off)
+all build one. While it is recorded, a span also opens a
+``jax.profiler.TraceAnnotation`` named ``repro.<span name>``
+(:data:`PROFILER_PREFIX`), so under ``jax.profiler`` it lands in the
+host plane on the same clock as the device operations. Each recorded
+event's args carry ``call`` (the index in ``Telemetry.match_calls`` of
+the engine call the span belongs to: the one open or last opened) and
+``parent`` (the name of the enclosing span on the same thread, from the
+tracer's per-thread stack; None at top level).
 
 Zero-overhead-when-disabled contract
 ------------------------------------
 The disabled path never touches this module's classes: ``NULL_SPAN`` is
 one shared, reentrant no-op context manager and the disabled telemetry
 facade returns it by identity from every ``span()`` call — no event
-list, no timestamping, no per-call object. Hot loops may call
-``telemetry.span(...)`` unconditionally.
-
-:class:`stopwatch` is the single timing path shared by code that must
-report a duration even when telemetry is off (e.g. the deprecated
-``WaveSchedule.schedule_seconds`` compatibility fields): it always
-measures ``perf_counter`` and *additionally* records a span when the
-telemetry object is enabled, so there is one measurement, two views.
+list, no timestamping, no per-call object, no profiler annotation and
+no ``jax`` import. Hot loops may call ``telemetry.span(...)``
+unconditionally.
 """
 from __future__ import annotations
 
 import json
 import threading
 import time
+
+#: Prefix of the profiler annotation each recorded span opens.
+PROFILER_PREFIX = "repro."
+
+def _annotation(name: str):
+    """The profiler annotation of a recorded span (``jax.profiler`` is
+    imported here, on the enabled path only)."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(PROFILER_PREFIX + name)
 
 
 class _NullSpan:
@@ -51,30 +67,53 @@ NULL_SPAN = _NullSpan()
 
 
 class Span:
-    """One live span of an enabled :class:`Tracer` (context manager).
+    """One timed block (context manager) — the one timing path.
 
-    Timestamps are taken on ``__enter__``/``__exit__``; the completed
-    event is appended to the owning tracer at exit. ``seconds`` holds
-    the duration after exit (also exposed by :class:`stopwatch`).
+    ``seconds`` holds the block's ``perf_counter`` duration after exit.
+    With a ``tracer`` the span is recorded: it opens the profiler
+    annotation ``repro.<name>`` around the block, sits on the tracer's
+    per-thread stack while open, and appends one complete event at
+    exit. Without one (:func:`stopwatch` with telemetry off) it only
+    times. ``credit`` is an optional ``(dict, key)`` the seconds are
+    added to at exit — how an engine recorder's stage spans fill
+    ``stage_seconds``.
     """
 
-    __slots__ = ("_tracer", "name", "args", "t0", "seconds")
+    __slots__ = ("_tracer", "name", "args", "_credit", "_annotation", "t0", "seconds")
 
-    def __init__(self, tracer: "Tracer", name: str, args: dict | None):
+    def __init__(self, tracer: "Tracer | None", name: str, args: dict | None = None,
+                 credit: tuple | None = None):
         self._tracer = tracer
         self.name = name
         self.args = args
+        self._credit = credit
+        self._annotation = None
         self.t0 = 0.0
         self.seconds = 0.0
 
     def __enter__(self) -> "Span":
+        if self._tracer is not None:
+            self._tracer._stack().append(self.name)
+            self._annotation = _annotation(self.name)
+            self._annotation.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = time.perf_counter()
         self.seconds = t1 - self.t0
-        self._tracer.complete(self.name, self.t0, t1, self.args)
+        tracer = self._tracer
+        if tracer is not None:
+            self._annotation.__exit__(None, None, None)
+            stack = tracer._stack()
+            stack.pop()
+            args = dict(self.args or ())
+            args["call"] = tracer.call
+            args["parent"] = stack[-1] if stack else None
+            tracer.complete(self.name, self.t0, t1, args)
+        if self._credit is not None:
+            totals, key = self._credit
+            totals[key] += self.seconds
         return False
 
 
@@ -83,13 +122,17 @@ class Tracer:
 
     All timestamps are ``perf_counter`` seconds relative to the
     tracer's construction (``epoch``), exported as microseconds — the
-    trace-event ``ts`` unit. One tracer = one trace file.
+    trace-event ``ts`` unit. One tracer = one trace file. ``call`` is
+    the index of the engine call the next spans belong to (set by the
+    engine recorder, :func:`repro.obs.recorder`; None before the first).
     """
 
     def __init__(self):
         self.epoch = time.perf_counter()
         self.events: list[dict] = []
+        self.call: int | None = None
         self._tids: dict[int, int] = {}
+        self._local = threading.local()
 
     def _tid(self) -> int:
         ident = threading.get_ident()
@@ -98,12 +141,19 @@ class Tracer:
             tid = self._tids[ident] = len(self._tids)
         return tid
 
+    def _stack(self) -> list:
+        """This thread's stack of open span names."""
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
     def span(self, name: str, **args) -> Span:
         """``with tracer.span("pack"): ...`` — records one complete event."""
         return Span(self, name, args or None)
 
     def complete(self, name: str, t0: float, t1: float, args: dict | None = None):
-        """Record an already-measured span (the :class:`stopwatch` path)."""
+        """Record a measured span as a complete (``ph: "X"``) event."""
         ev = {
             "name": name,
             "cat": "obs",
@@ -149,34 +199,14 @@ class Tracer:
             f.write("\n")
 
 
-class stopwatch:
-    """Measure a block's wall seconds AND record a telemetry span.
+def stopwatch(telemetry, name: str, **args) -> Span:
+    """A :class:`Span` that times the block even when telemetry is off.
 
-    The one timing path for durations that must exist even when
-    telemetry is disabled (the ``WaveSchedule.schedule_seconds`` /
-    ``pack_seconds`` compatibility fields): ``perf_counter`` is always
-    read, ``seconds`` is always set, and the span is recorded into the
-    telemetry object's tracer only when it is enabled — one
+    For durations that must exist either way (the
+    ``WaveSchedule.schedule_seconds`` / ``pack_seconds`` compatibility
+    fields): ``seconds`` is always set, and the span is recorded (event
+    and profiler annotation) only when ``telemetry`` is enabled — one
     measurement, never two timing code paths.
     """
-
-    __slots__ = ("_telemetry", "_name", "_args", "t0", "seconds")
-
-    def __init__(self, telemetry, name: str, **args):
-        self._telemetry = telemetry
-        self._name = name
-        self._args = args or None
-        self.t0 = 0.0
-        self.seconds = 0.0
-
-    def __enter__(self) -> "stopwatch":
-        self.t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc) -> bool:
-        t1 = time.perf_counter()
-        self.seconds = t1 - self.t0
-        tel = self._telemetry
-        if tel is not None and tel.enabled:
-            tel.tracer.complete(self._name, self.t0, t1, self._args)
-        return False
+    enabled = telemetry is not None and telemetry.enabled
+    return Span(telemetry.tracer if enabled else None, name, args or None)

@@ -14,6 +14,7 @@ Three invariant families:
   runs produce identical counters (modulo the jit hit/miss labels,
   which legitimately flip between a cold and a warm call).
 """
+import contextlib
 import json
 import time
 import tracemalloc
@@ -23,7 +24,7 @@ import numpy as np
 import pytest
 
 from repro import obs
-from repro.core import merge, rounds
+from repro.core import merge, mwm_pipeline, rounds
 from repro.core.matching import mwm_waves
 from repro.core.types import EdgeStream, SubstreamConfig
 from repro.graph.waves import (
@@ -33,11 +34,13 @@ from repro.graph.waves import (
 )
 from repro.kernels.substream_match.ops import (
     MEGA_SEG_BLOCK,
+    match_epochs,
     mega_plan,
     substream_match,
     traffic_bytes,
     wave_plan,
 )
+from repro.obs import trace as obs_trace
 
 
 def _round_up(x, mult):
@@ -112,6 +115,162 @@ def test_stopwatch_measures_even_when_disabled():
     assert ev["dur"] == pytest.approx(sw2.seconds * 1e6, rel=1e-9)
 
 
+def _spans(tel, name=None):
+    return [
+        e for e in tel.tracer.events
+        if e["ph"] == "X" and (name is None or e["name"] == name)
+    ]
+
+
+def test_enabled_spans_land_in_the_profiler_host_plane(tmp_path):
+    """Every recorded span is also a ``repro.<name>`` profiler
+    annotation, and the stage seconds are still the spans' own
+    measurements beside it."""
+    from jax.profiler import ProfileData
+
+    stream, cfg = _workload(m=300, n=64, L=8)
+    substream_match(stream, cfg, schedule="mega")  # compile outside the trace
+    tel = obs.Telemetry()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        substream_match(stream, cfg, schedule="mega", telemetry=tel)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    names = {
+        ev.name for plane in ProfileData.from_file(str(path)).planes
+        for line in plane.lines for ev in line.events
+    }
+    assert {
+        "repro.wave_schedule.assign", "repro.copy.d2h", "repro.layout.gather",
+        "repro.pallas_mega.layout", "repro.copy.h2d",
+    } <= names
+    rec = tel.match_calls[-1]
+    (assign,) = _spans(tel, "wave_schedule.assign")
+    layout = sum(e["dur"] for e in _spans(tel, "pallas_mega.layout"))
+    assert rec.stage_seconds["schedule"] * 1e6 == pytest.approx(assign["dur"], rel=1e-9)
+    assert rec.stage_seconds["layout"] * 1e6 == pytest.approx(layout, rel=1e-9)
+
+
+def test_span_args_carry_call_and_parent():
+    """``call`` ties a job's spans (its merge too) to its engine call;
+    ``parent`` names the enclosing span."""
+    stream, cfg = _workload(m=300, n=64, L=8)
+    tel = obs.Telemetry()
+    with tel.span("before", k=1):
+        pass
+    for _ in range(2):
+        res = substream_match(stream, cfg, schedule="mega", telemetry=tel)
+        merge.merge_host(stream, res, cfg, telemetry=tel)
+    assert _spans(tel, "before")[0]["args"] == {"k": 1, "call": None, "parent": None}
+    gathers = _spans(tel, "layout.gather")
+    assert [e["args"] for e in gathers] == [
+        {"call": 0, "parent": "pallas_mega.layout"},
+        {"call": 1, "parent": "pallas_mega.layout"},
+    ]
+    merge_copies = [
+        e["args"] for e in _spans(tel, "copy.d2h") if e["args"]["parent"] == "merge.host"
+    ]
+    assert [(a["call"], a["what"]) for a in merge_copies] == [
+        (0, "stream"), (0, "assigned"), (1, "stream"), (1, "assigned"),
+    ]
+    d2h = {(e["args"]["what"], e["args"]["parent"]) for e in _spans(tel, "copy.d2h")}
+    assert {
+        ("stream", None), ("stream", "pallas_mega.layout"),
+        ("assigned_slots", "pallas_mega.layout"),
+    } <= d2h
+    for e in _spans(tel, "copy.h2d"):
+        assert e["args"]["parent"] in (
+            "pallas_mega.compile", "pallas_mega.execute", None,
+        )
+    assert {e["args"]["what"] for e in _spans(tel, "copy.h2d")} == {"slots", "assigned"}
+    (align,) = [e for e in _spans(tel, "layout.block_align") if e["args"]["call"] == 1]
+    assert align["args"]["parent"] == "pallas_mega.layout"
+
+
+@pytest.mark.parametrize("eng", ["edges", "waves", "mega"])
+def test_kernel_trips_are_the_plans_trip_count(eng):
+    """``kernel.trips`` is the kernel's dependent trip count, exact from
+    the plan: one per edge, per wave segment, or per mega tile."""
+    stream, cfg = _workload(m=700, n=160, L=8)
+    src, dst = np.asarray(stream.src), np.asarray(stream.dst)
+    tel = obs.Telemetry()
+    substream_match(stream, cfg, schedule=eng, telemetry=tel)
+    sch = wave_schedule(src, dst, valid=np.asarray(stream.valid))
+    want = {
+        "edges": lambda: stream.num_edges,
+        "waves": lambda: wave_plan(cfg.n, cfg.L, sch).num_segments,
+        "mega": lambda: mega_plan(
+            cfg.n, cfg.L, block_aligned_layout(sch, MEGA_SEG_BLOCK)
+        ).num_tiles,
+    }[eng]()
+    assert tel.match_calls[-1].counters["kernel.trips"] == want
+
+
+def test_disabled_path_opens_no_profiler_annotation(monkeypatch):
+    opened = []
+
+    def annotation(name):
+        opened.append(name)
+        return contextlib.nullcontext()
+
+    monkeypatch.setattr(obs_trace, "_annotation", annotation)
+    stream, cfg = _workload(m=300, n=64, L=8)
+    for eng in ("edges", "waves", "mega"):
+        res = substream_match(stream, cfg, schedule=eng)
+    merge.merge_host(stream, res, cfg)
+    match_epochs(stream, cfg, epochs=2, engine="mega")
+    mwm_pipeline(stream, cfg, part1="pallas", K=8)
+    assert opened == []
+    substream_match(stream, cfg, schedule="mega", telemetry=obs.Telemetry())
+    assert {"wave_schedule.assign", "copy.d2h", "layout.gather"} <= set(opened)
+
+
+def test_traffic_bytes_pinned_on_a_small_mega_plan():
+    """HBM bytes of one mega call: 16 B per streamed slot, and the bit
+    block written once (and read once more when state is carried in);
+    the VMEM rows a slot touches move none."""
+    stream, cfg = _workload(m=600, n=128, L=8)
+    tel = obs.Telemetry()
+    substream_match(stream, cfg, schedule="mega", telemetry=tel)
+    counters = tel.match_calls[-1].counters
+    # 59 tiles of 2 x 8 slots in one grid program, a 4 KiB bit block
+    assert counters["layout.num_tiles"] == 59
+    assert counters["plan.tiles_per_block"] == 59
+    assert counters["plan.bit_block_bytes"] == 4096
+    assert counters["traffic.hbm_bytes"] == 59 * 16 * 16 + 4096 == 19_200
+    assert traffic_bytes(944, 4096) == 19_200
+    mb0 = np.zeros((cfg.n, 1), np.uint8)
+    substream_match(stream, cfg, schedule="mega", telemetry=tel, mb0=mb0)
+    assert tel.match_calls[-1].counters["traffic.hbm_bytes"] == 944 * 16 + 2 * 4096
+
+
+def test_mwm_pipeline_records_its_merge():
+    """The public entry hands its telemetry to the merge: ``merge.host``
+    and its copies are recorded on the per-edge path too."""
+    stream, cfg = _workload(m=300, n=64, L=8)
+    tel = obs.Telemetry()
+    idx, weight = mwm_pipeline(stream, cfg, part1="pallas", K=8, telemetry=tel)
+    assert len(_spans(tel, "merge.host")) == 1
+    assert tel.counters.get("merge.matched_edges") == len(idx)
+    assert {e["args"]["what"] for e in _spans(tel, "copy.d2h")} == {
+        "stream", "assigned", "weight",
+    }
+    assert tel.match_calls[-1].counters["kernel.trips"] == stream.num_edges
+    idx2, weight2 = mwm_pipeline(stream, cfg, part1="pallas", K=8)
+    np.testing.assert_array_equal(idx, idx2)
+    assert weight == weight2
+
+
+def test_match_epochs_records_state_spans():
+    stream, cfg = _workload(m=300, n=64, L=8)
+    tel = obs.Telemetry()
+    match_epochs(stream, cfg, epochs=3, engine="mega", telemetry=tel)
+    assert len(_spans(tel, "state.initial")) == 1
+    folds = _spans(tel, "epoch.fold")
+    assert [e["args"]["call"] for e in folds] == [0, 1, 2]
+
+
 # ------------------------------------------------------- disabled path
 
 
@@ -141,6 +300,8 @@ def test_disabled_hot_loop_does_not_accumulate_allocations():
             pass
         tel.count("hot.counter")
         with rec.stage("layout"):
+            pass
+        with rec.span("copy.d2h", what="stream"):
             pass
         rec.put("gauge", 1)
     current, peak = tracemalloc.get_traced_memory()
@@ -222,9 +383,7 @@ def test_wave_counters_bit_exact_against_plan():
     for k, v in schedule_counters(sch).items():
         assert rec.counters[k] == v, k
     total = _round_up(max(sch.num_segments, 1), plan.block_s) * plan.seg
-    assert rec.counters["traffic.hbm_bytes"] == traffic_bytes(
-        total, sch.num_scheduled, plan.width
-    )
+    assert rec.counters["traffic.hbm_bytes"] == traffic_bytes(total, plan.nbytes)
 
 
 def test_mega_counters_bit_exact_against_plan():
@@ -246,9 +405,7 @@ def test_mega_counters_bit_exact_against_plan():
     )
     bslots = plan.seg_block * plan.seg
     total = _round_up(max(layout.num_tiles, 1), plan.tiles_per_block) * bslots
-    assert rec.counters["traffic.hbm_bytes"] == traffic_bytes(
-        total, sch.num_scheduled, plan.width
-    )
+    assert rec.counters["traffic.hbm_bytes"] == traffic_bytes(total, plan.nbytes)
 
 
 def test_counters_deterministic_across_runs():
@@ -309,17 +466,6 @@ def test_schedule_seconds_one_timing_path():
     # and the schedule geometry landed in the session counters
     assert tel.counters.get("schedule.num_waves") == sch.num_waves
     assert tel.counters.get("schedule.fill") == sch.fill
-
-
-def test_roofline_fraction_sane():
-    stream, cfg = _workload(m=600, n=128, L=8)
-    tel = obs.Telemetry()
-    substream_match(stream, cfg, schedule="mega", telemetry=tel)
-    terms = tel.match_calls[-1].roofline()
-    assert terms["bound_edges_per_s"] > 0
-    assert terms["bytes_per_edge"] > 0
-    assert 0 < terms["achieved_fraction"] < 1  # interpret mode is slow
-    assert terms["dominant"] in ("pipeline", "memory")
 
 
 def test_xla_engines_and_merge_record():
