@@ -22,10 +22,15 @@ With no occupancy cap (the default) earliest-fit collapses to the pure
 conflict-depth assignment, which is *provably minimal*: the wave count
 equals the longest conflict chain (≥ the maximum vertex multiplicity —
 every edge at the hub vertex needs its own wave), so no valid
-vertex-disjoint decomposition can use fewer waves. The depth pass is
-fully vectorized as numpy batch passes over ready edges (an indegree
-peel of the 2-predecessor conflict DAG), replacing the former per-edge
-Python loop; the capped path keeps the sequential earliest-fit packer.
+vertex-disjoint decomposition can use fewer waves. The uncapped pass
+is vectorized numpy in two steps. The conflict links (each edge's next
+edge at either endpoint) come from one ``np.sort`` of packed int64
+endpoint keys, ``((vertex << rb) | rank) << 1 | side``, decoded with
+shifts and masks. An indegree peel of that 2-predecessor DAG then
+resolves one wave per pass and keeps each pass's frontier, so it hands
+the pack the wave-major order itself: the pack needs no sort (one sort
+of packed (wave, position) keys when an explicit ``order`` permuted the
+stream). The capped path keeps the sequential earliest-fit packer.
 
 Layout (where the "fill-packed" in the title lives): waves are *not*
 padded to one global maximum width. They are packed back-to-back into
@@ -133,65 +138,87 @@ def _conflict_links(su: np.ndarray, sv: np.ndarray):
     """Successor links of the conflict DAG over ranks 0..k-1.
 
     Edge r (endpoints ``su[r]``, ``sv[r]``) conflicts with the previous
-    and next edge touching either endpoint. Returns (succ int32 [k, 2],
-    pred_count int32 [k]): ``succ[r, s]`` is the rank of the next edge
-    at r's endpoint s (-1 = none), ``pred_count[r]`` how many earlier
-    edges r directly waits on (0, 1, or 2). Self-loops contribute one
-    endpoint entry, so an edge never depends on itself.
+    and next edge touching either endpoint. Returns (succ int64 [k, 2],
+    waiting int64 [k + 1]): ``succ[r, s]`` is the rank of the next edge
+    at r's endpoint s, or the sentinel ``k`` where there is none;
+    ``waiting[r]`` is how many earlier edges r directly waits on (0, 1,
+    or 2), and ``waiting[k]`` (2k + 1) stays above what the sentinel can
+    ever be notified, so it never becomes ready. Self-loops contribute
+    one endpoint entry, so an edge never depends on itself.
+
+    Every endpoint entry is one packed int64 key
+    ``((vertex << rb) | rank) << 1 | side`` with ``rb = k.bit_length()``,
+    so one ``np.sort`` groups the entries by vertex in rank order, and
+    the low ``rb + 1`` bits of a key are its flat index ``2 * rank +
+    side`` into ``succ``: consecutive keys of one vertex are one link.
     """
     k = su.shape[0]
-    loop = su == sv
-    ranks = np.arange(k, dtype=np.int64)
-    vert = np.concatenate([su, sv[~loop]])
-    rank = np.concatenate([ranks, ranks[~loop]])
-    side = np.concatenate(
-        [np.zeros(k, np.int8), np.ones(int((~loop).sum()), np.int8)]
-    )
-    o = np.lexsort((rank, vert))
-    vo, ro, so = vert[o], rank[o], side[o]
-    same = np.empty(len(o), bool)
-    if len(o):
-        same[0] = False
-        same[1:] = vo[1:] == vo[:-1]
-    i = np.nonzero(same)[0]
-    succ = np.full((k, 2), -1, np.int64)
-    succ[ro[i - 1], so[i - 1]] = ro[i]
-    pred_count = np.zeros(k, np.int64)
-    np.add.at(pred_count, ro[i], 1)
-    return succ, pred_count
+    rb = k.bit_length()
+    if k:
+        # int32 vertex ids and k < 2**31 always fit: 31 + 31 + 1 bits
+        lo = int(min(su.min(), sv.min()))
+        hi = int(max(su.max(), sv.max()))
+        if lo < 0 or hi.bit_length() + rb + 1 > 63:
+            raise ValueError(
+                f"vertex ids [{lo}, {hi}] with {k} edges do not fit the "
+                "packed int64 conflict key (ids must be non-negative int32)"
+            )
+    # built, sorted and decoded in place: each fresh array of this size
+    # costs page faults on top of its pass
+    shift = rb + 1
+    other = np.flatnonzero(su != sv)
+    key = np.empty(k + other.size, np.int64)
+    np.left_shift(su, shift, out=key[:k])
+    key[:k] |= np.arange(0, 2 * k, 2)
+    np.left_shift(sv[other], shift, out=key[k:])
+    other <<= 1
+    other |= 1
+    key[k:] |= other
+    key.sort()
+    vert = key >> shift
+    same = vert[1:] == vert[:-1]
+    del vert
+    key &= (1 << shift) - 1
+    nxt = key[1:][same]
+    nxt >>= 1
+    succ = np.full(2 * k, k, np.int64)
+    succ[key[:-1][same]] = nxt
+    waiting = np.bincount(nxt, minlength=k + 1)
+    waiting[k] = 2 * k + 1
+    return succ.reshape(k, 2), waiting
 
 
-def _assign_depth_batched(su: np.ndarray, sv: np.ndarray) -> np.ndarray:
-    """Conflict depth per rank via numpy batch passes over ready edges.
+def _peel_waves(succ: np.ndarray, waiting: np.ndarray):
+    """Waves of the uncapped schedule, by indegree peel of the conflict DAG.
 
-    Pass t resolves exactly the edges of depth t (an edge is ready once
-    every earlier edge sharing an endpoint has a depth, and its depth is
-    one past its deepest predecessor — so the ready frontier of pass t
-    IS depth level t). Each edge enters the frontier once and notifies
-    at most two successors, so total element work is O(m) spread over
-    ``depth_max`` vectorized passes — no per-edge Python loop.
+    Pass d resolves exactly the edges of conflict depth d (an edge is
+    ready once every earlier edge sharing an endpoint has a depth, and
+    its depth is one past its deepest predecessor — so the ready
+    frontier of pass d IS wave d). Each edge enters the frontier once
+    and notifies at most two successors, so total element work is O(m)
+    spread over ``num_waves`` vectorized passes — no per-edge Python
+    loop. ``succ`` / ``waiting`` are :func:`_conflict_links`' output;
+    ``waiting`` is consumed.
+
+    Returns (ranks int64 [k], counts int64 [num_waves]): the frontiers
+    concatenated — wave-major, ascending rank inside each wave, the
+    order the pack needs — and each wave's size.
     """
-    k = su.shape[0]
-    depth = np.zeros(k, np.int64)
-    if k == 0:
-        return depth
-    succ, waiting = _conflict_links(su, sv)
-    frontier = np.nonzero(waiting == 0)[0]
-    d = -1
+    fronts = []
+    frontier = np.flatnonzero(waiting == 0)
     while frontier.size:
-        d += 1
-        depth[frontier] = d
-        nxt = succ[frontier].reshape(-1)
-        nxt = nxt[nxt >= 0]
-        if not nxt.size:
-            break
+        fronts.append(frontier)
+        nxt = succ.take(frontier, 0).ravel()
         np.subtract.at(waiting, nxt, 1)
-        frontier = nxt[waiting[nxt] == 0]
+        frontier = nxt[waiting.take(nxt) == 0]
         if frontier.size > 1:
-            # a rank occurs twice in ``nxt`` when both of its
-            # predecessors resolved this pass
+            # sorts the frontier; a rank occurs twice in ``nxt`` when
+            # both of its predecessors resolved this pass
             frontier = np.unique(frontier)
-    return depth
+    if not fronts:
+        return np.zeros(0, np.int64), np.zeros(0, np.int64)
+    counts = np.fromiter(map(len, fronts), np.int64, len(fronts))
+    return np.concatenate(fronts), counts
 
 
 def _assign_earliest_fit(
@@ -263,10 +290,12 @@ def wave_schedule(
     width of the packed layout (see :data:`SEG`).
 
     ``telemetry`` records the two host phases as spans
-    (``wave_schedule.assign`` / ``wave_schedule.pack``) plus the
-    schedule geometry counters; the deprecated ``schedule_seconds`` /
-    ``pack_seconds`` fields are populated from the *same* stopwatch
-    measurements, so there is one timing path either way.
+    (``wave_schedule.assign`` / ``wave_schedule.pack``; uncapped, the
+    assignment's children ``wave_schedule.links`` and
+    ``wave_schedule.peel``) plus the schedule geometry counters; the
+    deprecated ``schedule_seconds`` / ``pack_seconds`` fields are
+    populated from the *same* stopwatch measurements, so there is one
+    timing path either way.
     """
     src = np.asarray(src, dtype=np.int64)
     dst = np.asarray(dst, dtype=np.int64)
@@ -287,21 +316,30 @@ def wave_schedule(
         su = src[positions]
         sv = dst[positions]
         if max_width is None:
-            wave_of_rank = _assign_depth_batched(su, sv)
+            with telemetry.span("wave_schedule.links"):
+                succ, waiting = _conflict_links(su, sv)
+            with telemetry.span("wave_schedule.peel"):
+                ranks, counts = _peel_waves(succ, waiting)
         else:
             wave_of_rank = _assign_earliest_fit(su, sv, max_width)
-        wave = np.full(m, -1, dtype=np.int64)
-        wave[positions] = wave_of_rank
+            ranks = np.argsort(wave_of_rank, kind="stable")
+            counts = np.bincount(wave_of_rank)
 
     with obs.stopwatch(telemetry, "wave_schedule.pack") as sw_pack:
-        num_waves = int(wave_of_rank.max()) + 1 if wave_of_rank.size else 0
-        scheduled = np.nonzero(wave >= 0)[0]
-        # wave-major, stream-position-minor: stable sort on the wave key alone
-        # (``scheduled`` is already ascending in stream position)
-        order_out = scheduled[np.argsort(wave[scheduled], kind="stable")]
-        counts = np.bincount(wave[scheduled], minlength=max(num_waves, 1))[:num_waves]
+        num_waves = counts.shape[0]
         offsets = np.zeros(num_waves + 1, dtype=np.int64)
         np.cumsum(counts, out=offsets[1:])
+        wave_ids = np.repeat(np.arange(num_waves, dtype=np.int64), counts)
+        # wave-major, stream-position-minor: ranks are ascending inside
+        # each wave, and so are their stream positions unless an
+        # explicit ``order`` permuted them — then one sort of the packed
+        # (wave, position) key restores position order inside each wave
+        order_out = positions[ranks]
+        if order is not None:
+            pb = m.bit_length()
+            order_out = np.sort((wave_ids << pb) | order_out) & ((1 << pb) - 1)
+        wave = np.full(m, -1, dtype=np.int64)
+        wave[order_out] = wave_ids
 
         # fill-packed layout: wave k occupies ceil(counts[k] / seg) segment
         # rows back-to-back; only its last row carries (< seg) padding

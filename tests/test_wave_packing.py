@@ -15,10 +15,13 @@ The packer's contract, on top of the generic wave invariants:
   ``mb`` — including self-loops, duplicate edges, L % 8 != 0, capped
   (earliest-fit occupancy) schedules, and single-edge streams.
 """
+import contextlib
+
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro import obs
 from repro.core import EdgeStream, SubstreamConfig, mwm_scan, mwm_waves
 from repro.graph.waves import (
     SEG,
@@ -35,6 +38,7 @@ from repro.kernels.substream_match.ops import (
     mega_plan,
     substream_match,
 )
+from repro.obs import trace as obs_trace
 
 SETTINGS = dict(max_examples=15, deadline=None)
 
@@ -239,3 +243,140 @@ def test_conflict_free_stream_packs_full_segments(m):
     assert sch.num_waves == 1
     assert sch.num_segments == -(-m // SEG)
     assert sch.fill == m / (sch.num_segments * SEG)
+
+
+def _reference_schedule(src, dst, valid=None, order=None, seg=SEG):
+    """The uncapped schedule as a plain two-step computation: the
+    sequential ``greedy_depths`` oracle, then a stable argsort of the
+    depths and a bincount for the wave-major order and the slot fill.
+    Vertex ids are relabelled densely first (depths do not depend on
+    the labels), so ids near 2**31 need no 2**31-entry table."""
+    src = np.asarray(src, np.int64)
+    dst = np.asarray(dst, np.int64)
+    m = src.shape[0]
+    ids, dense = np.unique(np.concatenate([src, dst]), return_inverse=True)
+    depth = greedy_depths(dense[:m], dense[m:], valid=valid, order=order)
+    scheduled = np.nonzero(depth >= 0)[0]
+    order_out = scheduled[np.argsort(depth[scheduled], kind="stable")]
+    num_waves = int(depth.max()) + 1 if scheduled.size else 0
+    counts = np.bincount(depth[scheduled], minlength=num_waves)
+    offsets = np.concatenate([[0], np.cumsum(counts)])
+    seg_offsets = np.concatenate([[0], np.cumsum(-(-counts // seg))])
+    slots = np.full((int(seg_offsets[-1]), seg), -1, np.int64)
+    for k in range(num_waves):
+        members = order_out[offsets[k] : offsets[k + 1]]
+        rows = slots[seg_offsets[k] : seg_offsets[k + 1]].reshape(-1)
+        rows[: members.size] = members
+        slots[seg_offsets[k] : seg_offsets[k + 1]] = rows.reshape(-1, seg)
+    arrays = dict(
+        wave=depth, order=order_out, offsets=offsets, slots=slots,
+        seg_offsets=seg_offsets,
+    )
+    return {k: np.asarray(a, np.int64).astype(np.int32) for k, a in arrays.items()}
+
+
+def _random_stream(rng, n, m):
+    src = rng.integers(0, n, m)
+    dst = rng.integers(0, n, m)
+    dst[::7] = src[::7]  # self-loops
+    src[m // 2 :: 5] = src[0]  # duplicate pairs
+    dst[m // 2 :: 5] = dst[0]
+    return src, dst
+
+
+def _equivalence_case(name):
+    """(src, dst, valid, order, seg) of one named stream."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    if name == "loops_and_duplicates":
+        src, dst = _random_stream(rng, 40, 600)
+        return src, dst, None, None, SEG
+    if name == "padding":
+        src, dst = _random_stream(rng, 40, 600)
+        return src, dst, rng.random(600) > 0.2, None, SEG
+    if name == "explicit_order":
+        src, dst = _random_stream(rng, 40, 600)
+        return src, dst, rng.random(600) > 0.2, rng.permutation(600), SEG
+    if name == "explicit_order_subset":
+        src, dst = _random_stream(rng, 30, 400)
+        return src, dst, None, rng.permutation(400)[:250], 3
+    if name == "dense_multigraph":
+        src, dst = _random_stream(rng, 4, 500)
+        return src, dst, None, None, SEG
+    if name == "all_self_loops":
+        src = rng.integers(0, 20, 300)
+        return src, src.copy(), None, None, SEG
+    if name == "empty":
+        return np.zeros(0, np.int64), np.zeros(0, np.int64), None, None, SEG
+    if name == "one_edge":
+        return np.array([5]), np.array([9]), None, None, SEG
+    if name == "one_padding_edge":
+        return np.array([5]), np.array([9]), np.array([False]), None, SEG
+    if name == "star":  # one hub: a conflict chain as deep as m
+        m = 700
+        leaves = rng.permutation(np.arange(1, m + 1))
+        return np.zeros(m, np.int64), leaves, None, None, SEG
+    if name == "ids_near_int32_max":
+        top = 2**31 - 1
+        src, dst = _random_stream(rng, 64, 600)
+        return top - src, top - dst, rng.random(600) > 0.1, None, SEG
+    raise KeyError(name)
+
+
+EQUIVALENCE_CASES = [
+    "loops_and_duplicates", "padding", "explicit_order",
+    "explicit_order_subset", "dense_multigraph", "all_self_loops", "empty",
+    "one_edge", "one_padding_edge", "star", "ids_near_int32_max",
+]
+
+
+@pytest.mark.parametrize("name", EQUIVALENCE_CASES)
+def test_uncapped_schedule_equals_reference_pack(name):
+    """Every array of the uncapped schedule, dtypes included, equals the
+    sequential depths packed by a stable argsort: the peel's wave-major
+    order and the packed-key links change how, never what."""
+    src, dst, valid, order, seg = _equivalence_case(name)
+    sch = wave_schedule(src, dst, valid=valid, order=order, seg=seg)
+    want = _reference_schedule(src, dst, valid=valid, order=order, seg=seg)
+    assert sch.num_edges == len(src)
+    for field, expected in want.items():
+        got = getattr(sch, field)
+        assert got.dtype == expected.dtype == np.int32, field
+        assert got.shape == expected.shape, field
+        np.testing.assert_array_equal(got, expected, err_msg=field)
+    if name == "star":
+        assert sch.num_waves == src.shape[0]
+
+
+def test_assign_spans_links_and_peel(monkeypatch):
+    """Uncapped, ``wave_schedule.assign`` holds the two child spans
+    ``links`` and ``peel``; the capped packer has neither, and with
+    telemetry off nothing is recorded or annotated."""
+    opened = []
+
+    def annotation(name):
+        opened.append(name)
+        return contextlib.nullcontext()
+
+    monkeypatch.setattr(obs_trace, "_annotation", annotation)
+    src, dst = _random_stream(np.random.default_rng(3), 40, 300)
+    wave_schedule(src, dst)
+    wave_schedule(src, dst, telemetry=obs.DISABLED)
+    assert opened == []
+
+    tel = obs.Telemetry()
+    wave_schedule(src, dst, telemetry=tel)
+    spans = [e for e in tel.tracer.events if e.get("ph") == "X"]
+    by_name = {e["name"]: e for e in spans}
+    assert [e["name"] for e in spans] == [
+        "wave_schedule.links", "wave_schedule.peel",
+        "wave_schedule.assign", "wave_schedule.pack",
+    ]
+    for child in ("wave_schedule.links", "wave_schedule.peel"):
+        assert by_name[child]["args"]["parent"] == "wave_schedule.assign"
+    assert by_name["wave_schedule.assign"]["args"]["parent"] is None
+    assert {"wave_schedule.links", "wave_schedule.peel"} <= set(opened)
+
+    capped = obs.Telemetry()
+    wave_schedule(src, dst, max_width=4, telemetry=capped)
+    names = {e["name"] for e in capped.tracer.events if e.get("ph") == "X"}
+    assert names == {"wave_schedule.assign", "wave_schedule.pack"}
