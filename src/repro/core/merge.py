@@ -35,7 +35,8 @@ def merge_host(
     stays a loop, exactly like the paper's sequential post-processor.
 
     ``telemetry`` records one ``merge.host`` span (the copies to the
-    host inside it as ``copy.d2h`` spans) plus the recorded / matched
+    host inside it as ``copy.d2h`` spans, the sort as ``merge.order``
+    and the greedy pass as ``merge.greedy``) plus the recorded / matched
     edge counters.
     """
     with telemetry.span("merge.host"):
@@ -44,7 +45,11 @@ def merge_host(
             dst = np.asarray(stream.dst)
         with telemetry.span("copy.d2h", what="assigned"):
             assigned = np.asarray(result.assigned)
-        recorded = np.nonzero(assigned >= 0)[0]
+        with telemetry.span("merge.order"):
+            recorded = np.nonzero(assigned >= 0)[0]
+            # descending i, stream order within i: stable sort on the
+            # major key alone (``recorded`` is ascending in stream position)
+            order = recorded[np.argsort(cfg.L - 1 - assigned[recorded], kind="stable")]
         if recorded.size == 0:
             # empty / all-dropped streams: a well-formed empty T, skipping
             # the n-sized tbits allocation (n may be 0 here)
@@ -53,18 +58,16 @@ def merge_host(
                 telemetry.counters.put("merge.recorded_edges", 0)
                 telemetry.counters.put("merge.matched_edges", 0)
             return np.zeros(0, dtype=np.int64)
-        # descending i, stream order within i: stable sort on the major key
-        # alone (``recorded`` is already ascending in stream position)
-        order = recorded[np.argsort(cfg.L - 1 - assigned[recorded], kind="stable")]
-        tbits = np.zeros(cfg.n, dtype=bool)
-        out = []
-        for e in order.tolist():
-            u, v = src[e], dst[e]
-            if not tbits[u] and not tbits[v]:
-                tbits[u] = True
-                tbits[v] = True
-                out.append(e)
-        merged = np.sort(np.asarray(out, dtype=np.int64))
+        with telemetry.span("merge.greedy"):
+            tbits = np.zeros(cfg.n, dtype=bool)
+            out = []
+            for e in order.tolist():
+                u, v = src[e], dst[e]
+                if not tbits[u] and not tbits[v]:
+                    tbits[u] = True
+                    tbits[v] = True
+                    out.append(e)
+            merged = np.sort(np.asarray(out, dtype=np.int64))
     if telemetry.enabled:
         telemetry.counters.add("merge.host.calls")
         telemetry.counters.put("merge.recorded_edges", int(recorded.size))

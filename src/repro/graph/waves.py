@@ -295,22 +295,26 @@ def wave_schedule(
     ``wave_schedule.peel``) plus the schedule geometry counters; the
     deprecated ``schedule_seconds`` / ``pack_seconds`` fields are
     populated from the *same* stopwatch measurements, so there is one
-    timing path either way.
+    timing path either way. The casts on either side of the two phases
+    are the spans ``wave_schedule.prepare`` (the int64 stream, the valid
+    mask and the scheduled positions) and ``wave_schedule.emit`` (the
+    int32 arrays of the schedule); neither is part of a phase's seconds.
     """
-    src = np.asarray(src, dtype=np.int64)
-    dst = np.asarray(dst, dtype=np.int64)
-    m = src.shape[0]
-    if dst.shape[0] != m:
-        raise ValueError(f"src/dst length mismatch: {m} vs {dst.shape[0]}")
     if max_width is not None and max_width < 1:
         raise ValueError(f"max_width must be >= 1, got {max_width}")
     if seg < 1:
         raise ValueError(f"seg must be >= 1, got {seg}")
-    valid_np = (
-        np.ones(m, dtype=bool) if valid is None else np.asarray(valid, dtype=bool)
-    )
-    positions = np.arange(m) if order is None else np.asarray(order, dtype=np.int64)
-    positions = positions[valid_np[positions]]
+    with telemetry.span("wave_schedule.prepare"):
+        src = np.asarray(src, dtype=np.int64)
+        dst = np.asarray(dst, dtype=np.int64)
+        m = src.shape[0]
+        if dst.shape[0] != m:
+            raise ValueError(f"src/dst length mismatch: {m} vs {dst.shape[0]}")
+        valid_np = (
+            np.ones(m, dtype=bool) if valid is None else np.asarray(valid, dtype=bool)
+        )
+        positions = np.arange(m) if order is None else np.asarray(order, dtype=np.int64)
+        positions = positions[valid_np[positions]]
 
     with obs.stopwatch(telemetry, "wave_schedule.assign") as sw_assign:
         su = src[positions]
@@ -353,16 +357,17 @@ def wave_schedule(
             row = np.repeat(seg_offsets[:-1], counts) + within // seg
             slots[row, within % seg] = order_out
 
-    schedule = WaveSchedule(
-        wave=wave.astype(np.int32),
-        order=order_out.astype(np.int32),
-        offsets=offsets.astype(np.int32),
-        slots=slots.astype(np.int32),
-        seg_offsets=seg_offsets.astype(np.int32),
-        num_edges=m,
-        schedule_seconds=sw_assign.seconds,
-        pack_seconds=sw_pack.seconds,
-    )
+    with telemetry.span("wave_schedule.emit"):
+        schedule = WaveSchedule(
+            wave=wave.astype(np.int32),
+            order=order_out.astype(np.int32),
+            offsets=offsets.astype(np.int32),
+            slots=slots.astype(np.int32),
+            seg_offsets=seg_offsets.astype(np.int32),
+            num_edges=m,
+            schedule_seconds=sw_assign.seconds,
+            pack_seconds=sw_pack.seconds,
+        )
     if telemetry.enabled:
         telemetry.counters.update(schedule_counters(schedule))
     return schedule

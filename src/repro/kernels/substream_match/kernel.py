@@ -59,7 +59,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 #: Lanes in one vector row of the TPU.
 LANES = 128
-#: Int32 words per slot in the SMEM slot stream: (u, v, cnt).
+#: Int32 words per slot in the SMEM slot streams: one each of u, v, cnt.
 SLOT_WORDS = 3
 
 
@@ -75,7 +75,7 @@ def _prefix_words(cnt, k, bits: int):
 
 
 def _kernel(
-    bound_ref, slots_ref, *refs,
+    bound_ref, u_ref, v_ref, cnt_ref, *refs,
     group: int, groups_per_block: int, lanes: int, bits: int,
 ):
     if len(refs) == 4:
@@ -115,9 +115,9 @@ def _kernel(
         for j in range(group):
             s = t * group + j
             # Stage 1: slot scalars and row addresses
-            u = slots_ref[SLOT_WORDS * s]
-            v = slots_ref[SLOT_WORDS * s + 1]
-            cnt = slots_ref[SLOT_WORDS * s + 2]
+            u = u_ref[s]
+            v = v_ref[s]
+            cnt = cnt_ref[s]
             ru, ou = locate(u)
             rv, ov = locate(v)
             # Stage 2-3: single-row loads; v's words rotated onto u's lanes
@@ -146,7 +146,9 @@ def _kernel(
 
 
 def substream_match_pallas(
-    slots: jax.Array,  # int32 [SLOT_WORDS * total]: (u, v, cnt) per slot
+    u: jax.Array,  # int32 [total]: first endpoint per slot
+    v: jax.Array,  # int32 [total]: second endpoint per slot
+    cnt: jax.Array,  # int32 [total]: thresholds the slot's weight passes
     num_groups: jax.Array,  # int32 [1]: real groups; trips stop there
     rows: int,
     width: int,
@@ -161,7 +163,11 @@ def substream_match_pallas(
     """Raw pallas_call wrapper: run the slot stream over a folded bit block.
 
     ``total`` slots form ``total / (group * groups_per_block)`` grid
-    programs. Within a group the slots must be vertex-disjoint (any slot
+    programs; each program's block of ``u``, ``v`` and ``cnt`` is one
+    SMEM block per stream. The three are separate flat arrays: an
+    interleaved ``[total, 3]`` stream would be tiled (8, 128) in HBM on
+    its way to one flat array, 128 lanes for 3 words, about 512 B a
+    slot. Within a group the slots must be vertex-disjoint (any slot
     with ``cnt = 0`` may alias a vertex: it changes nothing). Returns
     ``(assigned int32 [total], mb int32 [rows, width])``; assigned is -1
     where nothing matched and undefined past ``num_groups`` groups.
@@ -169,15 +175,14 @@ def substream_match_pallas(
     zero-fills it.
     """
     block = group * groups_per_block
-    total = slots.shape[0] // SLOT_WORDS
+    total = u.shape[0]
+    assert v.shape == cnt.shape == (total,), (u.shape, v.shape, cnt.shape)
     assert total % block == 0, (total, group, groups_per_block)
     smem = pltpu.MemorySpace.SMEM
     in_specs = [
-        pl.BlockSpec(
-            (SLOT_WORDS * block,), lambda b, bound: (b,), memory_space=smem
-        )
-    ]
-    operands = [num_groups, slots]
+        pl.BlockSpec((block,), lambda b, bound: (b,), memory_space=smem)
+    ] * SLOT_WORDS
+    operands = [num_groups, u, v, cnt]
     scratch = []
     if mb_init is not None:
         assert mb_init.shape == (rows, width), (mb_init.shape, rows, width)

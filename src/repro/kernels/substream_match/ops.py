@@ -788,13 +788,14 @@ def _match_slots(u, v, w, num_groups, cfg, plan, group, groups_per_block,
                  interpret, mb0):
     """Traced core shared by the three engines: Stage 4's threshold
     count per slot (0 for self-loops and w <= 0), the kernel over the
-    interleaved (u, v, cnt) stream, and the caller-format bits."""
+    three (u, v, cnt) slot streams, and the caller-format bits."""
     thr = cfg.thresholds()
     cnt = jnp.sum(w[:, None] >= thr[None, :], axis=1, dtype=jnp.int32)
     cnt = jnp.where(u != v, cnt, 0)
-    slots = jnp.stack([u, v, cnt], axis=1).reshape(-1)
     assigned, mb = _kernel.substream_match_pallas(
-        slots,
+        u,
+        v,
+        cnt,
         jnp.full((1,), num_groups, jnp.int32),
         rows=plan.rows,
         width=plan.row_width,

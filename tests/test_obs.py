@@ -468,6 +468,54 @@ def test_schedule_seconds_one_timing_path():
     assert tel.counters.get("schedule.fill") == sch.fill
 
 
+def test_schedule_casts_lie_outside_the_schedule_and_pack_stages():
+    """``wave_schedule`` records ``prepare`` -> ``assign`` -> ``pack`` ->
+    ``emit``, one after the other, and an engine call's ``schedule`` /
+    ``pack`` stage seconds are the ``assign`` / ``pack`` spans alone."""
+    stream, cfg = _workload(m=700, n=160, L=8)
+    tel = obs.Telemetry()
+    substream_match(stream, cfg, schedule="mega", telemetry=tel)
+    names = ("prepare", "assign", "pack", "emit")
+    (prepare, assign, pack, emit) = (
+        _spans(tel, f"wave_schedule.{n}")[0] for n in names
+    )
+    assert all(len(_spans(tel, f"wave_schedule.{n}")) == 1 for n in names)
+    steps = [prepare, assign, pack, emit]
+    for a, b in zip(steps, steps[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"]
+    assert {e["args"]["parent"] for e in steps} == {None}
+    rec = tel.match_calls[-1]
+    assert rec.stage_seconds["schedule"] * 1e6 == pytest.approx(assign["dur"], rel=1e-9)
+    assert rec.stage_seconds["pack"] * 1e6 == pytest.approx(pack["dur"], rel=1e-9)
+
+
+def test_merge_order_and_greedy_nest_inside_merge_host():
+    stream, cfg = _workload(m=600, n=128, L=8)
+    res = substream_match(stream, cfg, schedule="mega")
+    tel = obs.Telemetry()
+    merged = merge.merge_host(stream, res, cfg, telemetry=tel)
+    np.testing.assert_array_equal(merged, merge.merge_host(stream, res, cfg))
+    (host,) = _spans(tel, "merge.host")
+    (order,) = _spans(tel, "merge.order")
+    (greedy,) = _spans(tel, "merge.greedy")
+    assert order["args"]["parent"] == greedy["args"]["parent"] == "merge.host"
+    assert host["ts"] <= order["ts"]
+    assert order["ts"] + order["dur"] <= greedy["ts"]
+    assert greedy["ts"] + greedy["dur"] <= host["ts"] + host["dur"]
+
+
+def test_merge_of_nothing_recorded_records_no_greedy_pass():
+    stream, cfg = _workload(m=50, n=32, L=8)
+    res = substream_match(stream, cfg, schedule="mega")
+    res = type(res)(
+        assigned=np.full(50, -1, np.int32), mb_packed=res.mb_packed, L=res.L
+    )
+    tel = obs.Telemetry()
+    assert merge.merge_host(stream, res, cfg, telemetry=tel).size == 0
+    assert len(_spans(tel, "merge.order")) == 1
+    assert _spans(tel, "merge.greedy") == []
+
+
 def test_xla_engines_and_merge_record():
     stream, cfg = _workload(m=400, n=96, L=8)
     tel = obs.Telemetry()

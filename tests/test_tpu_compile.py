@@ -142,3 +142,23 @@ def test_max_vertices_plan_compiles(one_chip, packed):
     _assert_kernel(
         _compile_edges(cfg, None, ops.MAX_BLOCK, one_chip, packed=packed)
     )
+
+
+def test_mega_device_memory_does_not_grow_with_padding(one_chip):
+    """The slot streams reach the kernel as three flat int32 arrays, so
+    the device program's scratch does not grow with the slot count. An
+    interleaved ``[total, 3]`` stream was tiled (8, 128) on its way to
+    one flat array, 512 B a slot: about 24 GB for the 46.7 M slots of a
+    scale-20 Kronecker job, more than the chip's 16 GB."""
+    sch = _independent_schedule(2**16)
+    layout = block_aligned_layout(sch, ops.MEGA_SEG_BLOCK)
+    plan = ops.mega_plan(N, L, layout)
+    group = plan.seg * plan.seg_block
+    small, large = (
+        _compile_slots(CFG, plan, group, one_chip, programs=p).memory_analysis()
+        for p in (2, 512)
+    )
+    slots = (512 - 2) * plan.block_e
+    assert large.temp_size_in_bytes - small.temp_size_in_bytes < 4 * slots
+    # u, v, w in: 12 B a slot
+    assert large.argument_size_in_bytes - small.argument_size_in_bytes == 12 * slots

@@ -368,8 +368,8 @@ def test_assign_spans_links_and_peel(monkeypatch):
     spans = [e for e in tel.tracer.events if e.get("ph") == "X"]
     by_name = {e["name"]: e for e in spans}
     assert [e["name"] for e in spans] == [
-        "wave_schedule.links", "wave_schedule.peel",
-        "wave_schedule.assign", "wave_schedule.pack",
+        "wave_schedule.prepare", "wave_schedule.links", "wave_schedule.peel",
+        "wave_schedule.assign", "wave_schedule.pack", "wave_schedule.emit",
     ]
     for child in ("wave_schedule.links", "wave_schedule.peel"):
         assert by_name[child]["args"]["parent"] == "wave_schedule.assign"
@@ -379,4 +379,7 @@ def test_assign_spans_links_and_peel(monkeypatch):
     capped = obs.Telemetry()
     wave_schedule(src, dst, max_width=4, telemetry=capped)
     names = {e["name"] for e in capped.tracer.events if e.get("ph") == "X"}
-    assert names == {"wave_schedule.assign", "wave_schedule.pack"}
+    assert names == {
+        "wave_schedule.prepare", "wave_schedule.assign", "wave_schedule.pack",
+        "wave_schedule.emit",
+    }
