@@ -23,14 +23,15 @@ conflict-depth assignment, which is *provably minimal*: the wave count
 equals the longest conflict chain (≥ the maximum vertex multiplicity —
 every edge at the hub vertex needs its own wave), so no valid
 vertex-disjoint decomposition can use fewer waves. The uncapped pass
-is vectorized numpy in two steps. The conflict links (each edge's next
-edge at either endpoint) come from one ``np.sort`` of packed int64
-endpoint keys, ``((vertex << rb) | rank) << 1 | side``, decoded with
-shifts and masks. An indegree peel of that 2-predecessor DAG then
-resolves one wave per pass and keeps each pass's frontier, so it hands
-the pack the wave-major order itself: the pack needs no sort (one sort
-of packed (wave, position) keys when an explicit ``order`` permuted the
-stream). The capped path keeps the sequential earliest-fit packer.
+has two steps. The conflict links (each edge's next edge at either
+endpoint) are built on the device that holds the stream, by two sorts
+of its endpoints (:func:`_links_device`); only the links come back to
+the host. A vectorized numpy indegree peel of that 2-predecessor DAG
+then resolves one wave per pass and keeps each pass's frontier, so it
+hands the pack the wave-major order itself: the pack needs no sort (one
+sort of packed (wave, position) keys when an explicit ``order``
+permuted the stream). The capped path keeps the sequential earliest-fit
+packer on the host.
 
 Layout (where the "fill-packed" in the title lives): waves are *not*
 padded to one global maximum width. They are packed back-to-back into
@@ -44,18 +45,23 @@ itself: every consumer that processed "one slots-row at a time"
 its row-major contract unchanged, with per-row traffic proportional to
 ``SEG`` instead of the largest wave.
 
-This module is pure scheduling — numpy in, numpy out, no dependency on
-:mod:`repro.core` — so the XLA reference (`repro.core.matching.
-mwm_waves`), the Pallas kernels (`repro.kernels.substream_match`) and
-the rounds engine (`repro.core.rounds`) share one schedule. Schedules
+This module is pure scheduling — a stream in (host or device arrays),
+a numpy schedule out, no dependency on :mod:`repro.core` — so the XLA
+reference (`repro.core.matching.mwm_waves`), the Pallas kernels
+(`repro.kernels.substream_match`) and the rounds engine
+(`repro.core.rounds`) share one schedule. Schedules
 are reusable across `L`/`eps` sweeps because they depend only on the
 edge endpoints and order.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro import obs
 
@@ -134,58 +140,129 @@ class WaveSchedule:
         return int(sizes.max()) if sizes.size else 0
 
 
-def _conflict_links(su: np.ndarray, sv: np.ndarray):
-    """Successor links of the conflict DAG over ranks 0..k-1.
+#: Vertex id of a link entry that touches no vertex: both endpoints of
+#: an invalid edge, the second endpoint of a self-loop, and the padding
+#: of the bucket. Vertex ids are non-negative, so it never meets one.
+NO_VERTEX = -(2**31)
 
-    Edge r (endpoints ``su[r]``, ``sv[r]``) conflicts with the previous
-    and next edge touching either endpoint. Returns (succ int64 [k, 2],
-    waiting int64 [k + 1]): ``succ[r, s]`` is the rank of the next edge
-    at r's endpoint s, or the sentinel ``k`` where there is none;
-    ``waiting[r]`` is how many earlier edges r directly waits on (0, 1,
-    or 2), and ``waiting[k]`` (2k + 1) stays above what the sentinel can
-    ever be notified, so it never becomes ready. Self-loops contribute
-    one endpoint entry, so an edge never depends on itself.
+#: Fewest link entries a links program is built for: streams of up to
+#: this many endpoints (the tests' streams among them) share one program.
+LINK_FLOOR = 4096
 
-    Every endpoint entry is one packed int64 key
-    ``((vertex << rb) | rank) << 1 | side`` with ``rb = k.bit_length()``,
-    so one ``np.sort`` groups the entries by vertex in rank order, and
-    the low ``rb + 1`` bits of a key are its flat index ``2 * rank +
-    side`` into ``succ``: consecutive keys of one vertex are one link.
+
+def link_bucket(entries: int) -> int:
+    """Link entries of the links program for ``entries`` real ones: the
+    least ``c * 2**j >= entries`` with ``8 <= c < 16`` (at most 12.5 %
+    padding), and at least :data:`LINK_FLOOR`. The program compiles once
+    per bucket, not once per stream length."""
+    entries = max(entries, LINK_FLOOR)
+    shift = entries.bit_length() - 4
+    return -(-entries >> shift) << shift
+
+
+@functools.partial(jax.jit, static_argnames="size")
+def _link_vertices(src, dst, valid, order, size):
+    """The link entries of ``size`` ranks, side-major: ``[0, size)`` the
+    first endpoints of the ranks' edges, ``[size, 2 * size)`` the second
+    (rank r is edge ``order[r]``, or r with no ``order``). An entry that
+    touches no vertex, and the bucket's padding, holds
+    :data:`NO_VERTEX`. One flat array: a ``[size, 2]`` array would be
+    tiled (8, 128) on a TPU, 64 times its size."""
+    if valid is None:
+        valid = jnp.ones(src.shape, bool)
+    if order is not None:
+        src, dst, valid = src[order], dst[order], valid[order]
+    pad = (0, size - src.shape[0])
+    u = jnp.pad(jnp.where(valid, src, NO_VERTEX), pad, constant_values=NO_VERTEX)
+    v = jnp.where(valid & (src != dst), dst, NO_VERTEX)
+    return jnp.concatenate([u, jnp.pad(v, pad, constant_values=NO_VERTEX)])
+
+
+@jax.jit
+def _links_device(vert):
+    """Successor links of the conflict DAG over the ranks of ``vert``.
+
+    Rank r (link entries ``vert[r]`` and ``vert[k + r]`` of the bucket's
+    k = ``vert.size // 2`` ranks) conflicts with the previous and next
+    rank touching either of its vertices. One sort of the (vertex,
+    ``2 * rank + side``) pairs groups the entries by vertex in rank
+    order, so neighbours of one vertex are one link; a second sort, on
+    the entry index, brings each entry's link back to its place.
+    Returns (succ int32 [2k], waiting int32 [k + 1]): ``succ[s * k +
+    r]`` is the rank of the next edge at r's endpoint s, or the sentinel
+    k where there is none; ``waiting[r]`` is how many earlier edges r
+    directly waits on (0, 1 or 2). Ranks that touch no vertex (invalid
+    edges, the padding) wait on 2k + 1, as does the sentinel
+    ``waiting[k]``: more than it can ever be notified, so none of them
+    becomes ready.
     """
-    k = su.shape[0]
-    rb = k.bit_length()
-    if k:
-        # int32 vertex ids and k < 2**31 always fit: 31 + 31 + 1 bits
-        lo = int(min(su.min(), sv.min()))
-        hi = int(max(su.max(), sv.max()))
-        if lo < 0 or hi.bit_length() + rb + 1 > 63:
-            raise ValueError(
-                f"vertex ids [{lo}, {hi}] with {k} edges do not fit the "
-                "packed int64 conflict key (ids must be non-negative int32)"
-            )
-    # built, sorted and decoded in place: each fresh array of this size
-    # costs page faults on top of its pass
-    shift = rb + 1
-    other = np.flatnonzero(su != sv)
-    key = np.empty(k + other.size, np.int64)
-    np.left_shift(su, shift, out=key[:k])
-    key[:k] |= np.arange(0, 2 * k, 2)
-    np.left_shift(sv[other], shift, out=key[k:])
-    other <<= 1
-    other |= 1
-    key[k:] |= other
-    key.sort()
-    vert = key >> shift
-    same = vert[1:] == vert[:-1]
-    del vert
-    key &= (1 << shift) - 1
-    nxt = key[1:][same]
-    nxt >>= 1
-    succ = np.full(2 * k, k, np.int64)
-    succ[key[:-1][same]] = nxt
-    waiting = np.bincount(nxt, minlength=k + 1)
-    waiting[k] = 2 * k + 1
-    return succ.reshape(k, 2), waiting
+    entries = vert.shape[0]
+    k = entries // 2
+    index = lax.iota(jnp.int32, entries)
+    side = index >= k
+    by_vertex, key = lax.sort(
+        (vert, 2 * jnp.where(side, index - k, index) + side), num_keys=2
+    )
+    same = (by_vertex[1:] == by_vertex[:-1]) & (by_vertex[1:] != NO_VERTEX)
+    # per sorted entry: 2 * (rank of its successor) + (has a predecessor)
+    nxt = jnp.append(jnp.where(same, key[1:] >> 1, k), k)
+    link = 2 * nxt + jnp.concatenate([jnp.zeros(1, bool), same]).astype(jnp.int32)
+    _, link = lax.sort(((key & 1) * k + (key >> 1), link), num_keys=1)
+    never = 2 * k + 1
+    waiting = jnp.where(
+        vert[:k] == NO_VERTEX, never, (link[:k] & 1) + (link[k:] & 1)
+    )
+    return link >> 1, jnp.append(waiting, never)
+
+
+def _device_stream(src, dst, valid, order):
+    """The stream, and ``order`` where given, as device arrays. Device
+    arrays are taken as they are. Host arrays are checked (vertex ids
+    must be non-negative int32) and padded with invalid edges to a
+    bucket, so that host streams of one bucket share one
+    :func:`_link_vertices` program (padded ranks are the invalid
+    position ``m``), then put on the default device."""
+    if order is not None:
+        order = np.asarray(order, np.int32)
+    if isinstance(src, jax.Array):
+        return src, dst, valid, None if order is None else jnp.asarray(order)
+    src = np.asarray(src, np.int64)
+    dst = np.asarray(dst, np.int64)
+    m = src.shape[0]
+    valid = np.ones(m, bool) if valid is None else np.asarray(valid, bool)
+    ids = np.concatenate([src[valid], dst[valid]])
+    if ids.size and (ids.min() < 0 or ids.max() >= 2**31):
+        raise ValueError(
+            f"vertex ids [{ids.min()}, {ids.max()}] are not non-negative int32"
+        )
+    pad = link_bucket(2 * m + 2) // 2 - m
+    src, dst = (np.pad(a.astype(np.int32), (0, pad)) for a in (src, dst))
+    valid = np.pad(valid, (0, pad))
+    if order is not None:
+        k = order.shape[0]
+        order = np.pad(order, (0, link_bucket(2 * k) // 2 - k), constant_values=m)
+    return (
+        jnp.asarray(src), jnp.asarray(dst), jnp.asarray(valid),
+        None if order is None else jnp.asarray(order),
+    )
+
+
+def _conflict_links(src, dst, valid, order, telemetry):
+    """:func:`_links_device` over the ranks of ``order`` (stream
+    positions where it is None), on the device that holds the stream;
+    returns the links as host arrays (``waiting`` writable: the peel
+    counts it down)."""
+    ranks = src.shape[0] if order is None else order.shape[0]
+    size = link_bucket(2 * ranks) // 2
+    succ, waiting = _links_device(_link_vertices(src, dst, valid, order, size=size))
+    jax.block_until_ready((succ, waiting))
+    with telemetry.span("copy.d2h", what="links"):
+        succ.copy_to_host_async()
+        waiting.copy_to_host_async()
+        succ = np.asarray(succ).reshape(2, size)
+        waiting = np.array(waiting)
+    telemetry.counters.put("schedule.link_entries", 2 * size)
+    return succ, waiting
 
 
 def _peel_waves(succ: np.ndarray, waiting: np.ndarray):
@@ -197,19 +274,23 @@ def _peel_waves(succ: np.ndarray, waiting: np.ndarray):
     frontier of pass d IS wave d). Each edge enters the frontier once
     and notifies at most two successors, so total element work is O(m)
     spread over ``num_waves`` vectorized passes — no per-edge Python
-    loop. ``succ`` / ``waiting`` are :func:`_conflict_links`' output;
-    ``waiting`` is consumed.
+    loop. ``succ`` [2, k] / ``waiting`` [k + 1] are
+    :func:`_conflict_links`' output; ``waiting`` is consumed.
 
     Returns (ranks int64 [k], counts int64 [num_waves]): the frontiers
     concatenated — wave-major, ascending rank inside each wave, the
     order the pack needs — and each wave's size.
     """
     fronts = []
+    # a scalar of waiting's own dtype keeps np.subtract.at on its fast
+    # path: a Python int sends int32 counts down the generic loop, about
+    # 3x slower over a whole peel
+    one = waiting.dtype.type(1)
     frontier = np.flatnonzero(waiting == 0)
     while frontier.size:
         fronts.append(frontier)
-        nxt = succ.take(frontier, 0).ravel()
-        np.subtract.at(waiting, nxt, 1)
+        nxt = succ.take(frontier, 1).ravel()
+        np.subtract.at(waiting, nxt, one)
         frontier = nxt[waiting.take(nxt) == 0]
         if frontier.size > 1:
             # sorts the frontier; a rank occurs twice in ``nxt`` when
@@ -289,43 +370,61 @@ def wave_schedule(
     order while independent edges pack together. ``seg`` is the slot
     width of the packed layout (see :data:`SEG`).
 
-    ``telemetry`` records the two host phases as spans
+    ``src`` / ``dst`` / ``valid`` may be host arrays or device arrays.
+    The uncapped links are built on the device: device arrays stay
+    where they are (vertex ids must be non-negative), host arrays are
+    checked and put on the default device. The capped packer reads
+    host arrays.
+
+    ``telemetry`` records the two phases as spans
     (``wave_schedule.assign`` / ``wave_schedule.pack``; uncapped, the
-    assignment's children ``wave_schedule.links`` and
-    ``wave_schedule.peel``) plus the schedule geometry counters; the
-    deprecated ``schedule_seconds`` / ``pack_seconds`` fields are
-    populated from the *same* stopwatch measurements, so there is one
-    timing path either way. The casts on either side of the two phases
-    are the spans ``wave_schedule.prepare`` (the int64 stream, the valid
-    mask and the scheduled positions) and ``wave_schedule.emit`` (the
-    int32 arrays of the schedule); neither is part of a phase's seconds.
+    assignment's children ``wave_schedule.links``, which holds the
+    device program and its ``copy.d2h`` of the links, and
+    ``wave_schedule.peel``) plus the schedule geometry counters and
+    ``schedule.link_entries`` (the link entries the device sorted,
+    padding included); the deprecated ``schedule_seconds`` /
+    ``pack_seconds`` fields are populated from the *same* stopwatch
+    measurements, so there is one timing path either way. The casts on
+    either side of the two phases are the spans ``wave_schedule.prepare``
+    (host streams put on the device, or the capped packer's int64
+    stream, valid mask and scheduled positions) and
+    ``wave_schedule.emit`` (the int32 arrays of the schedule); neither
+    is part of a phase's seconds.
     """
     if max_width is not None and max_width < 1:
         raise ValueError(f"max_width must be >= 1, got {max_width}")
     if seg < 1:
         raise ValueError(f"seg must be >= 1, got {seg}")
+    m = len(src)
+    if len(dst) != m:
+        raise ValueError(f"src/dst length mismatch: {m} vs {len(dst)}")
     with telemetry.span("wave_schedule.prepare"):
-        src = np.asarray(src, dtype=np.int64)
-        dst = np.asarray(dst, dtype=np.int64)
-        m = src.shape[0]
-        if dst.shape[0] != m:
-            raise ValueError(f"src/dst length mismatch: {m} vs {dst.shape[0]}")
-        valid_np = (
-            np.ones(m, dtype=bool) if valid is None else np.asarray(valid, dtype=bool)
-        )
-        positions = np.arange(m) if order is None else np.asarray(order, dtype=np.int64)
-        positions = positions[valid_np[positions]]
+        if order is not None:
+            order = np.asarray(order, dtype=np.int64)
+        if max_width is None:
+            src, dst, valid, dev_order = _device_stream(src, dst, valid, order)
+        else:
+            src = np.asarray(src, dtype=np.int64)
+            dst = np.asarray(dst, dtype=np.int64)
+            valid_np = (
+                np.ones(m, dtype=bool) if valid is None
+                else np.asarray(valid, dtype=bool)
+            )
+            positions = np.arange(m) if order is None else order
+            positions = positions[valid_np[positions]]
 
     with obs.stopwatch(telemetry, "wave_schedule.assign") as sw_assign:
-        su = src[positions]
-        sv = dst[positions]
         if max_width is None:
+            # ranks are stream positions, or indices into ``order``
             with telemetry.span("wave_schedule.links"):
-                succ, waiting = _conflict_links(su, sv)
+                succ, waiting = _conflict_links(src, dst, valid, dev_order, telemetry)
             with telemetry.span("wave_schedule.peel"):
                 ranks, counts = _peel_waves(succ, waiting)
+            positions = order
         else:
-            wave_of_rank = _assign_earliest_fit(su, sv, max_width)
+            wave_of_rank = _assign_earliest_fit(
+                src[positions], dst[positions], max_width
+            )
             ranks = np.argsort(wave_of_rank, kind="stable")
             counts = np.bincount(wave_of_rank)
 
@@ -338,7 +437,7 @@ def wave_schedule(
         # each wave, and so are their stream positions unless an
         # explicit ``order`` permuted them — then one sort of the packed
         # (wave, position) key restores position order inside each wave
-        order_out = positions[ranks]
+        order_out = ranks if positions is None else positions[ranks]
         if order is not None:
             pb = m.bit_length()
             order_out = np.sort((wave_ids << pb) | order_out) & ((1 << pb) - 1)

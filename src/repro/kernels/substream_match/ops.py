@@ -929,10 +929,14 @@ def _schedule_for(stream, waves, max_width, telemetry, rec):
     """Resolve the wave schedule, recording its stage times."""
     from repro.graph import waves as _waves
 
-    with rec.span("copy.d2h", what="stream"):
-        src = np.asarray(stream.src)
-        dst = np.asarray(stream.dst)
-        valid = np.asarray(stream.valid)
+    if waves is None and max_width is None:
+        # the uncapped links are built on the device that holds the stream
+        src, dst, valid = stream.src, stream.dst, stream.valid
+    else:
+        with rec.span("copy.d2h", what="stream"):
+            src = np.asarray(stream.src)
+            dst = np.asarray(stream.dst)
+            valid = np.asarray(stream.valid)
     if waves is None:
         # built in-call: the schedule's own stopwatch measurements are
         # the stage split (assign -> "schedule", layout -> "pack")

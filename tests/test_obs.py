@@ -176,9 +176,11 @@ def test_span_args_carry_call_and_parent():
     ]
     d2h = {(e["args"]["what"], e["args"]["parent"]) for e in _spans(tel, "copy.d2h")}
     assert {
-        ("stream", None), ("stream", "pallas_mega.layout"),
+        ("links", "wave_schedule.links"), ("stream", "pallas_mega.layout"),
         ("assigned_slots", "pallas_mega.layout"),
     } <= d2h
+    # the uncapped schedule reads the stream on the device
+    assert ("stream", None) not in d2h
     for e in _spans(tel, "copy.h2d"):
         assert e["args"]["parent"] in (
             "pallas_mega.compile", "pallas_mega.execute", None,

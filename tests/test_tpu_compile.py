@@ -162,3 +162,18 @@ def test_mega_device_memory_does_not_grow_with_padding(one_chip):
     assert large.temp_size_in_bytes - small.temp_size_in_bytes < 4 * slots
     # u, v, w in: 12 B a slot
     assert large.argument_size_in_bytes - small.argument_size_in_bytes == 12 * slots
+
+
+def test_links_program_fits_at_paper_scale(one_chip):
+    """The wave schedule's conflict links at the bucket of the paper's
+    scale-20 Kronecker graph (m = 44,350,400; 92 M link entries): two
+    sorts over flat int32 arrays, whose arguments and scratch stay under
+    half of the chip's 16 GB beside the stream it already holds."""
+    from repro.graph import waves
+
+    entries = waves.link_bucket(2 * 44_350_400)
+    vert = _spec((entries,), jnp.int32, one_chip)
+    stats = waves._links_device.lower(vert).compile().memory_analysis()
+    assert stats.argument_size_in_bytes + stats.temp_size_in_bytes < 8e9
+    # succ and waiting come back flat: 12 B a rank, no (8, 128) tiling
+    assert stats.output_size_in_bytes < 12.1 * (entries // 2)

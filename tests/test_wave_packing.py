@@ -349,8 +349,9 @@ def test_uncapped_schedule_equals_reference_pack(name):
 
 def test_assign_spans_links_and_peel(monkeypatch):
     """Uncapped, ``wave_schedule.assign`` holds the two child spans
-    ``links`` and ``peel``; the capped packer has neither, and with
-    telemetry off nothing is recorded or annotated."""
+    ``links`` and ``peel``, and ``links`` holds the copy of the links
+    to the host; the capped packer has none of them, and with telemetry
+    off nothing is recorded or annotated."""
     opened = []
 
     def annotation(name):
@@ -368,11 +369,14 @@ def test_assign_spans_links_and_peel(monkeypatch):
     spans = [e for e in tel.tracer.events if e.get("ph") == "X"]
     by_name = {e["name"]: e for e in spans}
     assert [e["name"] for e in spans] == [
-        "wave_schedule.prepare", "wave_schedule.links", "wave_schedule.peel",
-        "wave_schedule.assign", "wave_schedule.pack", "wave_schedule.emit",
+        "wave_schedule.prepare", "copy.d2h", "wave_schedule.links",
+        "wave_schedule.peel", "wave_schedule.assign", "wave_schedule.pack",
+        "wave_schedule.emit",
     ]
     for child in ("wave_schedule.links", "wave_schedule.peel"):
         assert by_name[child]["args"]["parent"] == "wave_schedule.assign"
+    assert by_name["copy.d2h"]["args"]["parent"] == "wave_schedule.links"
+    assert by_name["copy.d2h"]["args"]["what"] == "links"
     assert by_name["wave_schedule.assign"]["args"]["parent"] is None
     assert {"wave_schedule.links", "wave_schedule.peel"} <= set(opened)
 
