@@ -1,12 +1,9 @@
 """Part-1 throughput: edges/sec per engine, the repo's perf trajectory.
 
-Compares the six Part-1 engines on Kronecker workloads:
+Compares the five Part-1 engines on Kronecker workloads:
 
 * ``scan``         — the CS-SEQ `lax.scan` oracle (1 edge / step);
 * ``pallas_edges`` — the paper-literal Pallas pipeline (1 edge / iter);
-* ``pallas_waves`` — the segment-vectorized Pallas pipeline (fill-packed
-  slot layout, one [SEG, width] row-addressed tile per trip;
-  `schedule="waves"`);
 * ``pallas_mega``  — the grid-pipelined segment megakernel
   (`schedule="mega"`: scalar-prefetched block-aligned layout,
   ``seg_block`` segments per tile op, double-buffered tile stream);
@@ -15,7 +12,7 @@ Compares the six Part-1 engines on Kronecker workloads:
 
 Besides the CSV rows every benchmark emits, this one writes
 ``BENCH_substream.json`` at the repo root — the measured perf record the
-acceptance gate reads (wave vs per-edge speedup, mega vs the XLA oracle,
+acceptance gate reads (mega vs per-edge speedup, mega vs the XLA oracle,
 fill, #waves/#segments, scheduler/pack seconds per graph). Every engine
 row additionally carries its telemetry block (``stage_seconds`` —
 schedule/pack/layout/compile/execute — and the plan/schedule
@@ -72,14 +69,13 @@ from repro.kernels.substream_match.ops import (
     mega_plan,
     substream_match,
     traffic_bytes,
-    wave_plan,
 )
 from repro.testing import faultline
 
 BENCH_PATH = pathlib.Path(__file__).resolve().parent.parent / "BENCH_substream.json"
 
 #: Acceptance gates (checked by --check, e.g. from CI on the scale-10
-#: graph): wave Pallas must beat per-edge Pallas by this factor in
+#: graph): the megakernel must beat per-edge Pallas by this factor in
 #: edges/sec, the packed schedule must keep at least this fill, and the
 #: megakernel must match or beat the plain-XLA wave oracle (the raised
 #: gate of ISSUE 6 — a Pallas pipeline slower than naive XLA is a bug).
@@ -129,21 +125,14 @@ def _round_up(x: int, mult: int) -> int:
 
 
 def _expected_counters(schedule, cfg, L: int) -> dict:
-    """Recompute the plan accounting the wave/mega telemetry counters
-    must reproduce bit-exactly — embedded in the report so
+    """Recompute the plan accounting the mega telemetry counters must
+    reproduce bit-exactly — embedded in the report so
     :func:`check_report` can cross-check the emitted counters without
     re-running anything."""
-    wplan = wave_plan(cfg.n, L, schedule)
     layout = block_aligned_layout(schedule, MEGA_SEG_BLOCK)
     mplan = mega_plan(cfg.n, L, layout)
-    ns_pad = _round_up(max(schedule.num_segments, 1), wplan.block_s)
     mega_tiles_pad = _round_up(max(layout.num_tiles, 1), mplan.tiles_per_block)
     return {
-        "pallas_waves": {
-            "plan.gather_bytes": int(wplan.gather_bytes),
-            "plan.bit_block_bytes": int(wplan.nbytes),
-            "traffic.hbm_bytes": traffic_bytes(ns_pad * wplan.seg, wplan.nbytes),
-        },
         "pallas_mega": {
             "plan.gather_bytes": int(mplan.gather_bytes),
             "plan.bit_block_bytes": int(mplan.nbytes),
@@ -334,10 +323,6 @@ def _bench_graph(
             stream, cfg, schedule="edges", telemetry=tel,
             on_plan_failure="fallback",
         ),
-        "pallas_waves": lambda tel=obs.DISABLED: substream_match(
-            stream, cfg, schedule="waves", waves=schedule, telemetry=tel,
-            on_plan_failure="fallback",
-        ),
         "pallas_mega": lambda tel=obs.DISABLED: substream_match(
             stream, cfg, schedule="mega", waves=schedule, telemetry=tel,
             on_plan_failure="fallback",
@@ -391,7 +376,7 @@ def _bench_graph(
             "counters": {k: steady.counters[k] for k in sorted(steady.counters)},
         }
     speedup = (
-        timings["pallas_waves"]["edges_per_sec"]
+        timings["pallas_mega"]["edges_per_sec"]
         / timings["pallas_edges"]["edges_per_sec"]
     )
     mega_vs_xla = (
@@ -416,7 +401,7 @@ def _bench_graph(
         "expected_counters": _expected_counters(schedule, cfg, L),
         "recovery": _bench_recovery(stream, cfg, schedule, reps),
         "engines": timings,
-        "speedup_pallas_waves_vs_edges": round(speedup, 2),
+        "speedup_pallas_mega_vs_edges": round(speedup, 2),
         "speedup_mega_vs_xla": round(mega_vs_xla, 2),
     }
 
@@ -443,7 +428,7 @@ def run_report(scales=DEFAULT_SCALES, edge_factor=EDGE_FACTOR, L=L, eps=EPS,
     if telemetry is None:
         telemetry = obs.Telemetry()
     graphs = [_bench_graph(s, edge_factor, L, eps, reps, telemetry) for s in scales]
-    min_speedup = min(g["speedup_pallas_waves_vs_edges"] for g in graphs)
+    min_speedup = min(g["speedup_pallas_mega_vs_edges"] for g in graphs)
     min_fill = min(g["wave_fill"] for g in graphs)
     min_mega = min(g["speedup_mega_vs_xla"] for g in graphs)
     max_overhead = max(g["recovery"]["snapshot_overhead_pct"] for g in graphs)
@@ -461,7 +446,7 @@ def run_report(scales=DEFAULT_SCALES, edge_factor=EDGE_FACTOR, L=L, eps=EPS,
         },
         "graphs": graphs,
         "acceptance": {
-            "target_speedup_pallas_waves_vs_edges": TARGET_SPEEDUP,
+            "target_speedup_pallas_mega_vs_edges": TARGET_SPEEDUP,
             "measured_min_speedup": min_speedup,
             "target_wave_fill": TARGET_FILL,
             "measured_min_wave_fill": min_fill,
@@ -502,7 +487,7 @@ def run_report(scales=DEFAULT_SCALES, edge_factor=EDGE_FACTOR, L=L, eps=EPS,
                 (g["schedule_seconds"] + g["pack_seconds"]) * 1e6,
                 f"{g['num_waves']} waves {g['num_segments']} segs "
                 f"fill={g['wave_fill']:.2f} "
-                f"speedup={g['speedup_pallas_waves_vs_edges']:.1f}x "
+                f"speedup={g['speedup_pallas_mega_vs_edges']:.1f}x "
                 f"mega_vs_xla={g['speedup_mega_vs_xla']:.2f}x",
             )
         )
@@ -519,7 +504,7 @@ def check_report(report: dict) -> tuple[bool, list[str]]:
     that stops emitting a gate input can never silently disable it.
     Gates, each enforced on EVERY benched graph:
 
-    * ``pallas_waves`` >= ``TARGET_SPEEDUP`` x ``pallas_edges``;
+    * ``pallas_mega`` >= ``TARGET_SPEEDUP`` x ``pallas_edges``;
     * wave fill >= ``TARGET_FILL``;
     * ``pallas_mega`` >= ``TARGET_MEGA_VS_XLA`` x ``waves_xla`` (the
       raised ISSUE-6 gate: the megakernel must beat the XLA oracle);
@@ -528,7 +513,7 @@ def check_report(report: dict) -> tuple[bool, list[str]]:
       summing within ``telemetry_wall_seconds``; a non-empty
       ``counters`` dict) — a refactor that drops the instrumentation
       fails here instead of silently un-observing the bench;
-    * the wave/mega counters reproduce the plan accounting embedded in
+    * the mega counters reproduce the plan accounting embedded in
       ``expected_counters`` **bit-exactly** (gather bytes, bit-block
       bytes, modeled HBM traffic);
     * the clean-path guard: every graph embeds a ``validation`` block
@@ -552,8 +537,8 @@ def check_report(report: dict) -> tuple[bool, list[str]]:
         return False, ["FAIL report has no graphs (nothing was benched)"]
     ok = True
     gates = (
-        ("speedup_pallas_waves_vs_edges", TARGET_SPEEDUP,
-         "pallas_waves vs pallas_edges speedup"),
+        ("speedup_pallas_mega_vs_edges", TARGET_SPEEDUP,
+         "pallas_mega vs pallas_edges speedup"),
         ("wave_fill", TARGET_FILL, "wave fill"),
         ("speedup_mega_vs_xla", TARGET_MEGA_VS_XLA,
          "pallas_mega vs waves_xla speedup"),
@@ -600,7 +585,7 @@ def check_report(report: dict) -> tuple[bool, list[str]]:
         + ("" if verdict else ": " + "; ".join(problems))
     )
 
-    # plan-counter accounting: the emitted wave/mega counters must equal
+    # plan-counter accounting: the emitted mega counters must equal
     # the independently recomputed plan accounting bit-exactly
     mismatches: list[str] = []
     for g in graphs:
@@ -717,7 +702,7 @@ def main() -> None:
         "--check",
         action="store_true",
         help="exit non-zero unless on every benched graph wave_fill >= "
-        "%.2f, wave-vs-edge speedup >= %.1f, mega >= %.1fx waves_xla, "
+        "%.2f, mega-vs-edge speedup >= %.1f, mega >= %.1fx waves_xla, "
         "every engine row carries consistent telemetry, the input "
         "validated clean, no Pallas engine fell back, and the recovery "
         "block shows snapshot overhead <= %.1f%%, a bit-exact resume, "

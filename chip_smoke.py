@@ -8,7 +8,8 @@ the chip, each to ``block_until_ready``:
 
 * ``mwm_pipeline(part1="pallas")`` — the per-edge kernel in blocked
   order, then the Part 2 merge;
-* ``substream_match(schedule="waves")`` and ``schedule="mega"``;
+* ``substream_match(schedule="mega")`` at ``seg_block=1`` (one segment
+  per tile) and at the default ``seg_block``;
 * ``match_epochs(engine="mega", epochs=4)``.
 
 Every Part 1 result (``assigned`` and ``mb``) must be bit-identical to
@@ -167,18 +168,19 @@ def run_one_chip(scale: int = SMOKE_SCALE, expect_compiled: bool = True):
     _check_telemetry("edges", tel, expect_compiled)
 
     want_idx = merge_host(stream, ref, cfg)
-    for schedule in ("waves", "mega"):
+    for name, seg_block in (("mega[seg_block=1]", 1), ("mega", None)):
         tel = obs.Telemetry()
         run = lambda: substream_match(  # noqa: E731
-            stream, cfg, schedule=schedule, telemetry=tel, **strict
+            stream, cfg, schedule="mega", seg_block=seg_block, telemetry=tel,
+            **strict,
         )
         _, first = _timed(run)
         got, steady = _timed(run)
-        _report(schedule, first, steady, tel, 1)
-        _same(schedule, got, ref)
+        _report(name, first, steady, tel, 1)
+        _same(name, got, ref)
         idx = merge_host(stream, got, cfg)
         check_matching(got, stream, cfg, merged=idx)
-        _check_telemetry(schedule, tel, expect_compiled)
+        _check_telemetry(name, tel, expect_compiled)
 
     tel = obs.Telemetry()
     run = lambda: match_epochs(  # noqa: E731

@@ -147,7 +147,6 @@ def mwm_waves(
     stream: EdgeStream,
     cfg: SubstreamConfig,
     schedule=None,
-    max_width: int | None = None,
     telemetry=obs.DISABLED,
     mb0: jax.Array | None = None,
 ) -> MatchingResult:
@@ -184,16 +183,14 @@ def mwm_waves(
     valid = np.asarray(stream.valid)
     if schedule is None:
         schedule = _waves.resolve_schedule(
-            src, dst, valid, schedule=None, max_width=max_width,
-            telemetry=telemetry,
+            src, dst, valid, schedule=None, telemetry=telemetry
         )
         rec.add_stage("schedule", schedule.schedule_seconds)
         rec.add_stage("pack", schedule.pack_seconds)
     else:
         with rec.stage("schedule"):  # precomputed: validation cost only
             schedule = _waves.resolve_schedule(
-                src, dst, valid, schedule=schedule, max_width=max_width,
-                telemetry=telemetry,
+                src, dst, valid, schedule=schedule, telemetry=telemetry
             )
     with rec.stage("layout"):
         u, v, w, ok = _waves.slot_arrays(

@@ -9,29 +9,20 @@ yields bit-identical matching bits and recorded lists. So the stream can
 be cut into **waves** such that every wave is vertex-disjoint while
 conflicting edges keep their stream order across waves.
 
-Scheduling (the tentpole of this module) is earliest-fit packing:
-every edge goes into the earliest wave that is
-
-* at or past its **conflict depth** — one past the wave of every earlier
-  edge sharing an endpoint, tracked with per-vertex next-free-wave
-  pointers, and
-* not **full** — when ``max_width`` caps wave occupancy, full waves are
-  skipped via an interval-union skip list, so scheduling stays near-O(m).
-
-With no occupancy cap (the default) earliest-fit collapses to the pure
-conflict-depth assignment, which is *provably minimal*: the wave count
-equals the longest conflict chain (≥ the maximum vertex multiplicity —
-every edge at the hub vertex needs its own wave), so no valid
-vertex-disjoint decomposition can use fewer waves. The uncapped pass
-has two steps. The conflict links (each edge's next edge at either
-endpoint) are built on the device that holds the stream, by two sorts
-of its endpoints (:func:`_links_device`); only the links come back to
-the host. A vectorized numpy indegree peel of that 2-predecessor DAG
-then resolves one wave per pass and keeps each pass's frontier, so it
-hands the pack the wave-major order itself: the pack needs no sort (one
-sort of packed (wave, position) keys when an explicit ``order``
-permuted the stream). The capped path keeps the sequential earliest-fit
-packer on the host.
+Scheduling (the tentpole of this module) puts every edge into the wave
+of its **conflict depth**: one past the wave of every earlier edge
+sharing an endpoint. That assignment is *provably minimal*: the wave
+count equals the longest conflict chain (≥ the maximum vertex
+multiplicity — every edge at the hub vertex needs its own wave), so no
+valid vertex-disjoint decomposition can use fewer waves. It has two
+steps. The conflict links (each edge's next edge at either endpoint)
+are built on the device that holds the stream, by two sorts of its
+endpoints (:func:`_links_device`); only the links come back to the
+host. A vectorized numpy indegree peel of that 2-predecessor DAG then
+resolves one wave per pass and keeps each pass's frontier, so it hands
+the pack the wave-major order itself: the pack needs no sort (one sort
+of packed (wave, position) keys when an explicit ``order`` permuted the
+stream).
 
 Layout (where the "fill-packed" in the title lives): waves are *not*
 padded to one global maximum width. They are packed back-to-back into
@@ -41,7 +32,7 @@ fixed-size **segments** of ``SEG`` slots (a wave of size s occupies
 slots holding a real edge — stays high regardless of wave-size skew.
 Each segment is a *subset* of one wave and therefore vertex-disjoint
 itself: every consumer that processed "one slots-row at a time"
-(the XLA wave scan, the Pallas segment kernel, rounds-with-waves) keeps
+(the XLA wave scan, the Pallas megakernel, rounds-with-waves) keeps
 its row-major contract unchanged, with per-row traffic proportional to
 ``SEG`` instead of the largest wave.
 
@@ -266,7 +257,7 @@ def _conflict_links(src, dst, valid, order, telemetry):
 
 
 def _peel_waves(succ: np.ndarray, waiting: np.ndarray):
-    """Waves of the uncapped schedule, by indegree peel of the conflict DAG.
+    """Waves of the schedule, by indegree peel of the conflict DAG.
 
     Pass d resolves exactly the edges of conflict depth d (an edge is
     ready once every earlier edge sharing an endpoint has a depth, and
@@ -302,55 +293,11 @@ def _peel_waves(succ: np.ndarray, waiting: np.ndarray):
     return np.concatenate(fronts), counts
 
 
-def _assign_earliest_fit(
-    su: np.ndarray, sv: np.ndarray, max_width: int
-) -> np.ndarray:
-    """Sequential earliest-fit packer with per-wave occupancy ``max_width``.
-
-    Every edge lands in the earliest wave at or past its conflict depth
-    (per-vertex next-free-wave pointers in ``avail``) that still has a
-    free slot. Full waves never reopen, so they are skipped with an
-    interval union (path-halving) — amortized near-O(1) per edge, where
-    a linear "first open wave" rescan would be quadratic on streams of
-    mostly-independent edges that all target the lowest waves.
-    """
-    k = su.shape[0]
-    n_hint = int(max(su.max(), sv.max())) + 1 if k else 1
-    avail = np.zeros(n_hint, dtype=np.int64)  # next free wave per vertex
-    counts: list[int] = []  # occupancy per wave
-    parent: list[int] = []  # skip pointers over full waves
-    wave = np.empty(k, dtype=np.int64)
-
-    def _find_open(w: int) -> int:
-        while w < len(counts) and parent[w] != w:
-            nxt = parent[w]
-            if nxt < len(counts) and parent[nxt] != nxt:
-                parent[w] = parent[nxt]
-            w = nxt
-        return w
-
-    for r in range(k):
-        u = su[r]
-        v = sv[r]
-        w = _find_open(int(max(avail[u], avail[v])))
-        if w == len(counts):
-            counts.append(0)
-            parent.append(w)
-        counts[w] += 1
-        if counts[w] >= max_width:
-            parent[w] = w + 1
-        wave[r] = w
-        avail[u] = w + 1
-        avail[v] = w + 1
-    return wave
-
-
 def wave_schedule(
     src,
     dst,
     valid=None,
     order=None,
-    max_width: int | None = None,
     seg: int = SEG,
     telemetry=obs.DISABLED,
 ) -> WaveSchedule:
@@ -362,22 +309,18 @@ def wave_schedule(
     schedule still indexes original stream positions. ``valid`` masks
     padding edges, which are left unscheduled (``wave == -1``).
 
-    ``max_width`` (default None = uncapped) bounds per-wave occupancy
-    via the sequential earliest-fit packer; uncapped scheduling is the
-    vectorized conflict-depth assignment, which is wave-count minimal.
-    Either way every edge is placed at or past its conflict depth, so
-    any two edges sharing a vertex land in distinct waves in processing
-    order while independent edges pack together. ``seg`` is the slot
-    width of the packed layout (see :data:`SEG`).
+    Every edge is placed at its conflict depth (the wave-count minimal
+    assignment), so any two edges sharing a vertex land in distinct
+    waves in processing order while independent edges pack together.
+    ``seg`` is the slot width of the packed layout (see :data:`SEG`).
 
     ``src`` / ``dst`` / ``valid`` may be host arrays or device arrays.
-    The uncapped links are built on the device: device arrays stay
-    where they are (vertex ids must be non-negative), host arrays are
-    checked and put on the default device. The capped packer reads
-    host arrays.
+    The links are built on the device: device arrays stay where they
+    are (vertex ids must be non-negative), host arrays are checked and
+    put on the default device.
 
     ``telemetry`` records the two phases as spans
-    (``wave_schedule.assign`` / ``wave_schedule.pack``; uncapped, the
+    (``wave_schedule.assign`` / ``wave_schedule.pack``; the
     assignment's children ``wave_schedule.links``, which holds the
     device program and its ``copy.d2h`` of the links, and
     ``wave_schedule.peel``) plus the schedule geometry counters and
@@ -386,13 +329,9 @@ def wave_schedule(
     ``pack_seconds`` fields are populated from the *same* stopwatch
     measurements, so there is one timing path either way. The casts on
     either side of the two phases are the spans ``wave_schedule.prepare``
-    (host streams put on the device, or the capped packer's int64
-    stream, valid mask and scheduled positions) and
-    ``wave_schedule.emit`` (the int32 arrays of the schedule); neither
-    is part of a phase's seconds.
+    (host streams put on the device) and ``wave_schedule.emit`` (the
+    int32 arrays of the schedule); neither is part of a phase's seconds.
     """
-    if max_width is not None and max_width < 1:
-        raise ValueError(f"max_width must be >= 1, got {max_width}")
     if seg < 1:
         raise ValueError(f"seg must be >= 1, got {seg}")
     m = len(src)
@@ -401,32 +340,14 @@ def wave_schedule(
     with telemetry.span("wave_schedule.prepare"):
         if order is not None:
             order = np.asarray(order, dtype=np.int64)
-        if max_width is None:
-            src, dst, valid, dev_order = _device_stream(src, dst, valid, order)
-        else:
-            src = np.asarray(src, dtype=np.int64)
-            dst = np.asarray(dst, dtype=np.int64)
-            valid_np = (
-                np.ones(m, dtype=bool) if valid is None
-                else np.asarray(valid, dtype=bool)
-            )
-            positions = np.arange(m) if order is None else order
-            positions = positions[valid_np[positions]]
+        src, dst, valid, dev_order = _device_stream(src, dst, valid, order)
 
     with obs.stopwatch(telemetry, "wave_schedule.assign") as sw_assign:
-        if max_width is None:
-            # ranks are stream positions, or indices into ``order``
-            with telemetry.span("wave_schedule.links"):
-                succ, waiting = _conflict_links(src, dst, valid, dev_order, telemetry)
-            with telemetry.span("wave_schedule.peel"):
-                ranks, counts = _peel_waves(succ, waiting)
-            positions = order
-        else:
-            wave_of_rank = _assign_earliest_fit(
-                src[positions], dst[positions], max_width
-            )
-            ranks = np.argsort(wave_of_rank, kind="stable")
-            counts = np.bincount(wave_of_rank)
+        # ranks are stream positions, or indices into ``order``
+        with telemetry.span("wave_schedule.links"):
+            succ, waiting = _conflict_links(src, dst, valid, dev_order, telemetry)
+        with telemetry.span("wave_schedule.peel"):
+            ranks, counts = _peel_waves(succ, waiting)
 
     with obs.stopwatch(telemetry, "wave_schedule.pack") as sw_pack:
         num_waves = counts.shape[0]
@@ -437,10 +358,10 @@ def wave_schedule(
         # each wave, and so are their stream positions unless an
         # explicit ``order`` permuted them — then one sort of the packed
         # (wave, position) key restores position order inside each wave
-        order_out = ranks if positions is None else positions[ranks]
+        order_out = ranks
         if order is not None:
             pb = m.bit_length()
-            order_out = np.sort((wave_ids << pb) | order_out) & ((1 << pb) - 1)
+            order_out = np.sort((wave_ids << pb) | order[ranks]) & ((1 << pb) - 1)
         wave = np.full(m, -1, dtype=np.int64)
         wave[order_out] = wave_ids
 
@@ -588,20 +509,17 @@ def resolve_schedule(
     dst,
     valid,
     schedule: WaveSchedule | None = None,
-    max_width: int | None = None,
     telemetry=obs.DISABLED,
 ) -> WaveSchedule:
     """Build a schedule for the stream, or validate a precomputed one.
 
-    The single entry every wave consumer (`mwm_waves`, the Pallas wave
+    The single entry every wave consumer (`mwm_waves`, the Pallas mega
     path, rounds-with-waves) goes through, so the validation rules stay
     in one place. ``telemetry`` records the build (or validation) cost
     as ``wave_schedule.*`` spans.
     """
     if schedule is None:
-        return wave_schedule(
-            src, dst, valid=valid, max_width=max_width, telemetry=telemetry
-        )
+        return wave_schedule(src, dst, valid=valid, telemetry=telemetry)
     with telemetry.span("wave_schedule.validate"):
         validate_schedule(schedule, src, dst, valid)
     return schedule
@@ -750,9 +668,7 @@ def slot_arrays(schedule: WaveSchedule, src, dst, weight, valid=None):
     Returns numpy ``(u, v, w, ok)``, each shaped [num_segments, SEG].
     Padding slots get ``u == v == 0`` and ``w == 0`` — below every
     substream threshold and a self-loop besides, so they can never match
-    (the XLA wave engine relies on this encoding; the Pallas path remaps
-    ``~ok`` slots to a sacrificial bit-block row before its in-place
-    row scatter, see ops._waves_device).
+    (the XLA wave engine relies on this encoding).
     """
     src = np.asarray(src)
     dst = np.asarray(dst)
@@ -774,7 +690,7 @@ def greedy_depths(src, dst, valid=None, order=None) -> np.ndarray:
     ``depth[e] = 1 + max(depth of previous edge at u, at v)`` walked in
     processing order — the per-edge loop the vectorized scheduler
     replaced, kept as the test oracle for the "every edge is placed at
-    or past its conflict depth" invariant. Returns int64 [m], -1 for
+    its conflict depth" invariant. Returns int64 [m], -1 for
     unscheduled (invalid) edges.
     """
     src = np.asarray(src, dtype=np.int64)
@@ -803,8 +719,7 @@ def check_schedule(schedule: WaveSchedule, src, dst, valid=None, order=None) -> 
     * conflicting edges appear in processing order across waves
       (``order`` is the explicit permutation the schedule was built
       with, if any — stream order otherwise);
-    * every edge sits at or past its conflict depth (equal when the
-      schedule is uncapped);
+    * every edge sits exactly at its conflict depth;
     * ``order``/``offsets``/``seg_offsets``/``slots`` describe the same
       fill-packed decomposition: wave k's members fill its segment rows
       back-to-back with padding only at the tail of its last row.
@@ -831,11 +746,9 @@ def check_schedule(schedule: WaveSchedule, src, dst, valid=None, order=None) -> 
         assert rows.shape[0] == -(-len(members) // seg), f"wave {k} segment count"
         assert (flat[: len(members)] == members).all(), f"wave {k} slot layout"
         assert (flat[len(members) :] == -1).all(), f"wave {k} slot padding"
-    # depth floor: earliest-fit never places an edge before its conflict
-    # depth (uncapped scheduling places it exactly there)
     depths = greedy_depths(src, dst, valid=valid, order=order)
     scheduled = wave >= 0
-    assert (wave[scheduled] >= depths[scheduled]).all(), "edge above its depth"
+    assert (wave[scheduled] == depths[scheduled]).all(), "edge off its depth"
     # order preservation among conflicting edges (in processing order)
     positions = (
         np.nonzero(scheduled)[0]

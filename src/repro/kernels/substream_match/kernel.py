@@ -15,15 +15,15 @@ TPU mapping of the FPGA design:
                                          slot blocks (double-buffered by
                                          the Pallas grid pipeline)
 
-One kernel body serves all three engines. A *slot* is ``(u, v, cnt)``:
+One kernel body serves both engines. A *slot* is ``(u, v, cnt)``:
 the two endpoints and the number of substream thresholds the weight
 passes. Thresholds ``(1+eps)^i`` are sorted, so the Stage-4 eligibility
 word of an edge is the prefix of its lowest ``cnt`` substreams; the
 caller computes ``cnt`` (0 for self-loops, padding and invalid edges).
 Slots come in *groups* whose members are vertex-disjoint: group size 1
-is the paper's per-edge processor (``edges``), a wave segment of
-``seg`` slots is the wave engine (``waves``), and a block-aligned tile
-of ``seg_block * seg`` slots is the megakernel (``mega``). Disjointness
+is the paper's per-edge processor (``edges``), and a block-aligned
+tile of ``seg_block * seg`` slots, a subset of one wave, is the
+megakernel (``mega``). Disjointness
 lets a group load all its rows before it stores any: no slot of the
 group can see another's update, so the result is the sequential one.
 

@@ -123,21 +123,22 @@ class VmemPlan:
         return self.nbytes + VMEM_HEADROOM
 
 
-def _slot_block(group: int, groups: int | None, name: str, knob: str) -> int:
-    """Groups per grid program: the most that fit ``MAX_BLOCK`` slots,
-    rounded to whole ``SMEM_ALIGN`` blocks where possible, never more
-    than the ``groups`` the stream has. Errors name the knob to change."""
+def _slot_block(group: int, groups: int) -> int:
+    """Tiles of ``group`` slots per grid program: the most that fit
+    ``MAX_BLOCK`` slots, rounded to whole ``SMEM_ALIGN`` blocks where
+    possible, never more than the ``groups`` the stream has."""
     if group * SLOT_SMEM_BYTES > SMEM_PER_CORE:
         raise ValueError(
-            f"{name} of {group} slots needs {group * SLOT_SMEM_BYTES} B of "
+            f"a tile of {group} slots needs {group * SLOT_SMEM_BYTES} B of "
             f"slot buffers, more than the {SMEM_PER_CORE} B of SMEM; "
-            f"rebuild with a smaller {knob}"
+            f"rebuild with a smaller seg_block "
+            f"(repro.graph.waves.block_aligned_layout)"
         )
     per = max(1, MAX_BLOCK // group)
     align = SMEM_ALIGN // math.gcd(group, SMEM_ALIGN)
     if per >= align:
         per -= per % align
-    return max(1, min(per, max(groups or 1, 1)))
+    return max(1, min(per, max(groups, 1)))
 
 
 def vmem_plan(
@@ -226,8 +227,8 @@ def traffic_bytes(total_slots: int, block_bytes: int, carried: bool = False) -> 
 def plan_counters(plan: VmemPlan) -> dict:
     """The plan-accounting counter set (``plan.*``) for telemetry.
 
-    Bit-exact copies of the :func:`vmem_plan` / :func:`wave_plan` /
-    :func:`mega_plan` fields — tests and the bench gate compare these
+    Bit-exact copies of the :func:`vmem_plan` / :func:`mega_plan`
+    fields — tests and the bench gate compare these
     ``==`` against a recomputed plan, so no derived or rounded values.
     """
     out = {
@@ -258,15 +259,19 @@ def plan_counters(plan: VmemPlan) -> dict:
 
 @dataclasses.dataclass(frozen=True)
 class WavePlan(VmemPlan):
-    """VmemPlan plus the segment-pipeline geometry.
+    """VmemPlan plus the megakernel's slot-pipeline geometry.
 
     ``seg`` is the fixed slot count per segment (the schedule's
-    fill-packed row width), ``num_waves``/``num_segments`` the
-    schedule's true wave count and packed row count, ``block_s`` how
-    many segments one grid program consumes (so ``block_e = block_s *
-    seg`` slots), ``gather_bytes`` the SMEM the grid pipeline allocates
-    for the double-buffered slot and assigned blocks, and ``fill`` the
-    schedule's slot fill (fraction of slots holding a real edge).
+    fill-packed row width), ``num_waves``/``num_segments`` the layout's
+    wave count and block-aligned row count, ``block_s`` how many
+    segments one grid program consumes (so ``block_e = block_s * seg``
+    slots), and ``fill`` the layout's slot fill (fraction of slots
+    holding a real edge). ``seg_block`` segments make one tile (one
+    kernel trip), ``num_tiles`` is the layout's tile count,
+    ``tiles_per_block`` the tiles per grid program, ``tile_bytes`` one
+    buffer of the slot pipeline and ``gather_bytes`` the SMEM the grid
+    pipeline allocates for it: ``2 * tile_bytes``, double-buffered so
+    the copy of block b+1 overlaps the trips of block b.
     """
 
     seg: int
@@ -275,64 +280,10 @@ class WavePlan(VmemPlan):
     block_s: int
     gather_bytes: int
     fill: float
-    #: Mega-path geometry (zero on plain wave plans): ``seg_block``
-    #: segments per tile, ``num_tiles`` real tiles in the block-aligned
-    #: layout, ``tiles_per_block`` tiles per grid program, and
-    #: ``tile_bytes`` one buffer of the slot pipeline — ``gather_bytes``
-    #: is ``2 * tile_bytes`` (double-buffered: the copy of block b+1
-    #: overlaps the trips of block b).
-    seg_block: int = 0
-    num_tiles: int = 0
-    tiles_per_block: int = 0
-    tile_bytes: int = 0
-
-
-def wave_plan(
-    n: int,
-    L: int,
-    schedule,
-    packed: bool = True,
-    block_s: int | None = None,
-) -> WavePlan:
-    """Plan the segment engine over ``schedule``.
-
-    The bit block is :func:`vmem_plan`'s. One trip handles one segment
-    of ``seg`` vertex-disjoint slots, so nothing per trip is allocated;
-    the grid pipeline double-buffers ``block_s`` segments of slots in
-    SMEM (``gather_bytes``). The auto ``block_s`` fills ``MAX_BLOCK``
-    slots, never exceeds the schedule's segment count, and keeps a
-    multi-program block a multiple of ``SMEM_ALIGN`` slots. Errors name
-    the knob that must change.
-    """
-    seg = int(schedule.width)
-    num_segments = int(schedule.num_segments)
-    base = vmem_plan(n, L, packed=packed, block_e=1)
-    auto = _slot_block(
-        seg, num_segments, "a segment", "seg (repro.graph.waves.wave_schedule(seg=...))"
-    )
-    block_s = auto if block_s is None else block_s
-    gather_bytes = block_s * seg * SLOT_SMEM_BYTES
-    if gather_bytes > SMEM_PER_CORE:
-        raise ValueError(
-            f"slot-stream blocks ({gather_bytes} B at block_s={block_s}, "
-            f"seg={seg}) exceed the {SMEM_PER_CORE} B of SMEM; lower "
-            f"block_s (ops.wave_plan) or seg "
-            f"(repro.graph.waves.wave_schedule(seg=...))"
-        )
-    return WavePlan(
-        n_pad=base.n_pad,
-        width=base.width,
-        words=base.words,
-        nbytes=base.nbytes,
-        block_e=block_s * seg,
-        packed=packed,
-        seg=seg,
-        num_waves=int(schedule.num_waves),
-        num_segments=num_segments,
-        block_s=block_s,
-        gather_bytes=gather_bytes,
-        fill=float(schedule.fill),
-    )
+    seg_block: int
+    num_tiles: int
+    tiles_per_block: int
+    tile_bytes: int
 
 
 #: Default segments per megakernel tile: two 8-slot segments make one
@@ -351,12 +302,14 @@ def mega_plan(
     """Plan the megakernel over ``layout`` (a
     :class:`repro.graph.waves.BlockAlignedLayout`).
 
-    Same accounting as :func:`wave_plan` with the tile (``seg_block *
-    seg`` slots) as the trip: ``tile_bytes`` is one buffer of the slot
-    pipeline's SMEM blocks and ``gather_bytes`` the double-buffered
-    pair. The auto ``tiles_per_block`` fills ``MAX_BLOCK`` slots,
-    clamped to the layout's tile count and ``SMEM_ALIGN``-aligned where
-    the stream spans several programs.
+    The bit block is :func:`vmem_plan`'s. One trip handles one tile
+    (``seg_block * seg`` vertex-disjoint slots), so nothing per trip is
+    allocated: ``tile_bytes`` is one buffer of the slot pipeline's SMEM
+    blocks and ``gather_bytes`` the double-buffered pair. The auto
+    ``tiles_per_block`` fills ``MAX_BLOCK`` slots, clamped to the
+    layout's tile count and ``SMEM_ALIGN``-aligned where the stream
+    spans several programs; the fallback ladder passes 1 for its
+    smallest slot working set. Errors name the knob that must change.
     """
     seg = int(layout.width)
     seg_block = int(layout.seg_block)
@@ -364,10 +317,7 @@ def mega_plan(
     num_tiles = int(layout.num_tiles)
     base = vmem_plan(n, L, packed=packed, block_e=1)
     if tiles_per_block is None:
-        tiles_per_block = _slot_block(
-            bslots, num_tiles, "a tile",
-            "seg_block (repro.graph.waves.block_aligned_layout)",
-        )
+        tiles_per_block = _slot_block(bslots, num_tiles)
     tile_bytes = tiles_per_block * bslots * SLOT_SMEM_BYTES // 2
     if 2 * tile_bytes > SMEM_PER_CORE:
         raise ValueError(
@@ -469,28 +419,22 @@ def _run_engine(
     interpret,
     packed,
     waves,
-    max_width,
     seg_block,
-    block_s,
     telemetry,
     mb0=None,
+    tiles_per_block=None,
 ) -> MatchingResult:
     """Dispatch one concrete engine of the cascade. The XLA fallbacks are
     looked up through the module at call time (not from-imported), so the
     fault injector can force them to fail too. ``mb0`` (caller storage:
     uint8 [n, words] packed / bool [n, L] dense) seeds the matching bits;
-    the XLA rungs take the dense view."""
+    the XLA rungs take the dense view. ``tiles_per_block`` is the
+    ladder's own megakernel knob (``None``: the plan's auto pick)."""
     if engine == "mega":
         return _substream_match_mega(
             stream, cfg, interpret=interpret, packed=packed, waves=waves,
-            max_width=max_width, seg_block=seg_block, telemetry=telemetry,
-            mb0=mb0,
-        )
-    if engine == "waves":
-        return _substream_match_waves(
-            stream, cfg, interpret=interpret, packed=packed, waves=waves,
-            max_width=max_width, block_s=block_s, telemetry=telemetry,
-            mb0=mb0,
+            seg_block=seg_block, telemetry=telemetry, mb0=mb0,
+            tiles_per_block=tiles_per_block,
         )
     if engine == "edges":
         return _edges_entry(
@@ -502,8 +446,8 @@ def _run_engine(
     if engine == "waves_xla":
         return _repack(
             _matching.mwm_waves(
-                stream, cfg, schedule=waves, max_width=max_width,
-                telemetry=telemetry, mb0=_mb0_dense(mb0, cfg, packed),
+                stream, cfg, schedule=waves, telemetry=telemetry,
+                mb0=_mb0_dense(mb0, cfg, packed),
             ),
             packed,
         )
@@ -530,23 +474,23 @@ def _run_engine(
     raise ValueError(f"unknown engine {engine!r}")
 
 
-def _fallback_attempts(schedule: str, seg_block, block_s):
+def _fallback_attempts(schedule: str, seg_block):
     """The ordered degradation ladder for ``on_plan_failure="fallback"``:
-    shrink the failing engine's tile knob first (smaller VMEM working
-    set), then step down mega -> waves -> waves_xla -> scan. Each entry
+    shrink the megakernel's slot working set first — one segment per
+    tile, then one tile per grid program — then step down to waves_xla
+    and scan. No rung repeats the program of an earlier one. Each entry
     is ``(engine, {knob overrides}, label)``."""
-    shrink_waves = [("waves", {"block_s": block_s}, "waves")]
-    if block_s != 1:
-        shrink_waves.append(("waves", {"block_s": 1}, "waves[block_s=1]"))
     xla = [("waves_xla", {}, "waves_xla"), ("scan", {}, "scan")]
-    if schedule == "mega":
-        attempts = [("mega", {"seg_block": seg_block}, "mega")]
-        if (MEGA_SEG_BLOCK if seg_block is None else seg_block) != 1:
-            attempts.append(("mega", {"seg_block": 1}, "mega[seg_block=1]"))
-        return attempts + shrink_waves + xla
-    if schedule == "waves":
-        return shrink_waves + xla
-    return [("edges", {}, "edges")] + xla
+    if schedule == "edges":
+        return [("edges", {}, "edges")] + xla
+    attempts = [("mega", {}, "mega")]
+    if (MEGA_SEG_BLOCK if seg_block is None else seg_block) != 1:
+        attempts.append(("mega", {"seg_block": 1}, "mega[seg_block=1]"))
+    attempts.append((
+        "mega", {"seg_block": 1, "tiles_per_block": 1},
+        "mega[seg_block=1,tiles_per_block=1]",
+    ))
+    return attempts + xla
 
 
 def _substream_match_fallback(
@@ -558,9 +502,7 @@ def _substream_match_fallback(
     packed,
     schedule,
     waves,
-    max_width,
     seg_block,
-    block_s,
     telemetry,
     mb0=None,
 ) -> MatchingResult:
@@ -579,11 +521,10 @@ def _substream_match_fallback(
     """
     from repro.core import guard as _guard
 
-    attempts = _fallback_attempts(schedule, seg_block, block_s)
+    attempts = _fallback_attempts(schedule, seg_block)
     failures = []
     for idx, (engine, overrides, label) in enumerate(attempts):
-        kw = {"seg_block": seg_block, "block_s": block_s}
-        kw.update(overrides)
+        kw = {"seg_block": seg_block, **overrides}
         ncalls = len(telemetry.match_calls)
         span = (
             telemetry.span("fallback", engine=label, attempt=idx)
@@ -594,9 +535,8 @@ def _substream_match_fallback(
             with span:
                 out = _run_engine(
                     engine, stream, cfg, block_e=block_e, interpret=interpret,
-                    packed=packed, waves=waves, max_width=max_width,
-                    seg_block=kw["seg_block"], block_s=kw["block_s"],
-                    telemetry=telemetry, mb0=mb0,
+                    packed=packed, waves=waves, telemetry=telemetry, mb0=mb0,
+                    **kw,
                 )
         except (_guard.StreamValidationError, _guard.MatchingInvariantError):
             raise
@@ -638,9 +578,7 @@ def substream_match(
     packed: bool | None = None,
     schedule: str = "edges",
     waves=None,
-    max_width: int | None = None,
     seg_block: int | None = None,
-    block_s: int | None = None,
     telemetry=obs.DISABLED,
     on_plan_failure: str = "raise",
     validate: str = "off",
@@ -657,20 +595,16 @@ def substream_match(
     ``schedule`` picks the pipeline:
 
     * ``"edges"`` — the paper-faithful 1-edge-per-iteration processor;
-    * ``"waves"`` — the wave processor: the stream is first decomposed
-      into vertex-disjoint waves (``repro.graph.waves``) on the host,
-      packed into ``seg``-slot segments; each kernel trip loads the
-      rows of one whole segment before it stores any. Bit-identical to
-      ``"edges"`` (greedy matching is confluent over vertex-disjoint
-      edges). Pass a precomputed ``waves`` schedule to amortize the
-      decomposition across runs; ``max_width`` caps the wave width when
-      building one here.
-    * ``"mega"`` — the megakernel: the wave schedule is re-padded
-      block-aligned (every tile of ``seg_block`` segments is a subset of
-      one wave, hence vertex-disjoint) and each trip processes one whole
-      tile. Same bit-identical contract as ``"waves"``, ~``seg_block``x
-      fewer sequential trips; ``seg_block=None`` takes
-      :data:`MEGA_SEG_BLOCK`.
+    * ``"mega"`` — the megakernel: the stream is first decomposed into
+      vertex-disjoint waves (``repro.graph.waves``), packed into
+      ``seg``-slot segments and re-padded block-aligned (every tile of
+      ``seg_block`` segments is a subset of one wave, hence
+      vertex-disjoint); each kernel trip loads the rows of one whole
+      tile before it stores any. Bit-identical to ``"edges"`` (greedy
+      matching is confluent over vertex-disjoint edges), with
+      ~``seg_block * seg``x fewer sequential trips; ``seg_block=None``
+      takes :data:`MEGA_SEG_BLOCK`. Pass a precomputed ``waves``
+      schedule to amortize the decomposition across runs.
 
     ``packed=None`` follows ``cfg.mb_layout``; ``block_e=None`` takes the
     auto-picked value from :func:`vmem_plan` (edges schedule only).
@@ -693,12 +627,11 @@ def substream_match(
 
     ``on_plan_failure`` picks what happens when a plan exceeds VMEM or
     the Pallas path fails: ``"raise"`` (default, today's behavior)
-    propagates; ``"fallback"`` degrades through the cascade — shrunk
-    ``seg_block``/``block_s`` first, then mega -> waves -> ``waves_xla``
-    -> the scan oracle — emitting an :class:`EngineFallbackWarning` and
-    ``fallback`` spans/events/counters so the degradation is observable,
-    never silent. ``block_s`` caps the
-    wave path's segments-per-program (``None`` = the plan's auto pick).
+    propagates; ``"fallback"`` degrades through the cascade — the
+    megakernel at one segment per tile, then at one tile per grid
+    program, then ``waves_xla`` -> the scan oracle — emitting an
+    :class:`EngineFallbackWarning` and ``fallback`` spans/events/counters
+    so the degradation is observable, never silent.
 
     With ``on_plan_failure="raise"``, raises if the bit block exceeds
     the VMEM budget — at that size the caller must vertex-partition
@@ -712,8 +645,8 @@ def substream_match(
         )
     interpret = resolve_interpret(interpret)
     packed = _resolve_packed(cfg, packed)
-    if schedule not in ("edges", "waves", "mega"):
-        raise ValueError(f"unknown schedule {schedule!r}")
+    if schedule not in ("edges", "mega"):
+        raise ValueError(f"unknown schedule {schedule!r}; use 'edges' or 'mega'")
     if on_plan_failure not in ("raise", "fallback"):
         raise ValueError(
             f"unknown on_plan_failure {on_plan_failure!r}; "
@@ -731,25 +664,17 @@ def substream_match(
     if on_plan_failure == "fallback":
         return _substream_match_fallback(
             stream, cfg, block_e=block_e, interpret=interpret, packed=packed,
-            schedule=schedule, waves=waves, max_width=max_width,
-            seg_block=seg_block, block_s=block_s, telemetry=telemetry,
-            mb0=mb0,
+            schedule=schedule, waves=waves, seg_block=seg_block,
+            telemetry=telemetry, mb0=mb0,
         )
     if schedule == "edges":
         return _edges_entry(
             stream, cfg, block_e=block_e, interpret=interpret, packed=packed,
             telemetry=telemetry, mb0=mb0,
         )
-    if schedule == "waves":
-        return _substream_match_waves(
-            stream, cfg, interpret=interpret, packed=packed,
-            waves=waves, max_width=max_width, block_s=block_s,
-            telemetry=telemetry, mb0=mb0,
-        )
     return _substream_match_mega(
         stream, cfg, interpret=interpret, packed=packed,
-        waves=waves, max_width=max_width, seg_block=seg_block,
-        telemetry=telemetry, mb0=mb0,
+        waves=waves, seg_block=seg_block, telemetry=telemetry, mb0=mb0,
     )
 
 
@@ -786,7 +711,7 @@ def _from_block(mb: jax.Array, plan: VmemPlan, cfg: SubstreamConfig):
 
 def _match_slots(u, v, w, num_groups, cfg, plan, group, groups_per_block,
                  interpret, mb0):
-    """Traced core shared by the three engines: Stage 4's threshold
+    """Traced core shared by the two engines: Stage 4's threshold
     count per slot (0 for self-loops and w <= 0), the kernel over the
     three (u, v, cnt) slot streams, and the caller-format bits."""
     thr = cfg.thresholds()
@@ -900,7 +825,7 @@ def _substream_match_edges(
     static_argnames=("cfg", "plan", "group", "interpret"),
 )
 def _slots_device(u, v, w, num_groups, cfg, plan, group, interpret, mb0=None):
-    """Jitted device half of the wave and mega paths: run the kernel over
+    """Jitted device half of the mega path: run the kernel over
     the host-prepped slot stream (grid-padded, groups vertex-disjoint;
     padding slots are ``u = v = 0, w = 0``). ``mb0`` (caller storage)
     seeds the bit block."""
@@ -910,9 +835,8 @@ def _slots_device(u, v, w, num_groups, cfg, plan, group, interpret, mb0=None):
     )
 
 
-# Separate module attributes so a fault injector can fail one engine's
-# device half without touching the other's.
-_waves_device = _slots_device
+# A module attribute of its own, so a fault injector can fail the mega
+# path's device half.
 _mega_device = _slots_device
 
 
@@ -925,32 +849,27 @@ def _kernel_plan(plan: VmemPlan) -> VmemPlan:
     )
 
 
-def _schedule_for(stream, waves, max_width, telemetry, rec):
+def _schedule_for(stream, waves, telemetry, rec):
     """Resolve the wave schedule, recording its stage times."""
     from repro.graph import waves as _waves
 
-    if waves is None and max_width is None:
-        # the uncapped links are built on the device that holds the stream
-        src, dst, valid = stream.src, stream.dst, stream.valid
-    else:
-        with rec.span("copy.d2h", what="stream"):
-            src = np.asarray(stream.src)
-            dst = np.asarray(stream.dst)
-            valid = np.asarray(stream.valid)
     if waves is None:
-        # built in-call: the schedule's own stopwatch measurements are
-        # the stage split (assign -> "schedule", layout -> "pack")
-        sch = _waves.resolve_schedule(
-            src, dst, valid, schedule=None, max_width=max_width,
-            telemetry=telemetry,
+        # built in-call, its links on the device that holds the stream:
+        # the schedule's own stopwatch measurements are the stage split
+        # (assign -> "schedule", layout -> "pack")
+        sch = _waves.wave_schedule(
+            stream.src, stream.dst, valid=stream.valid, telemetry=telemetry
         )
         rec.add_stage("schedule", sch.schedule_seconds)
         rec.add_stage("pack", sch.pack_seconds)
         return sch
+    with rec.span("copy.d2h", what="stream"):
+        src = np.asarray(stream.src)
+        dst = np.asarray(stream.dst)
+        valid = np.asarray(stream.valid)
     with rec.stage("schedule"):  # precomputed: validation cost only
         return _waves.resolve_schedule(
-            src, dst, valid, schedule=waves, max_width=max_width,
-            telemetry=telemetry,
+            src, dst, valid, schedule=waves, telemetry=telemetry
         )
 
 
@@ -958,7 +877,7 @@ def _run_slot_layout(
     stream, cfg, plan, slots, num_groups, group, device, engine, rec,
     interpret, packed, mb0,
 ):
-    """Shared host half of the wave and mega paths. ``slots`` is the
+    """Host half of the mega path. ``slots`` is the
     [rows, seg] slot -> stream-position map (-1 = padding), already
     grouped so each run of ``group`` slots is vertex-disjoint: gather
     the slot stream (padding -> ``u = v = 0, w = 0``), pad it to whole
@@ -1011,52 +930,16 @@ def _run_slot_layout(
     return total, _result(assigned, mb, cfg, packed)
 
 
-def _substream_match_waves(
-    stream: EdgeStream,
-    cfg: SubstreamConfig,
-    interpret: bool,
-    packed: bool,
-    waves=None,
-    max_width: int | None = None,
-    block_s: int | None = None,
-    telemetry=obs.DISABLED,
-    mb0=None,
-) -> MatchingResult:
-    from repro.graph import waves as _waves
-
-    rec = obs.recorder(
-        telemetry, "pallas_waves", stream.num_edges,
-        jax.default_backend(), interpret,
-    )
-    sch = _schedule_for(stream, waves, max_width, telemetry, rec)
-    plan = wave_plan(cfg.n, cfg.L, sch, packed=packed, block_s=block_s)
-    _check_budget(plan)
-    total, out = _run_slot_layout(
-        stream, cfg, plan, sch.slots, sch.num_segments, plan.seg,
-        _waves_device, "waves", rec, interpret, packed, mb0,
-    )
-    if telemetry.enabled:
-        rec.put_many(_waves.schedule_counters(sch))
-        rec.put_many(plan_counters(plan))
-        rec.put("stream.num_edges", stream.num_edges)
-        rec.put("kernel.trips", plan.num_segments)
-        rec.put(
-            "traffic.hbm_bytes", traffic_bytes(total, plan.nbytes, mb0 is not None)
-        )
-    rec.finish()
-    return out
-
-
 def _substream_match_mega(
     stream: EdgeStream,
     cfg: SubstreamConfig,
     interpret: bool,
     packed: bool,
     waves=None,
-    max_width: int | None = None,
     seg_block: int | None = None,
     telemetry=obs.DISABLED,
     mb0=None,
+    tiles_per_block: int | None = None,
 ) -> MatchingResult:
     from repro.graph import waves as _waves
 
@@ -1066,10 +949,12 @@ def _substream_match_mega(
         telemetry, "pallas_mega", stream.num_edges,
         jax.default_backend(), interpret,
     )
-    sch = _schedule_for(stream, waves, max_width, telemetry, rec)
+    sch = _schedule_for(stream, waves, telemetry, rec)
     with rec.stage("layout"), rec.span("layout.block_align"):
         layout = _waves.block_aligned_layout(sch, seg_block)
-    plan = mega_plan(cfg.n, cfg.L, layout, packed=packed)
+    plan = mega_plan(
+        cfg.n, cfg.L, layout, packed=packed, tiles_per_block=tiles_per_block
+    )
     _check_budget(plan)
     total, out = _run_slot_layout(
         stream, cfg, plan, layout.slots, layout.num_tiles,
@@ -1096,7 +981,7 @@ def _substream_match_mega(
 #: through :func:`substream_match`'s machinery; ``scan`` / ``waves_xla``
 #: are the XLA engines and ``ref`` the pure-jnp oracle — all accept the
 #: carried ``mb0`` state, so every engine is epoch-chunkable.
-EPOCH_ENGINES = ("edges", "waves", "mega", "scan", "waves_xla", "ref")
+EPOCH_ENGINES = ("edges", "mega", "scan", "waves_xla", "ref")
 
 
 def epoch_bounds(num_edges: int, epochs: int) -> list[int]:
@@ -1124,9 +1009,7 @@ def match_epochs(
     validate: str = "off",
     on_plan_failure: str = "raise",
     block_e: int | None = None,
-    max_width: int | None = None,
     seg_block: int | None = None,
-    block_s: int | None = None,
     epoch_hook=None,
 ) -> MatchingResult:
     """Run Part 1 chunked into ``epochs`` resumable epochs.
@@ -1197,9 +1080,7 @@ def match_epochs(
         )
     m = stream.num_edges
     bounds = epoch_bounds(m, epochs)
-    fallback = on_plan_failure == "fallback" and engine in (
-        "edges", "waves", "mega",
-    )
+    fallback = on_plan_failure == "fallback" and engine in ("edges", "mega")
     for k in range(epochs):
         a, b = max(bounds[k], state.pos), bounds[k + 1]
         if b <= state.pos:
@@ -1221,14 +1102,12 @@ def match_epochs(
                 return _substream_match_fallback(
                     sub, cfg, block_e=block_e, interpret=interpret,
                     packed=packed, schedule=engine, waves=None,
-                    max_width=max_width, seg_block=seg_block,
-                    block_s=block_s, telemetry=telemetry, mb0=mb0,
+                    seg_block=seg_block, telemetry=telemetry, mb0=mb0,
                 )
             return _run_engine(
                 engine, sub, cfg, block_e=block_e, interpret=interpret,
-                packed=packed, waves=None, max_width=max_width,
-                seg_block=seg_block, block_s=block_s, telemetry=telemetry,
-                mb0=mb0,
+                packed=packed, waves=None, seg_block=seg_block,
+                telemetry=telemetry, mb0=mb0,
             )
 
         out = guard.run(run_one, label=f"epoch[{k}]") if guard else run_one()

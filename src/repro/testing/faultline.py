@@ -217,10 +217,8 @@ class InjectedFailure(RuntimeError):
 #: cannot route around an injected failure.
 _TARGETS = {
     "vmem_plan": "vmem_plan",
-    "wave_plan": "wave_plan",
     "mega_plan": "mega_plan",
     "edges_device": "_substream_match_edges",
-    "waves_device": "_waves_device",
     "mega_device": "_mega_device",
     "scan_oracle": "mwm_scan",
     "waves_xla": "mwm_waves",
@@ -232,8 +230,8 @@ def failing(*targets: str, exc_type=InjectedFailure):
     """Force the named ops/matching internals to raise inside the block.
 
     ``targets`` are keys of :data:`_TARGETS` — planners (``vmem_plan``,
-    ``wave_plan``, ``mega_plan``), jitted device entries
-    (``edges_device``, ``waves_device``, ``mega_device``), or the XLA
+    ``mega_plan``), jitted device entries (``edges_device``,
+    ``mega_device``), or the XLA
     fallbacks (``waves_xla``, ``scan_oracle``). Always restores the
     originals, even when the block raises."""
     from repro.core import matching as _matching

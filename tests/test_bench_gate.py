@@ -16,7 +16,6 @@ from the resumable path with snapshot producer stall within budget, a
 bit-exact killed-and-resumed result, and zero clean-path retries.
 """
 import json
-import pathlib
 import sys
 
 import pytest
@@ -34,11 +33,6 @@ from benchmarks.bench_throughput import (
 #: + the recovery gate (snapshot stall, bit-exact resume, zero retries).
 N_GATES = 7
 
-_WAVES_EXPECT = {
-    "plan.gather_bytes": 960,
-    "plan.bit_block_bytes": 8192,
-    "traffic.hbm_bytes": 100_000,
-}
 _MEGA_EXPECT = {
     "plan.gather_bytes": 4352,
     "plan.bit_block_bytes": 8192,
@@ -70,15 +64,12 @@ def _graph(scale=10, speedup=9.0, fill=0.7, mega=1.3):
     engines["pallas_edges"] = _engine_row(
         {"stream.num_edges": 8192, "fallback.count": 0}
     )
-    engines["pallas_waves"] = _engine_row(
-        {"stream.num_edges": 8192, "fallback.count": 0, **_WAVES_EXPECT}
-    )
     engines["pallas_mega"] = _engine_row(
         {"stream.num_edges": 8192, "fallback.count": 0, **_MEGA_EXPECT}
     )
     return {
         "scale": scale,
-        "speedup_pallas_waves_vs_edges": speedup,
+        "speedup_pallas_mega_vs_edges": speedup,
         "wave_fill": fill,
         "speedup_mega_vs_xla": mega,
         "validation": {
@@ -89,7 +80,6 @@ def _graph(scale=10, speedup=9.0, fill=0.7, mega=1.3):
             "guard.num_problems": 0,
         },
         "expected_counters": {
-            "pallas_waves": dict(_WAVES_EXPECT),
             "pallas_mega": dict(_MEGA_EXPECT),
         },
         "recovery": {
@@ -207,11 +197,11 @@ def test_plan_counter_mismatch_fails_bit_exactly():
     """A single off-by-one in the emitted gather bytes is a gate failure —
     the counters must equal the recomputed plan accounting exactly."""
     g = _graph()
-    g["engines"]["pallas_waves"]["counters"]["plan.gather_bytes"] += 1
+    g["engines"]["pallas_mega"]["counters"]["plan.gather_bytes"] += 1
     ok, msgs = check_report(_report([g]))
     assert not ok
     assert any(
-        "plan.gather_bytes" in m and "pallas_waves" in m for m in msgs
+        "plan.gather_bytes" in m and "pallas_mega" in m for m in msgs
     )
     g2 = _graph()
     del g2["engines"]["pallas_mega"]["counters"]["traffic.hbm_bytes"]
@@ -266,11 +256,11 @@ def test_missing_fallback_counter_fails():
     """Dropping the counter (e.g. running with on_plan_failure='raise')
     fails loudly rather than passing vacuously."""
     g = _graph()
-    del g["engines"]["pallas_waves"]["counters"]["fallback.count"]
+    del g["engines"]["pallas_mega"]["counters"]["fallback.count"]
     ok, msgs = check_report(_report([g]))
     assert not ok
     assert any(
-        "pallas_waves" in m and "no fallback.count" in m for m in msgs
+        "pallas_mega" in m and "no fallback.count" in m for m in msgs
     )
 
 
@@ -389,12 +379,3 @@ def test_trace_flag_writes_chrome_trace(monkeypatch, capsys, tmp_path):
     trace = json.loads(out_path.read_text())
     assert "traceEvents" in trace and isinstance(trace["traceEvents"], list)
 
-
-def test_committed_bench_record_passes_gate():
-    """The repo's committed BENCH_substream.json satisfies its own gate
-    (including mega >= waves_xla at every recorded scale AND the
-    telemetry stage/counter gates)."""
-    path = pathlib.Path(__file__).resolve().parent.parent / "BENCH_substream.json"
-    report = json.loads(path.read_text())
-    ok, msgs = check_report(report)
-    assert ok, msgs

@@ -5,8 +5,8 @@ Six engines claim bit-identical Part-1 semantics:
 * ``scan``         — `mwm_scan`, the sequential Listing-1 baseline;
 * ``ref``          — the pure-jnp kernel oracle (`substream_match_ref`);
 * ``pallas_edges`` — the 1-edge-per-iteration Pallas processor;
-* ``pallas_waves`` — the wave-vectorized Pallas processor;
 * ``mega``         — the grid-pipelined segment megakernel;
+* ``mega_seg1``    — the megakernel at one segment per tile;
 * ``waves_xla``    — the plain-XLA wave parity oracle (`mwm_waves`).
 
 Every engine runs on a shared zoo of adversarial graphs (empty stream,
@@ -147,9 +147,9 @@ def _run_waves_xla(stream, cfg):
     return np.asarray(r.assigned), np.asarray(r.mb)
 
 
-def _run_pallas(schedule):
+def _run_pallas(schedule, **kw):
     def run(stream, cfg):
-        r = substream_match(stream, cfg, interpret=True, schedule=schedule)
+        r = substream_match(stream, cfg, interpret=True, schedule=schedule, **kw)
         return np.asarray(r.assigned), np.asarray(r.mb)
 
     return run
@@ -158,8 +158,8 @@ def _run_pallas(schedule):
 ENGINES = {
     "ref": _run_ref,
     "pallas_edges": _run_pallas("edges"),
-    "pallas_waves": _run_pallas("waves"),
     "mega": _run_pallas("mega"),
+    "mega_seg1": _run_pallas("mega", seg_block=1),
     "waves_xla": _run_waves_xla,
 }
 

@@ -18,6 +18,8 @@ the three guard-layer claims:
    rewrites and bit-plane flips all raise
    :class:`MatchingInvariantError`.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -140,34 +142,43 @@ def test_validate_policy_threaded_through_substream_match():
 # 2. Plan/compile faults: the cascade degrades, observably, to a correct engine
 # ---------------------------------------------------------------------------
 
+MEGA = {"schedule": "mega"}
+SEG1 = {"schedule": "mega", "seg_block": 1}
+
+#: case -> (failing targets, engine options, calls that fail per target
+#: (None: every call), the ladder rung that delivers)
 PLAN_FAULTS = {
-    "mega_plan": (("mega_plan",), "mega"),
-    "mega_compile": (("mega_device",), "mega"),
-    "mega_then_waves": (("mega_plan", "mega_device", "wave_plan"), "mega"),
-    "all_pallas_mega": (
-        ("mega_plan", "mega_device", "wave_plan", "waves_device"),
-        "mega",
-    ),
+    "mega_plan": (("mega_plan",), MEGA, None, "waves_xla"),
+    "mega_compile": (("mega_device",), MEGA, None, "waves_xla"),
+    "mega_then_seg1": (("mega_plan",), MEGA, 1, "mega[seg_block=1]"),
+    "all_pallas_mega": (("mega_plan", "mega_device"), MEGA, None, "waves_xla"),
     "down_to_scan": (
-        ("mega_plan", "mega_device", "wave_plan", "waves_device", "waves_xla"),
-        "mega",
+        ("mega_plan", "mega_device", "waves_xla"), MEGA, None, "scan",
     ),
-    "waves_plan": (("wave_plan",), "waves"),
-    "waves_compile": (("waves_device",), "waves"),
-    "edges_compile": (("edges_device",), "edges"),
+    "seg1_plan": (
+        ("mega_plan",), SEG1, 1, "mega[seg_block=1,tiles_per_block=1]",
+    ),
+    "seg1_compile": (("mega_device",), SEG1, None, "waves_xla"),
+    "edges_compile": (("edges_device",), {"schedule": "edges"}, None, "waves_xla"),
 }
+
+
+def _faults(targets, times):
+    if times is None:
+        return faultline.failing(*targets)
+    return faultline.flaky(*targets, times=times)
 
 
 @pytest.mark.parametrize("name", sorted(PLAN_FAULTS))
 def test_cascade_lands_on_a_correct_engine(name):
-    targets, schedule = PLAN_FAULTS[name]
+    targets, options, times, delivers = PLAN_FAULTS[name]
     stream, cfg = _stream(seed=1)
     want = mwm_scan(stream, cfg)
     tel = obs.Telemetry()
-    with faultline.failing(*targets):
+    with _faults(targets, times):
         got = substream_match(
-            stream, cfg, schedule=schedule, interpret=True,
-            on_plan_failure="fallback", telemetry=tel,
+            stream, cfg, interpret=True, on_plan_failure="fallback",
+            telemetry=tel, **options,
         )
     assert (np.asarray(got.assigned) == np.asarray(want.assigned)).all()
     assert (np.asarray(got.mb) == np.asarray(want.mb)).all()
@@ -175,7 +186,8 @@ def test_cascade_lands_on_a_correct_engine(name):
     assert tel.counters.get("fallback.count") >= 1
     events = [e for e in tel.events if e["name"] == "fallback"]
     assert events and all("reason" in e and "from_engine" in e for e in events)
-    assert any("injected failure" in e["reason"] for e in events)
+    assert any(re.search("injected (failure|flake)", e["reason"]) for e in events)
+    assert events[-1]["to_engine"] == delivers
     # the record of the engine that delivered carries the degradation depth
     if tel.match_calls:
         assert tel.match_calls[-1].counters["fallback.count"] == len(events)
@@ -209,8 +221,7 @@ def test_raise_mode_propagates_injected_failures():
 def test_cascade_exhaustion_names_every_attempt():
     stream, cfg = _stream()
     all_engines = (
-        "mega_plan", "mega_device", "wave_plan", "waves_device",
-        "edges_device", "waves_xla", "scan_oracle",
+        "mega_plan", "mega_device", "edges_device", "waves_xla", "scan_oracle",
     )
     with faultline.failing(*all_engines):
         with pytest.raises(FallbackExhaustedError) as exc:
@@ -220,7 +231,7 @@ def test_cascade_exhaustion_names_every_attempt():
             )
     labels = [label for label, _ in exc.value.attempts]
     assert labels == [
-        "mega", "mega[seg_block=1]", "waves", "waves[block_s=1]",
+        "mega", "mega[seg_block=1]", "mega[seg_block=1,tiles_per_block=1]",
         "waves_xla", "scan",
     ]
     assert all("injected failure" in str(err) for _, err in exc.value.attempts)
@@ -249,13 +260,13 @@ def test_stale_schedule_is_rejected_then_survived(corruptor):
         validate_schedule(bad, src, dst, valid)
     # raise mode: the corruption propagates
     with pytest.raises(ValueError):
-        substream_match(stream, cfg, schedule="waves", waves=bad, interpret=True)
+        substream_match(stream, cfg, waves=bad, interpret=True, **SEG1)
     # fallback mode: every schedule consumer fails, scan (which ignores the
     # schedule) still delivers the bit-exact result
     tel = obs.Telemetry()
     got = substream_match(
-        stream, cfg, schedule="waves", waves=bad, interpret=True,
-        on_plan_failure="fallback", telemetry=tel,
+        stream, cfg, waves=bad, interpret=True, on_plan_failure="fallback",
+        telemetry=tel, **SEG1,
     )
     want = mwm_scan(stream, cfg)
     assert (np.asarray(got.assigned) == np.asarray(want.assigned)).all()
@@ -280,9 +291,7 @@ def test_duplicate_order_entry_is_rejected():
 
 def test_fallback_result_repacked_to_requested_storage():
     stream, cfg = _stream(seed=4)
-    with faultline.failing(
-        "mega_plan", "mega_device", "wave_plan", "waves_device"
-    ):
+    with faultline.failing("mega_plan", "mega_device"):
         got = substream_match(
             stream, cfg, schedule="mega", interpret=True,
             on_plan_failure="fallback",

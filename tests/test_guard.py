@@ -140,9 +140,9 @@ def _run_waves_xla(stream, cfg):
     return np.asarray(r.assigned), np.asarray(r.mb)
 
 
-def _run_pallas(schedule):
+def _run_pallas(schedule, **kw):
     def run(stream, cfg):
-        r = substream_match(stream, cfg, interpret=True, schedule=schedule)
+        r = substream_match(stream, cfg, interpret=True, schedule=schedule, **kw)
         return np.asarray(r.assigned), np.asarray(r.mb)
 
     return run
@@ -151,8 +151,8 @@ def _run_pallas(schedule):
 ENGINES = {
     "ref": _run_ref,
     "pallas_edges": _run_pallas("edges"),
-    "pallas_waves": _run_pallas("waves"),
     "mega": _run_pallas("mega"),
+    "mega_seg1": _run_pallas("mega", seg_block=1),
     "waves_xla": _run_waves_xla,
 }
 
@@ -319,8 +319,10 @@ def test_degenerate_streams_well_formed_everywhere(case):
         cfg = SubstreamConfig(n=0, L=4)
     _assert_empty_result(mwm_scan(stream, cfg), stream, cfg)
     _assert_empty_result(mwm_waves(stream, cfg), stream, cfg)
-    for schedule in ("edges", "waves", "mega"):
-        res = substream_match(stream, cfg, interpret=True, schedule=schedule)
+    for schedule, seg_block in (("edges", None), ("mega", 1), ("mega", None)):
+        res = substream_match(
+            stream, cfg, interpret=True, schedule=schedule, seg_block=seg_block
+        )
         _assert_empty_result(res, stream, cfg)
 
 

@@ -1,6 +1,6 @@
 """Property-based invariants of the grid-pipelined segment megakernel.
 
-Random RMAT graphs with random ``seg_block`` / wave-width / ``L`` draws
+Random RMAT graphs with random ``seg_block`` / ``L`` draws
 must leave the megakernel bit-identical to the sequential scan, and the
 double-buffered grid pipeline must never let a tile's gather observe
 state from its own (or a later) tile's scatter.  The second property is
@@ -38,16 +38,15 @@ def _rmat_case(draw):
     pad = draw(st.sampled_from([0, 5]))
     stream = EdgeStream.from_numpy(src, dst, w, n_pad=src.shape[0] + pad)
     seg_block = draw(st.sampled_from([1, 2, 3, 4]))
-    max_width = draw(st.sampled_from([None, 2, 8]))
-    return stream, cfg, seg_block, max_width
+    return stream, cfg, seg_block
 
 
 @given(st.data())
 @settings(**SETTINGS)
 def test_mega_bit_identical_to_scan(data):
-    """Random RMAT x random seg_block/width/L: mega == scan, bit for bit,
+    """Random RMAT x random seg_block/L: mega == scan, bit for bit,
     in both bit layouts."""
-    stream, cfg, seg_block, max_width = _rmat_case(data.draw)
+    stream, cfg, seg_block = _rmat_case(data.draw)
     want = mwm_scan(stream, cfg)
     packed = data.draw(st.booleans())
     got = substream_match(
@@ -55,7 +54,6 @@ def test_mega_bit_identical_to_scan(data):
         cfg,
         schedule="mega",
         seg_block=seg_block,
-        max_width=max_width,
         interpret=True,
         packed=packed,
     )
@@ -97,11 +95,11 @@ def test_mega_tiles_never_read_before_scatter(data):
     """Double-buffer safety: (a) no tile straddles a wave boundary and
     every tile is vertex-disjoint (structural race-freedom), (b) the
     interpret-mode kernel equals the atomic pre-tile-state replay."""
-    stream, cfg, seg_block, max_width = _rmat_case(data.draw)
+    stream, cfg, seg_block = _rmat_case(data.draw)
     src = np.asarray(stream.src)
     dst = np.asarray(stream.dst)
     valid = np.asarray(stream.valid)
-    sch = wave_schedule(src, dst, valid=valid, max_width=max_width)
+    sch = wave_schedule(src, dst, valid=valid)
     layout = block_aligned_layout(sch, seg_block)
     # (a) structural: each tile lies inside one wave...
     offs = layout.seg_offsets

@@ -38,13 +38,21 @@ from repro.kernels.substream_match.ops import (
     mega_plan,
     substream_match,
     traffic_bytes,
-    wave_plan,
 )
 from repro.obs import trace as obs_trace
 
 
 def _round_up(x, mult):
     return ((x + mult - 1) // mult) * mult
+
+
+#: The Pallas engines by id: the per-edge kernel, and the megakernel at
+#: one segment per tile and at the default ``MEGA_SEG_BLOCK``.
+PALLAS = {
+    "edges": {"schedule": "edges"},
+    "mega_seg1": {"schedule": "mega", "seg_block": 1},
+    "mega": {"schedule": "mega"},
+}
 
 
 def _workload(m=600, n=128, L=8, eps=0.1, seed=0):
@@ -179,7 +187,7 @@ def test_span_args_carry_call_and_parent():
         ("links", "wave_schedule.links"), ("stream", "pallas_mega.layout"),
         ("assigned_slots", "pallas_mega.layout"),
     } <= d2h
-    # the uncapped schedule reads the stream on the device
+    # the schedule reads the stream on the device
     assert ("stream", None) not in d2h
     for e in _spans(tel, "copy.h2d"):
         assert e["args"]["parent"] in (
@@ -190,18 +198,18 @@ def test_span_args_carry_call_and_parent():
     assert align["args"]["parent"] == "pallas_mega.layout"
 
 
-@pytest.mark.parametrize("eng", ["edges", "waves", "mega"])
+@pytest.mark.parametrize("eng", list(PALLAS))
 def test_kernel_trips_are_the_plans_trip_count(eng):
     """``kernel.trips`` is the kernel's dependent trip count, exact from
-    the plan: one per edge, per wave segment, or per mega tile."""
+    the plan: one per edge, per one-segment tile, or per mega tile."""
     stream, cfg = _workload(m=700, n=160, L=8)
     src, dst = np.asarray(stream.src), np.asarray(stream.dst)
     tel = obs.Telemetry()
-    substream_match(stream, cfg, schedule=eng, telemetry=tel)
+    substream_match(stream, cfg, telemetry=tel, **PALLAS[eng])
     sch = wave_schedule(src, dst, valid=np.asarray(stream.valid))
     want = {
         "edges": lambda: stream.num_edges,
-        "waves": lambda: wave_plan(cfg.n, cfg.L, sch).num_segments,
+        "mega_seg1": lambda: sch.num_segments,
         "mega": lambda: mega_plan(
             cfg.n, cfg.L, block_aligned_layout(sch, MEGA_SEG_BLOCK)
         ).num_tiles,
@@ -218,8 +226,8 @@ def test_disabled_path_opens_no_profiler_annotation(monkeypatch):
 
     monkeypatch.setattr(obs_trace, "_annotation", annotation)
     stream, cfg = _workload(m=300, n=64, L=8)
-    for eng in ("edges", "waves", "mega"):
-        res = substream_match(stream, cfg, schedule=eng)
+    for kw in PALLAS.values():
+        res = substream_match(stream, cfg, **kw)
     merge.merge_host(stream, res, cfg)
     match_epochs(stream, cfg, epochs=2, engine="mega")
     mwm_pipeline(stream, cfg, part1="pallas", K=8)
@@ -316,9 +324,9 @@ def test_disabled_hot_loop_does_not_accumulate_allocations():
 def test_disabled_engine_results_identical():
     stream, cfg = _workload()
     tel = obs.Telemetry()
-    for eng in ("edges", "waves", "mega"):
-        a = substream_match(stream, cfg, schedule=eng, telemetry=tel)
-        b = substream_match(stream, cfg, schedule=eng)
+    for kw in PALLAS.values():
+        a = substream_match(stream, cfg, telemetry=tel, **kw)
+        b = substream_match(stream, cfg, **kw)
         np.testing.assert_array_equal(np.asarray(a.assigned), np.asarray(b.assigned))
 
 
@@ -337,13 +345,13 @@ def test_consistency_problems_unit():
     assert any("exceeds wall" in p for p in probs)
 
 
-@pytest.mark.parametrize("eng", ["edges", "waves", "mega"])
+@pytest.mark.parametrize("eng", list(PALLAS))
 def test_stage_seconds_within_wall(eng):
     stream, cfg = _workload(m=500, n=96, L=8, eps=0.12, seed=eng.__hash__() % 7)
     tel = obs.Telemetry()
-    substream_match(stream, cfg, schedule=eng, telemetry=tel)
+    substream_match(stream, cfg, telemetry=tel, **PALLAS[eng])
     rec = tel.match_calls[-1]
-    assert rec.engine == f"pallas_{eng}"
+    assert rec.engine == f"pallas_{PALLAS[eng]['schedule']}"
     assert obs.consistency_problems(rec.stage_seconds, rec.wall_seconds) == []
     assert set(rec.stage_seconds) == set(obs.STAGES)
 
@@ -353,9 +361,10 @@ def test_compile_then_execute_labeling():
     `execute` — tracked process-wide, including disabled warmups."""
     stream, cfg = _workload(m=333, n=64, L=8, eps=0.17, seed=5)
     tel = obs.Telemetry()
-    substream_match(stream, cfg, schedule="waves", telemetry=tel)
+    seg1 = PALLAS["mega_seg1"]
+    substream_match(stream, cfg, telemetry=tel, **seg1)
     cold = tel.match_calls[-1]
-    substream_match(stream, cfg, schedule="waves", telemetry=tel)
+    substream_match(stream, cfg, telemetry=tel, **seg1)
     warm = tel.match_calls[-1]
     assert cold.stage_seconds["compile"] > 0 and cold.stage_seconds["execute"] == 0
     assert warm.stage_seconds["compile"] == 0 and warm.stage_seconds["execute"] > 0
@@ -363,9 +372,9 @@ def test_compile_then_execute_labeling():
     assert warm.counters["jit.variant_hit"] == 1
     # a warmup made with telemetry DISABLED still marks the variant warm
     stream2, cfg2 = _workload(m=334, n=64, L=8, eps=0.17, seed=6)
-    substream_match(stream2, cfg2, schedule="waves")
+    substream_match(stream2, cfg2, **seg1)
     tel2 = obs.Telemetry()
-    substream_match(stream2, cfg2, schedule="waves", telemetry=tel2)
+    substream_match(stream2, cfg2, telemetry=tel2, **seg1)
     assert tel2.match_calls[-1].stage_seconds["compile"] == 0
 
 
@@ -374,10 +383,10 @@ def test_wave_counters_bit_exact_against_plan():
     src, dst = np.asarray(stream.src), np.asarray(stream.dst)
     valid = np.asarray(stream.valid)
     tel = obs.Telemetry()
-    substream_match(stream, cfg, schedule="waves", telemetry=tel)
+    substream_match(stream, cfg, telemetry=tel, **PALLAS["mega_seg1"])
     rec = tel.match_calls[-1]
     sch = wave_schedule(src, dst, valid=valid)
-    plan = wave_plan(cfg.n, cfg.L, sch)
+    plan = mega_plan(cfg.n, cfg.L, block_aligned_layout(sch, 1))
     assert rec.counters["plan.gather_bytes"] == plan.gather_bytes
     assert rec.counters["plan.bit_block_bytes"] == plan.nbytes
     assert rec.counters["plan.seg"] == plan.seg
@@ -417,14 +426,14 @@ def test_counters_deterministic_across_runs():
 
     def counters_of(eng):
         tel = obs.Telemetry()
-        substream_match(stream, cfg, schedule=eng, telemetry=tel)
+        substream_match(stream, cfg, telemetry=tel, **PALLAS[eng])
         return {
             k: v
             for k, v in tel.match_calls[-1].counters.items()
             if not k.startswith("jit.")
         }
 
-    for eng in ("edges", "waves", "mega"):
+    for eng in PALLAS:
         first = counters_of(eng)
         second = counters_of(eng)
         assert first == second
@@ -541,9 +550,9 @@ def test_xla_engines_and_merge_record():
 def test_match_telemetry_asdict_json_ready():
     stream, cfg = _workload(m=300, n=64, L=8)
     tel = obs.Telemetry()
-    substream_match(stream, cfg, schedule="waves", telemetry=tel)
+    substream_match(stream, cfg, telemetry=tel, **PALLAS["mega_seg1"])
     d = tel.match_calls[-1].asdict()
     json.dumps(d)  # must serialize
     assert list(d["stage_seconds"]) == list(obs.STAGES)
     assert d["edges_per_sec"] > 0
-    assert d["engine"] == "pallas_waves"
+    assert d["engine"] == "pallas_mega"

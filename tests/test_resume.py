@@ -39,6 +39,11 @@ from repro.testing import faultline
 N, M, L = 44, 98, 12
 EPOCHS = 7
 
+#: Every epoch engine, plus the megakernel at one segment per tile (its
+#: smallest slot working set, the fallback ladder's second rung).
+ENGINES = {name: {"engine": name} for name in EPOCH_ENGINES}
+ENGINES["mega_seg1"] = {"engine": "mega", "seg_block": 1}
+
 
 def _build_stream():
     rng = np.random.default_rng(42)
@@ -86,13 +91,13 @@ def test_epoch_bounds_properties():
 
 
 @pytest.mark.parametrize("packed", [True, False], ids=["packed", "dense"])
-@pytest.mark.parametrize("engine", EPOCH_ENGINES)
+@pytest.mark.parametrize("engine", list(ENGINES))
 @pytest.mark.parametrize("epochs", [1, 2, 7])
 def test_epoch_parity(engine, epochs, packed):
     """Chunked == one-shot, bit for bit, for every engine and E."""
     out = match_epochs(
-        STREAM, CFG, epochs=epochs, engine=engine, packed=packed,
-        interpret=True,
+        STREAM, CFG, epochs=epochs, packed=packed, interpret=True,
+        **ENGINES[engine],
     )
     assert out.is_packed == packed
     _assert_bit_identical(out)
@@ -116,14 +121,14 @@ def test_unknown_engine_rejected():
 
 
 @pytest.mark.parametrize("packed", [True, False], ids=["packed", "dense"])
-@pytest.mark.parametrize("engine", EPOCH_ENGINES)
+@pytest.mark.parametrize("engine", list(ENGINES))
 @pytest.mark.parametrize("kill", range(EPOCHS))
 def test_kill_and_resume_bit_identical(tmp_path, engine, kill, packed):
     """Kill after epoch ``kill`` snapshots, resume from the latest
     snapshot, and the stitched run equals the one-shot oracle with a
     clean check_matching postcondition."""
     kw = dict(
-        epochs=EPOCHS, engine=engine, packed=packed, interpret=True,
+        epochs=EPOCHS, packed=packed, interpret=True, **ENGINES[engine],
     )
     with pytest.raises(faultline.SimulatedCrash):
         match_epochs(
@@ -356,7 +361,7 @@ def test_epochs_with_fallback_cascade(tmp_path):
     PR 8 cascade (mega -> ... -> scan) and the chunked run still
     matches the oracle; snapshots keep committing."""
     snaps = SnapshotManager(tmp_path, keep=0, async_save=False)
-    with faultline.failing("mega_device", "waves_device"):
+    with faultline.failing("mega_device"):
         out = match_epochs(
             STREAM, CFG, epochs=3, engine="mega", interpret=True,
             on_plan_failure="fallback", snapshots=snaps,

@@ -1,4 +1,4 @@
-"""Compile the three Pallas engines for a described TPU v5e, at the
+"""Compile the Pallas engines for a described TPU v5e, at the
 paper's default width (n = 2^20, L = 64), with the block sizes their
 plans pick. Nothing runs: the TPU compiler is installed and compiles
 for a chip that is described, not attached, so a Mosaic refusal (an
@@ -110,8 +110,10 @@ def test_edges_compiles_at_paper_width(one_chip, mb0):
 
 
 def test_waves_compiles_at_paper_width(one_chip):
+    """The megakernel at one segment per tile, the fallback ladder's
+    second rung."""
     sch = _independent_schedule(2**16)
-    plan = ops.wave_plan(N, L, sch)
+    plan = ops.mega_plan(N, L, block_aligned_layout(sch, 1))
     assert plan.block_e % ops.SMEM_ALIGN == 0
     _assert_kernel(_compile_slots(CFG, plan, plan.seg, one_chip))
 

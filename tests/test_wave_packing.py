@@ -1,19 +1,18 @@
-"""Property suite for the fill-packed earliest-fit wave scheduler.
+"""Property suite for the fill-packed wave scheduler.
 
 The packer's contract, on top of the generic wave invariants:
 
 * every wave — and therefore every packed segment row — is
   vertex-disjoint;
 * conflicting edges keep their processing order across waves;
-* every edge is placed at or past its greedy conflict depth (exactly at
-  it when uncapped, which makes the uncapped wave count provably
-  minimal);
+* every edge is placed exactly at its greedy conflict depth, which
+  makes the wave count provably minimal;
 * the fill-packed [num_segments, SEG] layout carries padding only at
   each wave's tail segment, so the fill never depends on wave-size skew;
 * both the packed (uint8 bit-plane) and unpacked (int8) engine layouts
   stay bit-identical to the sequential scan oracle in ``assigned`` and
-  ``mb`` — including self-loops, duplicate edges, L % 8 != 0, capped
-  (earliest-fit occupancy) schedules, and single-edge streams.
+  ``mb`` — including self-loops, duplicate edges, L % 8 != 0, and
+  single-edge streams.
 """
 import contextlib
 
@@ -67,7 +66,7 @@ def _stream(draw, max_n=48, max_m=150):
 @given(st.data())
 @settings(**SETTINGS)
 def test_packer_invariants_uncapped(data):
-    """Uncapped packing = exact conflict depth: wave-count minimal, and
+    """Packing = exact conflict depth: wave-count minimal, and
     the packed layout groups each wave's members contiguously with
     padding only at its tail segment."""
     stream, _ = _stream(data.draw)
@@ -77,7 +76,7 @@ def test_packer_invariants_uncapped(data):
     sch = wave_schedule(src, dst, valid=valid)
     check_schedule(sch, src, dst, valid)
     depths = greedy_depths(src, dst, valid=valid)
-    assert (sch.wave == depths).all(), "uncapped packing must equal depth"
+    assert (sch.wave == depths).all(), "packing must equal depth"
     # wave count floor: the longest conflict chain; also >= max vertex
     # multiplicity, so no vertex-disjoint decomposition can do better
     assert sch.num_waves == (int(depths.max()) + 1 if valid.any() else 0)
@@ -90,38 +89,20 @@ def test_packer_invariants_uncapped(data):
 
 
 @given(st.data())
-@settings(**SETTINGS)
-def test_packer_invariants_capped(data):
-    """Earliest-fit with occupancy caps: sizes bounded, every edge at or
-    past its depth, conflict order preserved, segments disjoint."""
-    stream, _ = _stream(data.draw)
-    src = np.asarray(stream.src)
-    dst = np.asarray(stream.dst)
-    valid = np.asarray(stream.valid)
-    cap = data.draw(st.sampled_from([1, 2, 3, 8]))
-    sch = wave_schedule(src, dst, valid=valid, max_width=cap)
-    check_schedule(sch, src, dst, valid)  # includes the depth floor
-    assert (sch.wave_sizes() <= cap).all()
-    # capping never reorders conflicts, only delays placements
-    depths = greedy_depths(src, dst, valid=valid)
-    assert (sch.wave[valid] >= depths[valid]).all()
-
-
-@given(st.data())
 @settings(max_examples=10, deadline=None)
 def test_packed_schedule_bit_identity(data):
     """Packed-layout engine results == the sequential scan oracle, for
-    uncapped and capped schedules, packed and unpacked bit layouts."""
+    packed and unpacked bit layouts."""
     stream, cfg = _stream(data.draw, max_n=32, max_m=90)
     src = np.asarray(stream.src)
     dst = np.asarray(stream.dst)
     valid = np.asarray(stream.valid)
     want = mwm_scan(stream, cfg)
-    cap = data.draw(st.sampled_from([None, 4]))
-    sch = wave_schedule(src, dst, valid=valid, max_width=cap)
+    sch = wave_schedule(src, dst, valid=valid)
     got_xla = mwm_waves(stream, cfg, schedule=sch)
-    got_p = substream_match(stream, cfg, schedule="waves", waves=sch, packed=True)
-    got_u = substream_match(stream, cfg, schedule="waves", waves=sch, packed=False)
+    seg1 = dict(schedule="mega", seg_block=1, waves=sch)
+    got_p = substream_match(stream, cfg, packed=True, **seg1)
+    got_u = substream_match(stream, cfg, packed=False, **seg1)
     for got in (got_xla, got_p, got_u):
         assert (np.asarray(got.assigned) == np.asarray(want.assigned)).all()
         assert (np.asarray(got.mb) == np.asarray(want.mb)).all()
@@ -134,7 +115,7 @@ def test_single_edge_stream():
     assert sch.num_waves == 1 and sch.num_segments == 1
     assert sch.fill == 1 / SEG
     want = mwm_scan(stream, cfg)
-    got = substream_match(stream, cfg, schedule="waves", waves=sch)
+    got = substream_match(stream, cfg, schedule="mega", seg_block=1, waves=sch)
     assert (np.asarray(got.assigned) == np.asarray(want.assigned)).all()
     assert (np.asarray(got.mb) == np.asarray(want.mb)).all()
 
@@ -246,7 +227,7 @@ def test_conflict_free_stream_packs_full_segments(m):
 
 
 def _reference_schedule(src, dst, valid=None, order=None, seg=SEG):
-    """The uncapped schedule as a plain two-step computation: the
+    """The schedule as a plain two-step computation: the
     sequential ``greedy_depths`` oracle, then a stable argsort of the
     depths and a bincount for the wave-major order and the slot fill.
     Vertex ids are relabelled densely first (depths do not depend on
@@ -331,7 +312,7 @@ EQUIVALENCE_CASES = [
 
 @pytest.mark.parametrize("name", EQUIVALENCE_CASES)
 def test_uncapped_schedule_equals_reference_pack(name):
-    """Every array of the uncapped schedule, dtypes included, equals the
+    """Every array of the schedule, dtypes included, equals the
     sequential depths packed by a stable argsort: the peel's wave-major
     order and the packed-key links change how, never what."""
     src, dst, valid, order, seg = _equivalence_case(name)
@@ -348,10 +329,9 @@ def test_uncapped_schedule_equals_reference_pack(name):
 
 
 def test_assign_spans_links_and_peel(monkeypatch):
-    """Uncapped, ``wave_schedule.assign`` holds the two child spans
-    ``links`` and ``peel``, and ``links`` holds the copy of the links
-    to the host; the capped packer has none of them, and with telemetry
-    off nothing is recorded or annotated."""
+    """``wave_schedule.assign`` holds the two child spans ``links`` and
+    ``peel``, and ``links`` holds the copy of the links to the host; with
+    telemetry off nothing is recorded or annotated."""
     opened = []
 
     def annotation(name):
@@ -379,11 +359,3 @@ def test_assign_spans_links_and_peel(monkeypatch):
     assert by_name["copy.d2h"]["args"]["what"] == "links"
     assert by_name["wave_schedule.assign"]["args"]["parent"] is None
     assert {"wave_schedule.links", "wave_schedule.peel"} <= set(opened)
-
-    capped = obs.Telemetry()
-    wave_schedule(src, dst, max_width=4, telemetry=capped)
-    names = {e["name"] for e in capped.tracer.events if e.get("ph") == "X"}
-    assert names == {
-        "wave_schedule.prepare", "wave_schedule.assign", "wave_schedule.pack",
-        "wave_schedule.emit",
-    }

@@ -4,8 +4,9 @@ The wave pipeline's contract: decomposing the stream into vertex-disjoint
 waves and processing each wave simultaneously is *bit-identical* to the
 sequential 1-edge scan (greedy matching is confluent over vertex-disjoint
 edges) — across the XLA reference (`mwm_waves`), the packed and unpacked
-Pallas wave kernels (`substream_match(schedule="waves")`), the rounds
-engine with wave offsets, and the blocked lexicographic pre-order.
+Pallas megakernel at one segment per tile (`substream_match(schedule=
+"mega", seg_block=1)`), the rounds engine with wave offsets, and the
+blocked lexicographic pre-order.
 """
 import numpy as np
 import pytest
@@ -25,22 +26,28 @@ from repro.core import (
 )
 from repro.graph.waves import (
     WaveSchedule,
+    block_aligned_layout,
     check_schedule,
     slot_arrays,
     wave_schedule,
 )
 from repro.kernels.substream_match.ops import (
+    EPOCH_ENGINES,
     SLOT_SMEM_BYTES,
     SMEM_PER_CORE,
     VMEM_BIT_BUDGET,
     VMEM_PER_CORE,
     WavePlan,
+    match_epochs,
+    mega_plan,
     resolve_interpret,
     substream_match,
-    wave_plan,
 )
 
 SETTINGS = dict(max_examples=15, deadline=None)
+
+#: The megakernel at one segment per tile: one trip per schedule row.
+SEG1 = dict(schedule="mega", seg_block=1)
 
 
 def _stream(draw, max_n=48, max_m=150):
@@ -84,20 +91,6 @@ def test_wave_scheduler_invariants(data):
 
 @given(st.data())
 @settings(**SETTINGS)
-def test_wave_scheduler_max_width_split(data):
-    stream, _ = _stream(data.draw)
-    src = np.asarray(stream.src)
-    dst = np.asarray(stream.dst)
-    valid = np.asarray(stream.valid)
-    cap = data.draw(st.sampled_from([1, 2, 8]))
-    sch = wave_schedule(src, dst, valid=valid, max_width=cap)
-    check_schedule(sch, src, dst, valid)  # chunks stay vertex-disjoint
-    assert (sch.wave_sizes() <= cap).all()
-    assert sch.width <= -(-cap // 8) * 8
-
-
-@given(st.data())
-@settings(**SETTINGS)
 def test_mwm_waves_equals_scan(data):
     stream, cfg = _stream(data.draw)
     want = mwm_scan(stream, cfg)
@@ -109,12 +102,13 @@ def test_mwm_waves_equals_scan(data):
 @given(st.data())
 @settings(max_examples=8, deadline=None)
 def test_wave_kernel_equals_scan(data):
-    """schedule="waves" is bit-identical to mwm_scan for both layouts,
-    and the two layouts ship identical packed words."""
+    """The megakernel at one segment per tile is bit-identical to
+    mwm_scan for both layouts, and the two layouts ship identical packed
+    words."""
     stream, cfg = _stream(data.draw, max_n=32, max_m=80)
     want = mwm_scan(stream, cfg)
-    got_p = substream_match(stream, cfg, schedule="waves", packed=True)
-    got_u = substream_match(stream, cfg, schedule="waves", packed=False)
+    got_p = substream_match(stream, cfg, packed=True, **SEG1)
+    got_u = substream_match(stream, cfg, packed=False, **SEG1)
     assert got_p.is_packed and not got_u.is_packed
     assert (np.asarray(got_p.assigned) == np.asarray(want.assigned)).all()
     assert (np.asarray(got_u.assigned) == np.asarray(want.assigned)).all()
@@ -151,14 +145,16 @@ def test_rounds_waves_rejects_max_rounds(rng):
 
 
 def test_scheduler_handles_conflict_free_streams_at_scale():
-    """All-independent edges (every wave fills to max_width) must stay
-    near-linear: the full-wave skip pointers, not a per-edge rescan."""
+    """All-independent edges are one wave of m edges, packed into full
+    segments: the peel resolves the whole stream in its first pass."""
     m = 40_000
     src = np.arange(0, 2 * m, 2)
     dst = np.arange(1, 2 * m, 2)
-    sch = wave_schedule(src, dst, max_width=8)
-    assert sch.num_waves == m // 8
-    assert (sch.wave_sizes() == 8).all()
+    sch = wave_schedule(src, dst)
+    assert sch.num_waves == 1
+    assert sch.wave_sizes().tolist() == [m]
+    assert sch.num_segments == m // sch.width and sch.fill == 1.0
+    np.testing.assert_array_equal(sch.order, np.arange(m))
 
 
 def test_wave_kernel_blocked_order(rng):
@@ -168,7 +164,7 @@ def test_wave_kernel_blocked_order(rng):
 
     stream, cfg = make_stream(rng, 40, 200, 17, 0.1, self_loops=True)
     want = mwm_blocked(stream, cfg, K=8, backend="scan")
-    got = mwm_blocked(stream, cfg, K=8, backend="pallas", schedule="waves")
+    got = mwm_blocked(stream, cfg, K=8, backend="pallas", **SEG1)
     assert (np.asarray(got.assigned) == np.asarray(want.assigned)).all()
     assert (np.asarray(got.mb) == np.asarray(want.mb)).all()
     assert (merge_host(stream, got, cfg) == merge_host(stream, want, cfg)).all()
@@ -213,7 +209,7 @@ def test_reused_schedule_across_L(rng):
     for L, eps in [(1, 0.5), (9, 0.1), (64, 0.05)]:
         cfg = SubstreamConfig(n=30, L=L, eps=eps)
         want = mwm_scan(stream, cfg)
-        got = substream_match(stream, cfg, schedule="waves", waves=sch)
+        got = substream_match(stream, cfg, waves=sch, **SEG1)
         assert (np.asarray(got.assigned) == np.asarray(want.assigned)).all()
         assert (np.asarray(got.mb) == np.asarray(want.mb)).all()
 
@@ -232,7 +228,7 @@ def test_stale_schedule_rejected(rng):
     perm = np.random.default_rng(1).permutation(stream.num_edges)
     shuffled = permute_stream(stream, perm)
     with pytest.raises(ValueError, match="disjoint|cover"):
-        substream_match(shuffled, cfg, schedule="waves", waves=sch)
+        substream_match(shuffled, cfg, waves=sch, **SEG1)
     with pytest.raises(ValueError, match="disjoint|cover"):
         mwm_waves(shuffled, cfg, schedule=sch)
     # coverage mismatch: schedule built ignoring the valid mask
@@ -249,7 +245,7 @@ def test_schedule_stream_mismatch_raises(rng):
     other, _ = make_stream(rng, 16, 70, 8, 0.1)
     sch = wave_schedule(np.asarray(other.src), np.asarray(other.dst))
     with pytest.raises(ValueError, match="schedule"):
-        substream_match(stream, cfg, schedule="waves", waves=sch)
+        substream_match(stream, cfg, waves=sch, **SEG1)
     with pytest.raises(ValueError, match="schedule"):
         mwm_waves(stream, cfg, schedule=sch)
     with pytest.raises(ValueError, match="schedule"):
@@ -269,7 +265,9 @@ def test_slot_arrays_padding_encoding(rng):
     assert ok.sum() == 4
 
 
-def test_wave_plan_accounting(rng):
+def test_seg1_plan_accounting(rng):
+    """The plan of the megakernel at one segment per tile: one trip per
+    schedule row, ``block_s`` rows per grid program."""
     from tests.conftest import make_stream
 
     stream, cfg = make_stream(rng, 100, 400, 48, 0.1)
@@ -278,8 +276,9 @@ def test_wave_plan_accounting(rng):
         np.asarray(stream.dst),
         valid=np.asarray(stream.valid),
     )
+    layout = block_aligned_layout(sch, 1)
     for packed in (True, False):
-        plan = wave_plan(cfg.n, cfg.L, sch, packed=packed)
+        plan = mega_plan(cfg.n, cfg.L, layout, packed=packed)
         assert isinstance(plan, WavePlan)
         assert plan.seg == sch.width
         assert plan.num_waves == sch.num_waves
@@ -301,22 +300,61 @@ def test_wave_plan_accounting(rng):
         num_edges=1,
     )
     with pytest.raises(ValueError, match="seg"):
-        wave_plan(cfg.n, cfg.L, huge, packed=True)
-    # an explicit block_s that overflows the stream buffers names block_s
-    with pytest.raises(ValueError, match="block_s"):
-        wave_plan(cfg.n, cfg.L, sch, packed=True, block_s=2**24)
+        mega_plan(cfg.n, cfg.L, block_aligned_layout(huge, 1), packed=True)
+    # tiles per program that overflow the stream buffers are named
+    with pytest.raises(ValueError, match="tiles_per_block"):
+        mega_plan(cfg.n, cfg.L, layout, packed=True, tiles_per_block=2**24)
+
+
+def test_seg_block_one_layout_is_the_schedule(rng):
+    """At one segment per tile the block-aligned layout is the schedule's
+    own slot layout, and a grid program holds ``block_s`` whole rows: the
+    megakernel at ``seg_block=1`` runs exactly the one-segment-per-trip
+    program."""
+    from tests.conftest import make_stream
+
+    stream, cfg = make_stream(rng, 300, 4000, 64, 0.1, self_loops=True, pad=9)
+    sch = wave_schedule(
+        np.asarray(stream.src),
+        np.asarray(stream.dst),
+        valid=np.asarray(stream.valid),
+    )
+    layout = block_aligned_layout(sch, 1)
+    np.testing.assert_array_equal(layout.slots, sch.slots)
+    np.testing.assert_array_equal(layout.seg_offsets, sch.seg_offsets)
+    assert layout.num_tiles == sch.num_segments
+    plan = mega_plan(cfg.n, cfg.L, layout)
+    assert plan.seg_block == 1 and plan.block_s == plan.tiles_per_block
+    assert plan.block_e == plan.block_s * plan.seg
+    assert plan.gather_bytes == plan.block_e * SLOT_SMEM_BYTES
+
+
+@pytest.mark.parametrize("entry", ["substream_match", "match_epochs"])
+def test_retired_waves_engine_rejected(rng, entry):
+    """The one-segment Pallas engine is the megakernel at ``seg_block=1``;
+    the old engine name is refused with the names that remain."""
+    from tests.conftest import make_stream
+
+    stream, cfg = make_stream(rng, 16, 40, 8, 0.1)
+    with pytest.raises(ValueError, match="edges.*mega") as err:
+        if entry == "substream_match":
+            substream_match(stream, cfg, schedule="waves")
+        else:
+            match_epochs(stream, cfg, engine="waves")
+    assert "'waves'" in str(err.value)
+    assert "waves" not in EPOCH_ENGINES
 
 
 @pytest.mark.parametrize("packed", [True, False], ids=["packed", "unpacked"])
 def test_wave_path_vmem_budget_enforced(rng, packed):
     """An over-budget *bit block* reports the rounds/partitioning error,
-    not the wave-tile max_width one (that's only for oversized waves)."""
+    not the slot-buffer one (that is only for oversized tiles)."""
     from tests.conftest import make_stream
 
     stream, _ = make_stream(rng, 16, 40, 4, 0.1)
     big = SubstreamConfig(n=100_000_000, L=512, eps=0.1)
     with pytest.raises(ValueError, match="rounds"):
-        substream_match(stream, big, schedule="waves", packed=packed)
+        substream_match(stream, big, packed=packed, **SEG1)
 
 
 def test_resolve_interpret_auto():
@@ -333,7 +371,7 @@ def test_empty_and_degenerate_streams():
     stream = EdgeStream.from_numpy([3], [3], [5.0])
     cfg = SubstreamConfig(n=8, L=8, eps=0.1)
     want = mwm_scan(stream, cfg)
-    got = substream_match(stream, cfg, schedule="waves")
+    got = substream_match(stream, cfg, **SEG1)
     assert (np.asarray(got.assigned) == np.asarray(want.assigned)).all()
     # all-padding stream: zero waves scheduled
     padded = EdgeStream.from_numpy([0], [1], [2.0], n_pad=4)
@@ -346,6 +384,6 @@ def test_empty_and_degenerate_streams():
         valid=np.asarray(padded.valid),
     )
     assert sch.num_waves == 0 and sch.num_scheduled == 0
-    got = substream_match(padded, cfg, schedule="waves", waves=sch)
+    got = substream_match(padded, cfg, waves=sch, **SEG1)
     assert (np.asarray(got.assigned) == -1).all()
     assert not np.asarray(got.mb).any()
