@@ -28,16 +28,30 @@ def merge_host(
 
     The merge order "descending substream i, then stream position" is
     realized with ONE stable argsort over the recorded edges (key
-    ``L-1-i``; stability supplies the stream-position minor key), then a
-    single greedy pass over those edges only — O(R log R + R) for R
-    recorded edges instead of the old O(L·m) scan of the whole stream
-    per substream. The greedy pass itself is the dependency chain and
-    stays a loop, exactly like the paper's sequential post-processor.
+    ``L-1-i`` in the smallest dtype that holds it, ``uint8`` for every
+    ``L <= 256``, so numpy takes its radix sort; stability supplies the
+    stream-position minor key), and the sorted keys split that order
+    into one group per substream.
+
+    The greedy pass decides a whole group at once. An edge is recorded
+    under ``i`` only when it set bit ``i`` on both endpoints, and a bit
+    once set is never set again, so the edges of one substream form a
+    matching: vertex-disjoint. Inside a group no edge's decision then
+    depends on another's, only on the vertices taken by higher
+    substreams, so greedy over the merge order equals one gather, mask
+    and scatter per group, from ``i = L-1`` down to 0 — bit-identical
+    to the paper's sequential post-processor. Each group is checked to
+    be disjoint (every endpoint reads back its own stamp, and no
+    self-loop) in O(group); a group that fails, which no correct Part 1
+    produces, is decided edge by edge in stream order, so the result
+    equals the sequential loop for every ``assigned``.
 
     ``telemetry`` records one ``merge.host`` span (the copies to the
-    host inside it as ``copy.d2h`` spans, the sort as ``merge.order``
-    and the greedy pass as ``merge.greedy``) plus the recorded / matched
-    edge counters.
+    host inside it as ``copy.d2h`` spans, the order and group split as
+    ``merge.order`` and the per-group greedy pass as ``merge.greedy``)
+    plus the recorded / matched edge counters, the groups decided
+    (``merge.greedy_groups``) and the edges the per-edge fallback
+    decided (``merge.loop_edges``, 0 on every correct result).
     """
     with telemetry.span("merge.host"):
         with telemetry.span("copy.d2h", what="stream"):
@@ -46,33 +60,68 @@ def merge_host(
         with telemetry.span("copy.d2h", what="assigned"):
             assigned = np.asarray(result.assigned)
         with telemetry.span("merge.order"):
-            recorded = np.nonzero(assigned >= 0)[0]
-            # descending i, stream order within i: stable sort on the
-            # major key alone (``recorded`` is ascending in stream position)
-            order = recorded[np.argsort(cfg.L - 1 - assigned[recorded], kind="stable")]
+            recorded = np.flatnonzero(assigned >= 0)
+            order = recorded
+            bounds = np.zeros(1, np.int64)
+            if recorded.size:
+                key = cfg.L - 1 - assigned[recorded]
+                # uint8 for any L <= 256; a substream beyond L-1 (a
+                # corrupted result) keys below 0 and widens the dtype
+                key = key.astype(np.result_type(
+                    np.min_scalar_type(key.min()), np.min_scalar_type(key.max())))
+                # descending i, stream order within i: stable sort on the
+                # major key alone (``recorded`` is ascending in stream position)
+                perm = np.argsort(key, kind="stable")
+                order = recorded[perm]
+                key = key[perm]
+                bounds = np.concatenate(
+                    ([0], np.flatnonzero(key[1:] != key[:-1]) + 1, [key.size]))
         if recorded.size == 0:
             # empty / all-dropped streams: a well-formed empty T, skipping
-            # the n-sized tbits allocation (n may be 0 here)
-            if telemetry.enabled:
-                telemetry.counters.add("merge.host.calls")
-                telemetry.counters.put("merge.recorded_edges", 0)
-                telemetry.counters.put("merge.matched_edges", 0)
-            return np.zeros(0, dtype=np.int64)
-        with telemetry.span("merge.greedy"):
-            tbits = np.zeros(cfg.n, dtype=bool)
-            out = []
-            for e in order.tolist():
-                u, v = src[e], dst[e]
-                if not tbits[u] and not tbits[v]:
-                    tbits[u] = True
-                    tbits[v] = True
-                    out.append(e)
-            merged = np.sort(np.asarray(out, dtype=np.int64))
+            # the n-sized allocations (n may be 0 here)
+            merged = np.zeros(0, dtype=np.int64)
+            loop_edges = 0
+        else:
+            with telemetry.span("merge.greedy"):
+                merged, loop_edges = _greedy_groups(src, dst, order, bounds, cfg.n)
     if telemetry.enabled:
         telemetry.counters.add("merge.host.calls")
         telemetry.counters.put("merge.recorded_edges", int(recorded.size))
         telemetry.counters.put("merge.matched_edges", int(merged.size))
+        telemetry.counters.put("merge.greedy_groups", int(bounds.size - 1))
+        telemetry.counters.put("merge.loop_edges", int(loop_edges))
     return merged
+
+
+def _greedy_groups(src, dst, order, bounds, n):
+    """Greedy over ``order`` split at ``bounds`` into substream groups:
+    returns (sorted positions of T, edges decided one at a time)."""
+    # intp endpoints: numpy's fancy indexing converts any other dtype on
+    # every use
+    u, v = src[order].astype(np.intp), dst[order].astype(np.intp)
+    taken = np.zeros(n, dtype=bool)
+    stamp = np.empty(n, dtype=np.int32)
+    slot = np.arange(order.size, dtype=np.int32)
+    keep = np.zeros(order.size, dtype=bool)
+    loop_edges = 0
+    for a, b in zip(bounds[:-1].tolist(), bounds[1:].tolist()):
+        gu, gv, gs = u[a:b], v[a:b], slot[a:b]
+        stamp[gu] = gs
+        stamp[gv] = gs
+        if (stamp[gu] == gs).all() and (stamp[gv] == gs).all() and (gu != gv).all():
+            ok = ~taken[gu] & ~taken[gv]
+            keep[a:b] = ok
+            taken[gu[ok]] = True
+            taken[gv[ok]] = True
+            continue
+        loop_edges += b - a
+        for j in range(a, b):
+            x, y = u[j], v[j]
+            if not taken[x] and not taken[y]:
+                taken[x] = True
+                taken[y] = True
+                keep[j] = True
+    return np.sort(order[keep]).astype(np.int64, copy=False), loop_edges
 
 
 def merge_device(

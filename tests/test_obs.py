@@ -513,6 +513,11 @@ def test_merge_order_and_greedy_nest_inside_merge_host():
     assert host["ts"] <= order["ts"]
     assert order["ts"] + order["dur"] <= greedy["ts"]
     assert greedy["ts"] + greedy["dur"] <= host["ts"] + host["dur"]
+    assigned = np.asarray(res.assigned)
+    groups = np.unique(assigned[assigned >= 0]).size
+    assert groups > 1
+    assert tel.counters.get("merge.greedy_groups") == groups
+    assert tel.counters.get("merge.loop_edges") == 0
 
 
 def test_merge_of_nothing_recorded_records_no_greedy_pass():
